@@ -4,7 +4,7 @@
 agree on what the SPMD surface of this tree looks like:
 
 * a **shard_map site** is any call named ``shard_map`` — the
-  ``parallel/mesh.py`` compat wrapper is the only sanctioned spelling
+  ``parallel/mesh.py`` wrapper is the only sanctioned spelling
   (docs/distributed.md), and sites thread their body as a bare name,
   an inline ``functools.partial(f, ...)``, or the local
   ``f = functools.partial(...)`` binding (the same three idioms

@@ -42,7 +42,7 @@ LAYERS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("primitives", ("concurrency",)),
     ("foundation", ("tensor", "config", "initializers", "losses",
                     "metrics", "optim", "data", "native_lib",
-                    "distributed", "analysis")),
+                    "distributed", "analysis", "entrypoint")),
     ("telemetry", ("telemetry",)),
     ("ops", ("ops",)),
     # tiered embedding storage reads the ops cost gates

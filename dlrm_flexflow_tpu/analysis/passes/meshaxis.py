@@ -17,11 +17,11 @@ makes the discipline checkable:
   at trace time in the best case, and in the worst it sits in code a
   refactor is about to move onto a hot path;
 * ``jax.shard_map`` / ``jax.experimental.shard_map`` must not be
-  spelled outside ``parallel/mesh.py``: the compat wrapper exists
-  because this tree supports jax versions where only ONE of those
-  exists (``check_vma`` vs ``check_rep`` — the jax-0.4.37 hazard that
-  broke 13 tests before PR 13 routed everything through the wrapper);
-  a direct import is a version-portability regression by construction.
+  spelled outside ``parallel/mesh.py``: its one-line wrapper is the
+  single place this tree names jax's shard_map surface (whose spelling
+  has changed between releases — ``check_rep`` became ``check_vma``,
+  the experimental module is deprecated), and the site resolver of
+  this pass reads the wrapper's call sites.
 
 Codes: ``undeclared-axis``, ``collective-outside-spmd``,
 ``direct-shard-map``.
@@ -71,7 +71,7 @@ class MeshAxisPass(AnalysisPass):
     description = ("shard_map bodies only use axes their site "
                    "declares; no collectives outside SPMD contexts; "
                    "jax.shard_map only through the parallel/mesh.py "
-                   "compat wrapper")
+                   "wrapper")
 
     def run(self, modules: List[Module],
             index: FunctionIndex) -> List[Finding]:
@@ -94,11 +94,10 @@ class MeshAxisPass(AnalysisPass):
                      _out=out):
                 _out.append(self.finding(
                     _m.relpath, line, "direct-shard-map",
-                    f"{what} outside parallel/mesh.py — only the "
-                    f"compat wrapper may touch jax's shard_map "
-                    f"surface (check_vma vs check_rep differs across "
-                    f"the jax versions this tree supports; "
-                    f"docs/distributed.md)", detail=detail))
+                    f"{what} outside parallel/mesh.py — only its "
+                    f"wrapper may touch jax's shard_map surface (one "
+                    f"spelling to keep current; docs/distributed.md)",
+                    detail=detail))
 
             for node in ast.walk(m.tree):
                 if isinstance(node, ast.ImportFrom):

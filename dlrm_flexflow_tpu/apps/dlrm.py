@@ -41,7 +41,10 @@ class DLRMConfig:
     # --fused-interaction {off,auto,on}: build the gather->pool->interact
     # chain as ONE FusedEmbedInteract op (ops/fused_interact.py) instead
     # of stacked_embedding -> reshape -> concat/batch_matmul.  "auto"
-    # fuses on single-chip TPU (where the pallas kernel can engage);
+    # fuses on single-chip TPU — the one place the pallas kernel can
+    # engage, and only for feature sizes that are whole 128-lane rows
+    # (Mosaic refuses the default d = 64; ops/pallas_fused_interact.py
+    # ``kernel_eligible``), everything else runs the op's emitter path;
     # "on" forces the fused graph everywhere (the emitter path runs
     # off-TPU, bit-exact); "off" (default) keeps the classic graph.
     fused_interaction: str = "off"
@@ -305,17 +308,25 @@ def build_dlrm(cfg: DLRMConfig, ffconfig: Optional[FFConfig] = None,
     return model
 
 
-def run(argv: Sequence[str] = ()):  # pragma: no cover - CLI
-    """CLI mirroring the reference app (MSE loss + accuracy, dlrm.cc:150)."""
+def setup(argv: Sequence[str] = (), ffconfig: Optional[FFConfig] = None,
+          mesh=None, table_parallel: bool = False):
+    """Everything the CLI does before ``fit``: parse the flags, build
+    and compile the model (MSE loss + accuracy, dlrm.cc:150), create the
+    state and the loader.  Returns ``(model, state, loader)``.
+
+    ``ffconfig`` replaces the flag-parsed FFConfig (``chip_smoke.py``
+    passes one with a cache/storage mode changed); ``mesh`` and
+    ``table_parallel`` go to ``compile`` / ``build_dlrm`` unchanged (the
+    CLI leaves both at their defaults: all devices data-parallel)."""
     from ..data.loader import SyntheticDLRMLoader, load_criteo_h5, ArrayDataLoader
 
-    ffconfig = FFConfig.parse_args(argv)
+    ffconfig = ffconfig or FFConfig.parse_args(argv)
     cfg = DLRMConfig.parse_args(argv)
-    model = build_dlrm(cfg, ffconfig)
+    model = build_dlrm(cfg, ffconfig, table_parallel=table_parallel)
     model.compile(optimizer=SGDOptimizer(ffconfig.learning_rate, 0.0, False,
                                          ffconfig.weight_decay),
                   loss_type="mean_squared_error",
-                  metrics=("accuracy", "mean_squared_error"))
+                  metrics=("accuracy", "mean_squared_error"), mesh=mesh)
     state = model.init()
     stacked = model._dlrm_stacked  # keep loader layout in sync with graph
     if cfg.dataset:
@@ -326,6 +337,17 @@ def run(argv: Sequence[str] = ()):  # pragma: no cover - CLI
         loader = SyntheticDLRMLoader(n, cfg.mlp_bot[0], cfg.embedding_size,
                                      cfg.embedding_bag_size,
                                      ffconfig.batch_size, stacked=stacked)
+    return model, state, loader
+
+
+def run(argv: Sequence[str] = ()):  # pragma: no cover - CLI
+    """CLI mirroring the reference app (dlrm.cc:77-199)."""
+    from ..entrypoint import device_line, enable_compile_cache
+
+    enable_compile_cache()
+    print(device_line(), flush=True)
+    model, state, loader = setup(argv)
+    ffconfig = model.config
     state, thpt = model.fit(state, loader, epochs=ffconfig.epochs)
     if ffconfig.profiling:
         # reference --profiling wraps every kernel in timing events and

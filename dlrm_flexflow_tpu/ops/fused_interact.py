@@ -15,9 +15,15 @@ never pays the dense table-shaped backward).
 Forward dispatch (no ``rows__``):
 
 * **kernel** — the fused pallas kernel (pallas_fused_interact.py) when
-  the cost model says it wins (``kernel_costs.fused_interact_wins``)
-  on single-chip TPU with a plain f32 table.  ``FF_FUSED_INTERACT``
-  overrides: ``auto`` (default, cost-gated) | ``kernel`` | ``emitter``.
+  the compiler accepts the shape (``kernel_eligible``: a plain f32
+  table with whole 128-lane rows, d % 128 == 0 — Mosaic refuses the
+  app's d = 64) and the cost model says it wins
+  (``kernel_costs.fused_interact_wins``) on single-chip TPU.
+  ``FF_FUSED_INTERACT`` overrides the cost model only: ``auto``
+  (default, cost-gated) | ``kernel`` | ``emitter``.  The backward of a
+  kernel forward is the emitter VJP in every compiled program (the
+  backward kernel does not compile on the chip —
+  ``bwd_kernel_eligible``).
 * **emitter** — the reference XLA path otherwise (also the only path
   for packed-storage and quantized serving tables, whose reads go
   through ``view_gather`` / per-row dequant).
@@ -85,7 +91,8 @@ class FusedEmbedInteract(RaggedStackedEmbedding):
         if self._mesh is not None:
             return False  # SPMD cannot partition a pallas_call
         bag = idx.shape[-1]
-        if not kernel_eligible(table.dtype, self.out_dim, bag):
+        if not kernel_eligible(table.dtype, self.out_dim, bag,
+                               interpret=self._interpret):
             return False
         if self._interpret:
             return True

@@ -24,14 +24,18 @@ issued for it.  ``mask_local_ids`` encodes the rule once (invalid ->
 -1) so the kernel and the emitter reference path below cannot
 disagree; ``tests/test_kernels.py`` pins both.
 
-Dispatch is cost-model gated (``ops/kernel_costs.fused_interact_wins``
-— the same measured constants as the row-set gate): per-row DMAs are
-latency-bound, so the kernel wins only where the unfused chain's
-fusion-boundary overheads and intermediate bounce dominate (the small
-serving buckets); the training headline keeps XLA's batched gather
-pipeline, exactly as the pallas_embedding bring-up measured for the
-bag alone.  Off-TPU the reference path runs; tests exercise the kernel
-in interpret mode.
+Dispatch is eligibility- then cost-model gated.  Eligibility is what
+the compiler accepts (``kernel_eligible``): on the chip the forward
+kernel compiles only for whole 128-lane rows (d % 128 == 0 — the app's
+d = 64 is refused by Mosaic) and the backward kernel not at all
+(``bwd_kernel_eligible``), so compiled programs differentiate a kernel
+forward through the emitter VJP.  The cost gate
+(``ops/kernel_costs.fused_interact_wins`` — the same constants as the
+row-set gate): per-row DMAs are latency-bound, so the kernel wins only
+where the unfused chain's fusion-boundary overheads and intermediate
+bounce dominate (the small serving buckets); the training headline
+keeps XLA's batched gather pipeline.  Off-TPU the reference path runs;
+tests exercise both kernels in interpret mode.
 """
 
 from __future__ import annotations
@@ -268,14 +272,33 @@ def fused_interact_pallas(table, gids, bottom, *, interact: str = "cat",
     return out[:bsz]
 
 
-def kernel_eligible(table_dtype, dim: int, bag: int) -> bool:
-    """Static shape/dtype eligibility of the fused kernel: f32 tables
-    (bf16/quantized serving tables take the reference path — their
-    numerics are tolerance-pinned, not bit-exact), a non-empty bag,
-    and a lane-friendly dim (the (1, d) row DMAs need the 8-multiple
-    sublane tiling the row-update kernel established)."""
+def kernel_eligible(table_dtype, dim: int, bag: int,
+                    interpret: bool = False) -> bool:
+    """Static shape/dtype eligibility of the fused FORWARD kernel: f32
+    tables (bf16/quantized serving tables take the reference path —
+    their numerics are tolerance-pinned, not bit-exact), a non-empty
+    bag, and whole 128-lane rows.  Mosaic refuses the (1, d) row DMA
+    below that ("Slice shape along dimension 1 must be aligned to tiling
+    (128), but is 64" — TPU v5e, jax 0.9.0, PERF.md PR 21); at
+    d % 128 == 0 the kernel compiles and is bit-exact against the
+    emitter on the chip (cat and dot, bag 1 and 4).  The interpreter
+    has no lane tiling and keeps the 8-multiple rule the tests use."""
+    lanes = 8 if interpret else 128
     return (jnp.dtype(table_dtype) == jnp.float32 and bag > 0
-            and dim % 8 == 0)
+            and dim % lanes == 0)
+
+
+def bwd_kernel_eligible(interpret: bool, compute_dtype=None) -> bool:
+    """Whether the custom VJP runs the fused BACKWARD kernel.  Mosaic
+    refuses it at every shape tried on the chip ("infer-vector-layout:
+    unsupported shape cast" at d = 64 and 128, "Input offsets outside of
+    the first tile" at bag 4 — TPU v5e, jax 0.9.0, PERF.md PR 21), so a
+    compiled program never selects it: the backward of a kernel forward
+    is the emitter VJP, which the interpret-mode tests pin bit-identical
+    to the kernel.  The kernel stays for the interpreter (and for the
+    perf_opt issue that repairs it from a trace); f32 only either way
+    (the bf16 dot cast's autodiff chain stays on the emitter VJP)."""
+    return interpret and compute_dtype is None
 
 
 def interact_backward(g, bottom, pooled, interact: str):
@@ -458,13 +481,14 @@ def fused_embed_interact(table, gids, bottom, interact: str = "cat",
                          interpret: bool = False, compute_dtype=None):
     """Differentiable fused gather->pool->interact with the kernel/
     emitter dispatch already decided by the caller (the op consults
-    ``kernel_costs.fused_interact_wins``).  Backward: the fused
-    backward kernel when the forward ran the kernel at f32 (row grads
-    built in VMEM, no re-gather through the emitter's dense chain —
-    bit-exact vs the emitter VJP, pinned in interpret mode);
-    otherwise re-derives through the reference formulation — identical
-    to autodiff of the unfused graph (the training fast path instead
-    injects pre-gathered rows and never reaches this custom_vjp)."""
+    ``kernel_eligible`` and ``kernel_costs.fused_interact_wins``).
+    Backward: the fused backward kernel only where
+    ``bwd_kernel_eligible`` allows it — today the interpreter, because
+    Mosaic refuses the kernel on the chip; otherwise it re-derives
+    through the reference formulation — identical to autodiff of the
+    unfused graph, and pinned bit-exact against the kernel in interpret
+    mode (the training fast path instead injects pre-gathered rows and
+    never reaches this custom_vjp)."""
     if use_kernel:
         return fused_interact_pallas(table, gids, bottom,
                                      interact=interact, aggr=aggr,
@@ -483,12 +507,11 @@ def _fwd(table, gids, bottom, interact, aggr, use_kernel, interpret,
 
 def _bwd(interact, aggr, use_kernel, interpret, compute_dtype, res, g):
     table, gids, bottom = res
-    if use_kernel and compute_dtype is None:
-        # the fused backward kernel (f32 only — the bf16 dot cast's
-        # autodiff chain stays on the emitter VJP): per-slot row grads
-        # stream out of VMEM, then ONE scatter-add touches exactly the
-        # looked-up rows.  Same updates at the same indices as the
-        # emitter VJP's take-transpose, so dtable is bit-identical.
+    if use_kernel and bwd_kernel_eligible(interpret, compute_dtype):
+        # the fused backward kernel: per-slot row grads stream out of
+        # VMEM, then ONE scatter-add touches exactly the looked-up
+        # rows.  Same updates at the same indices as the emitter VJP's
+        # take-transpose, so dtable is bit-identical.
         rowg, db = fused_interact_bwd_pallas(
             table, gids, bottom, g, interact=interact, aggr=aggr,
             interpret=interpret)

@@ -35,24 +35,14 @@ SEQ_AXIS = "seq"
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across the jax versions this tree supports:
-    the public ``jax.shard_map`` (its replication checker knob is
-    ``check_vma``) or, on older jax, the experimental
-    ``shard_map`` (same knob under its earlier ``check_rep`` name).
-    One wrapper so every manual-collective module (table_exchange,
-    overlap, pipeline, ring/ulysses attention) stays version-portable
-    instead of five call sites hand-rolling the fallback."""
-    kwargs = {}
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
+    """``jax.shard_map``, spelled once: every manual-collective module
+    (table_exchange, overlap, pipeline, ring/ulysses attention) calls
+    this, so the ffcheck mesh-axis pass has one site to resolve and a
+    change in jax's spelling is a one-line edit.  ``check_vma=None``
+    leaves the replication checker at jax's default."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def make_mesh(shape: Optional[Dict[str, int]] = None,

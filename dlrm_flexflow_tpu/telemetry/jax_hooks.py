@@ -56,8 +56,13 @@ def _on_duration(event: str, duration: float, **_kw):
 
 def _on_event(event: str, **_kw):
     if event.startswith("/jax/compilation_cache/"):
+        # "cache_hits": an executable read back from the persistent
+        # cache; "cache_misses": one compiled and written to it
+        leaf = event.rsplit("/", 1)[1]
         with _lock:
             _counters["cache_events"] = _counters.get("cache_events", 0) + 1
+            if leaf in ("cache_hits", "cache_misses"):
+                _counters[leaf] = _counters.get(leaf, 0) + 1
 
 
 def install_compile_hooks() -> bool:
@@ -80,6 +85,7 @@ def install_compile_hooks() -> bool:
 def compile_stats() -> Dict[str, float]:
     """Snapshot of the running counters: per-stage counts and total
     seconds (``backend_compile``, ``jaxpr_trace``, ``jaxpr_to_mlir``)
-    plus ``cache_events`` (persistent-compilation-cache activity)."""
+    plus ``cache_events`` (persistent-compilation-cache activity) and,
+    of those, ``cache_hits`` and ``cache_misses``."""
     with _lock:
         return dict(_counters)

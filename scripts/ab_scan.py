@@ -8,8 +8,8 @@ a two-pass reshaped form (per-row scan along the minor dim + a tiny
 carry scan + a broadcast combine) moves the same data through O(n)
 vectorized work.
 
-Measures, chained inside one dispatch each (trace-derived busy; wall on
-this chip is a queue lottery):
+Measures, chained inside one dispatch each (trace-derived busy; wall
+would include the launch):
 
   cummax_1d      - jax.lax.cummax over s32[n]           (the ladder's form)
   cummax_2d_rxc  - reshape (r, c), cummax axis=1, carry combine

@@ -2,8 +2,8 @@
 
 Sweeps FF_SCATTER_BLOCK (the kernel re-imports per value via subprocess)
 over the DLRM headline shape: stacked 8x1M x 64 table (viewed (4M, 128)),
-2048 updates/step.  Run during a QUIET window (probe < 100us) or the
-numbers are meaningless; each timing is bracketed by probes.
+2048 updates/step.  Each timing is printed with the launch-latency probe
+(scripts/probe_chip.py) before and after it.
 
 Usage:  python scripts/ab_scatter.py [block ...]   (default 8 16 32 64)
 """

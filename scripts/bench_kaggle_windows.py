@@ -1,20 +1,13 @@
 """Criteo-Kaggle throughput vs measurement-window length.
 
-The recorded BENCH_APP=dlrm_kaggle number uses the anchored config
-(batch 64, nb 16, 2 epochs -> ~105 ms windows); on this shared chip
-(steady ~3-5 ms probe contention, PERF.md) such short windows are
-dominated by fixed costs (dispatch + cache build + contention stalls)
-and understate the framework.  This script measures the SAME per-step
-computation (bench.py's own Kaggle config, via bench._windows — the
-probe-bracketed quiet-window protocol) over increasing fused window
-lengths so the asymptotic rate is visible.
+The BENCH_APP=dlrm_kaggle cell uses a short window (batch 64, nb 16,
+2 epochs): fixed costs (dispatch, the row-cache build) are a large
+share of it.  This script measures the SAME per-step computation
+(bench.py's own Kaggle config, via bench._windows) over increasing fused
+window lengths so the asymptotic rate is visible: ``train_epochs`` fuses
+the whole run into ONE dispatch with ONE row-cache build.
 
     python scripts/bench_kaggle_windows.py
-
-Representative output under the session's steady contention
-(2026-07-30): 2 epochs -> ~17k samples/s, 4 -> ~33k, 8 -> ~68k —
-the window barely grows with epochs because ``train_epochs`` fuses the
-whole run into ONE dispatch with ONE row-cache build.
 """
 
 import json
@@ -24,9 +17,6 @@ import sys
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# keep the quiet-window resampling bounded per config
-os.environ.setdefault("BENCH_TIME_BUDGET", "120")
 
 
 def main(batch=64, nb=16, reps=3):
@@ -41,11 +31,10 @@ def main(batch=64, nb=16, reps=3):
     for epochs in (2, 4, 8):
         # fresh state per config: the fused train_epochs donates it
         state = m.init(seed=0)
-        thpt, probe_us, prov = _windows(m, state, inputs, labels, batch,
-                                        nb, epochs, reps)
-        out.append({"epochs": epochs,
-                    "samples_per_sec": round(thpt),
-                    "probe_us": round(probe_us, 1), **prov})
+        thpt, prov = _windows(m, state, inputs, labels, batch, nb, epochs,
+                              reps)
+        out.append({"epochs": epochs, "samples_per_sec": round(thpt),
+                    **prov})
     print(json.dumps({"windows": out}))
 
 

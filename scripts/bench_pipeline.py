@@ -10,7 +10,8 @@ path, before/after, on the same seed.
 Three identical runs of the sentinel-armed per-batch loop (the scanned
 fast path force-disabled) on the same seed:
 
-    JAX_PLATFORMS=cpu python scripts/bench_pipeline.py
+    python scripts/bench_pipeline.py    # the chip; JAX_PLATFORMS=cpu
+                                        # to measure the CPU on purpose
 
   fenced        — the pre-pipeline hot loop: a no-op per-batch callback
                   forces the eager path, so every dispatch fences on
@@ -37,7 +38,6 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np  # noqa: E402
 
@@ -51,6 +51,9 @@ from dlrm_flexflow_tpu.telemetry import event_log  # noqa: E402
 
 
 def main() -> int:
+    from dlrm_flexflow_tpu.entrypoint import require_tpu
+
+    require_tpu(allow_requested_cpu=True)
     batch = int(os.environ.get("PIPE_BATCH", "256"))
     nbatches = int(os.environ.get("PIPE_BATCHES", "32"))
     epochs = int(os.environ.get("PIPE_EPOCHS", "2"))

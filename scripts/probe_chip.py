@@ -1,7 +1,12 @@
-"""Probe TPU contention: fenced 1024^3 bf16 matmul, ~15us when quiet.
+"""Launch-latency probe: one chained 1024^3 bf16 matmul per dispatch.
 
-Prints one line: ``probe_us=<N>``.  >1000 means the shared chip is
-contended and absolute timing measurements are meaningless (PERF.md).
+Prints one line: ``probe_us=<N>`` — the wall time per launch of a jitted
+1024^3 bf16 matmul (about 11 us of MXU work at the v5e peak), 30 launches
+chained on their outputs and closed by one fence.  What it measures is
+the host's cost to launch a small program on this machine, not device
+speed: compare it with the device-busy time of the program under study to
+see whether per-launch dispatch can matter.  The A/B scripts print it
+next to their timings for the same reason.
 """
 import os
 import sys

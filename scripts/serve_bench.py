@@ -58,9 +58,8 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 if __name__ == "__main__":
-    # standalone default; NOT set when bench.py imports closed_loop on
-    # a real accelerator (backend init is lazy, so this is early enough)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # the load generator measures the chip unless the caller asks for
+    # the CPU (JAX_PLATFORMS=cpu — main() refuses a silent fallback).
     # a --mesh-shape run on the CPU backend needs the virtual device
     # count pinned BEFORE jax initializes (the flag is read at backend
     # start); respect an explicit XLA_FLAGS from the caller.  Both
@@ -230,6 +229,9 @@ def open_loop(batcher, pool, qps: float, duration: float):
 
 
 def main(argv=None) -> int:
+    from dlrm_flexflow_tpu.entrypoint import (enable_compile_cache,
+                                              require_tpu)
+
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     p.add_argument("--mode", choices=("closed", "open"), default="closed")
     p.add_argument("--clients", type=int, default=4,
@@ -314,6 +316,8 @@ def main(argv=None) -> int:
                         "0.0.0.0 exposes it to the network)")
     args = p.parse_args(argv)
 
+    enable_compile_cache()
+    require_tpu(allow_requested_cpu=True)
     os.makedirs(os.path.dirname(os.path.abspath(args.telemetry)),
                 exist_ok=True)
     if args.metrics_port:

@@ -1548,7 +1548,7 @@ class TestMeshAxis:
         assert fs[0].line == 3
 
     def test_fires_direct_shard_map_spellings(self, tmp_path):
-        # the jax-0.4.37 compat hazard the mesh.py wrapper contains:
+        # the two spellings the mesh.py wrapper keeps in one place:
         # both the experimental import and the jax.shard_map attribute
         fs = _run_pass(tmp_path, {"pkg/m.py": (
             "from jax.experimental.shard_map import shard_map\n"

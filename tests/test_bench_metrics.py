@@ -48,3 +48,22 @@ class TestMFUExtras:
     def test_no_busy_no_metrics(self):
         model = _tiny_mlp()
         assert _mfu_extras(model, 32, 100, {"device_busy_ms": None}) == {}
+
+
+class TestPeaksDevice:
+    """The peaks the utilization fields divide by describe ONE chip; the
+    bench refuses any other TPU before it measures (and a CPU run the
+    caller asked for records no utilization at all)."""
+
+    def test_the_described_chip_and_a_requested_cpu_pass(self):
+        from bench import _require_peaks_device
+        _require_peaks_device({"platform": "tpu", "kind": "TPU v5 lite",
+                               "count": 1})
+        _require_peaks_device({"platform": "cpu", "kind": "cpu",
+                               "count": 8})
+
+    def test_another_tpu_is_refused(self):
+        from bench import _require_peaks_device
+        with pytest.raises(SystemExit, match="TPU v4"):
+            _require_peaks_device({"platform": "tpu", "kind": "TPU v4",
+                                   "count": 4})

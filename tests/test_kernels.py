@@ -198,6 +198,24 @@ class TestDispatchCostModel:
         assert not fused_interact_wins(256, 26, 1, 16, 4, "dot")
 
 
+    def test_eligibility_is_what_the_chip_compiles(self):
+        """PR 21's chip run (TPU v5e, jax 0.9.0): Mosaic accepts the
+        forward kernel only for whole 128-lane rows and the backward
+        kernel nowhere — so no compiled program may select them there.
+        The interpreter keeps the looser rules these tests run under."""
+        from dlrm_flexflow_tpu.ops.pallas_fused_interact import (
+            bwd_kernel_eligible, kernel_eligible)
+        assert not kernel_eligible(jnp.float32, 64, 1)
+        assert kernel_eligible(jnp.float32, 128, 1)
+        assert kernel_eligible(jnp.float32, 256, 4)
+        assert not kernel_eligible(jnp.bfloat16, 128, 1)
+        assert not kernel_eligible(jnp.float32, 128, 0)
+        assert kernel_eligible(jnp.float32, 64, 1, interpret=True)
+        assert not bwd_kernel_eligible(False)
+        assert bwd_kernel_eligible(True)
+        assert not bwd_kernel_eligible(True, "bfloat16")
+
+
 class TestQuantizedTables:
     def test_int8_round_trip_error_bound(self):
         from dlrm_flexflow_tpu.ops.quantized import (dequant_rows,

@@ -526,12 +526,21 @@ class TestRegress:
         rows, reg = compare(m, dict(m), 5.0)
         assert len(rows) == 4 and not reg
 
-    def test_real_repo_artifacts(self):
-        # the repo's own history + newest BENCH record must gate clean
+    def test_real_repo_artifacts(self, tmp_path):
+        # the repo's own history must gate a driver-format record clean
+        # (the BENCH_rNN.json shape: the bench's JSON line under
+        # "parsed" — here the last such record the history was anchored
+        # against)
+        rec = str(tmp_path / "BENCH_r05.json")
+        with open(rec, "w") as f:
+            json.dump({"n": 5, "cmd": "python bench.py", "rc": 0,
+                       "parsed": {
+                           "metric": "dlrm_synthetic_samples_per_sec",
+                           "value": 1416751.26, "unit": "samples/s",
+                           "vs_baseline": 105.4904}}, f)
         rc = regress_main(["--baseline",
                            os.path.join(REPO, "bench_history.json"),
-                           "--new", os.path.join(REPO, "BENCH_r05.json"),
-                           "--tolerance", "5"])
+                           "--new", rec, "--tolerance", "5"])
         assert rc == 0
 
 

@@ -328,6 +328,32 @@ class TestOverlapMode:
             assert abs(py - nat) < 1e-9, (overlap, py, nat)
 
 
+class TestMeasuredOpTakesParamsAsArguments:
+    def test_no_table_sized_constant_in_the_measured_program(self):
+        """The measured program takes the op's params as ARGUMENTS.  As
+        closed-over values they were lowered as constants: at the DLRM
+        bench width a 2 GB table inside the HLO, whose compile and
+        cache-write took the chip machine's whole host memory (PR 21)."""
+        import warnings
+
+        import jax
+
+        m = ff.FFModel(ff.FFConfig(batch_size=8))
+        ids = m.create_tensor((8, 2), "int32", name="ids")
+        m.embedding(ids, 4096, 16, name="emb")  # a 256 KB table
+        before = jax.config.jax_captured_constants_warn_bytes
+        jax.config.update("jax_captured_constants_warn_bytes", 1024)
+        try:
+            with warnings.catch_warnings(record=True) as w:
+                warnings.simplefilter("always")
+                CostModel(measure=True, measure_iters=2).op_times(
+                    m.get_op("emb"), 1)
+        finally:
+            jax.config.update("jax_captured_constants_warn_bytes", before)
+        assert not [x for x in w if "constants were captured"
+                    in str(x.message)]
+
+
 class TestMeasureBudget:
     def test_budget_exhaustion_falls_back_to_analytic(self):
         """The measured cost model stops compiling new op measurements
