@@ -52,11 +52,13 @@ from .ops import (BatchMatmul, BatchNorm, Concat, Conv2D, Dropout,
 from .parallel.mesh import (DATA_AXIS, MODEL_AXIS, constrain, make_mesh,
                             param_pspec, pspec_for_config, sharding)
 from .parallel.parallel_config import Strategy
+from .profiling import note_program
 from .telemetry import active_log, sample_memory
 from .telemetry import fleet as _fleet
 from .telemetry import metrics as _tmetrics
 from .telemetry import rowfreq as _rowfreq
-from .telemetry.trace import start_span
+from .telemetry.trace import (NULL_SPAN, current_span, pop_span, push_span,
+                              start_span)
 from .tensor import Tensor, as_dtype
 
 
@@ -699,11 +701,23 @@ class FFModel:
                 elif t.uid in self._orig_out_dtypes:
                     t.dtype = self._orig_out_dtypes.pop(t.uid)
 
+        # Phase scopes (profiling.phase_of is the one reader of the
+        # naming rule; PERF.md §3 lists them).  The model scope sits
+        # INSIDE the differentiated functions, so the backward arrives
+        # as transpose(jvp(ff.step.model)).  Scopes are metadata only —
+        # and JAX's persistent compilation cache strips metadata from
+        # its key by default, so a process could be handed an
+        # executable compiled from another commit's names (measured:
+        # PERF.md §6, PR 26).  Whoever compiles these programs keys the
+        # cache on metadata too.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         def loss_and_preds(params, inputs, labels, rng, bn_state):
-            values, new_bn = self._apply(params, inputs, training=True,
-                                         rng=rng, bn_state=bn_state)
-            preds = _final(values)
-            loss = self._loss_fn(_loss_in(values), labels)
+            with jax.named_scope("ff.step.model"):
+                values, new_bn = self._apply(params, inputs, training=True,
+                                             rng=rng, bn_state=bn_state)
+                preds = _final(values)
+                loss = self._loss_fn(_loss_in(values), labels)
             return loss, (preds, new_bn)
 
         # only Dropout consumes per-step randomness; skipping the split for
@@ -846,10 +860,12 @@ class FFModel:
             for name in emb_names:
                 p[name] = {"embedding": tables[name],
                            "rows__": rows_dict[name]}
-            values, new_bn = self._apply(p, inputs, training=True, rng=rng,
-                                         bn_state=bn_state)
-            preds = _final(values)
-            return self._loss_fn(_loss_in(values), labels), (preds, new_bn)
+            with jax.named_scope("ff.step.model"):
+                values, new_bn = self._apply(p, inputs, training=True,
+                                             rng=rng, bn_state=bn_state)
+                preds = _final(values)
+                loss = self._loss_fn(_loss_in(values), labels)
+            return loss, (preds, new_bn)
 
         def _cache_gather(op, cache, slots):
             """Logical rows ``slots`` of an epoch/ladder cache, through
@@ -987,14 +1003,15 @@ class FFModel:
                           for op in sparse_emb}
                 slot_override = slot_override or {}
                 rows_dict = {}
-                for op in sparse_emb:
-                    slots = slot_override.get(op.name)
-                    if slots is None:
-                        rows_dict[op.name] = op.gather_rows(
-                            tables[op.name], inputs[id_name[op.name]])
-                    else:
-                        rows_dict[op.name] = _cache_gather(
-                            op, tables[op.name], slots)
+                with jax.named_scope("ff.step.gather"):
+                    for op in sparse_emb:
+                        slots = slot_override.get(op.name)
+                        if slots is None:
+                            rows_dict[op.name] = op.gather_rows(
+                                tables[op.name], inputs[id_name[op.name]])
+                        else:
+                            rows_dict[op.name] = _cache_gather(
+                                op, tables[op.name], slots)
                 grad_fn = jax.value_and_grad(loss_rows, argnums=(0, 1),
                                              has_aux=True)
                 (loss, (preds, new_bn)), (dgrads, rgrads) = grad_fn(
@@ -1008,8 +1025,9 @@ class FFModel:
                     for sn in lazy_slots:
                         opt_in[sn] = {k: v for k, v in opt_in[sn].items()
                                       if k not in emb_names}
-                new_params, new_opt = self.optimizer.update(
-                    dense_params, dgrads, opt_in)
+                with jax.named_scope("ff.step.dense_update"):
+                    new_params, new_opt = self.optimizer.update(
+                        dense_params, dgrads, opt_in)
                 lr = state.opt_state.get("lr", self.optimizer.lr)
                 new_params = dict(new_params)
                 if lazy_slots:
@@ -1018,37 +1036,45 @@ class FFModel:
                         new_opt[sn] = dict(new_opt[sn])
                 for op in sparse_emb:
                     slots = slot_override.get(op.name)
-                    if lazy_mode:
-                        upd, slot_upd = lazy_update(
-                            state, op, tables[op.name], slots,
-                            inputs, rows_dict[op.name], rgrads[op.name])
-                        for sn in lazy_slots:
-                            new_opt[sn][op.name] = {
-                                "embedding": slot_upd[sn]}
-                    elif slots is None:
-                        upd = op.scatter_apply(
-                            tables[op.name], inputs[id_name[op.name]],
-                            rgrads[op.name], -lr)
-                    elif op.storage_pack > 1:
-                        from .ops.pallas_scatter import sparse_view_update
-                        upd = sparse_view_update(
-                            tables[op.name], slots, rgrads[op.name], -lr,
-                            d=op.out_dim, allow_kernel=mesh_ is None)
-                    else:
-                        # allow_kernel doubles as the mesh-is-None bit:
-                        # under a mesh the packed view / pallas kernel
-                        # must not be used (layouts are SPMD-owned)
-                        upd = sparse_row_update(
-                            tables[op.name], slots, rgrads[op.name], -lr,
-                            allow_kernel=mesh_ is None)
+                    with jax.named_scope("ff.step.row_update"):
+                        if lazy_mode:
+                            upd, slot_upd = lazy_update(
+                                state, op, tables[op.name], slots,
+                                inputs, rows_dict[op.name],
+                                rgrads[op.name])
+                            for sn in lazy_slots:
+                                new_opt[sn][op.name] = {
+                                    "embedding": slot_upd[sn]}
+                        elif slots is None:
+                            upd = op.scatter_apply(
+                                tables[op.name], inputs[id_name[op.name]],
+                                rgrads[op.name], -lr)
+                        elif op.storage_pack > 1:
+                            from .ops.pallas_scatter import \
+                                sparse_view_update
+                            upd = sparse_view_update(
+                                tables[op.name], slots, rgrads[op.name],
+                                -lr, d=op.out_dim,
+                                allow_kernel=mesh_ is None)
+                        else:
+                            # allow_kernel doubles as the mesh-is-None
+                            # bit: under a mesh the packed view / pallas
+                            # kernel must not be used (layouts are
+                            # SPMD-owned)
+                            upd = sparse_row_update(
+                                tables[op.name], slots, rgrads[op.name],
+                                -lr, allow_kernel=mesh_ is None)
                     new_params[op.name] = {"embedding": upd}
             else:
                 grad_fn = jax.value_and_grad(loss_and_preds, has_aux=True)
                 (loss, (preds, new_bn)), grads = grad_fn(
                     state.params, inputs, labels, rng, state.bn_state)
-                new_params, new_opt = self.optimizer.update(
-                    state.params, grads, state.opt_state)
-            mets = compute_metrics(preds, labels, self.metrics, loss_type)
+                with jax.named_scope("ff.step.dense_update"):
+                    new_params, new_opt = self.optimizer.update(
+                        state.params, grads, state.opt_state)
+            with jax.named_scope("ff.step.metrics"):
+                mets = compute_metrics(preds, labels, self.metrics,
+                                       loss_type)
             mets["loss"] = loss
             new_state = TrainState(new_params, new_opt, new_bn, next_rng,
                                    state.step + 1)
@@ -1782,27 +1808,30 @@ class FFModel:
                         return _seg_writeback(p, r, child,
                                               s[0], s[1], s[2])
 
-                    params2[name] = {"embedding": _fetch(parent)}
+                    with jax.named_scope("ff.ladder.fetch"):
+                        params2[name] = {"embedding": _fetch(parent)}
                     wb.append((name, rowof, parent, _wback))
                     if lazy_slots:
                         for sn in lazy_slots:
                             slot_wb.append(
                                 (sn, name, rowof,
                                  opt2[sn][name]["embedding"], _wback))
-                        opt2 = _swap_slot_caches(opt2, name, _fetch)
+                        with jax.named_scope("ff.ladder.fetch"):
+                            opt2 = _swap_slot_caches(opt2, name, _fetch)
                 st2 = TrainState(params2, opt2, st.bn_state,
                                  st.rng, st.step)
                 st2, mets_k = ladder_scan(st2, in_k, lab_k, rest,
                                           a_k["next"])
                 new_p = dict(st2.params)
                 opt3 = st2.opt_state
-                for name, rowof, parent, _wback in wb:
-                    new_p[name] = {"embedding": _wback(
-                        parent, rowof, st2.params[name]["embedding"])}
-                for sn, name, rowof, parent, _wback in slot_wb:
-                    final = st2.opt_state[sn][name]["embedding"]
-                    opt3 = _swap_opt_entry(
-                        opt3, sn, name, _wback(parent, rowof, final))
+                with jax.named_scope("ff.ladder.writeback"):
+                    for name, rowof, parent, _wback in wb:
+                        new_p[name] = {"embedding": _wback(
+                            parent, rowof, st2.params[name]["embedding"])}
+                    for sn, name, rowof, parent, _wback in slot_wb:
+                        final = st2.opt_state[sn][name]["embedding"]
+                        opt3 = _swap_opt_entry(
+                            opt3, sn, name, _wback(parent, rowof, final))
                 st3 = TrainState(new_p, opt3, st2.bn_state,
                                  st2.rng, st2.step)
                 return st3, mets_k
@@ -1814,14 +1843,15 @@ class FFModel:
         def epoch_scan(state, inputs, labels, slots_ep, meta, arrs):
             """Scan one epoch's steps against the (cached) tables; returns
             (state, per-epoch folded metrics)."""
-            if meta:
-                state, mets = ladder_scan(state, inputs, labels, meta,
-                                          arrs)
-            else:
-                state, mets = jax.lax.scan(step_body, state,
-                                           (inputs, labels, slots_ep))
-            folded = {k: (jnp.mean(v) if k == "loss" else jnp.sum(v))
-                      for k, v in mets.items()}
+            with jax.named_scope("ff.ladder"):
+                if meta:
+                    state, mets = ladder_scan(state, inputs, labels, meta,
+                                              arrs)
+                else:
+                    state, mets = jax.lax.scan(step_body, state,
+                                               (inputs, labels, slots_ep))
+                folded = {k: (jnp.mean(v) if k == "loss" else jnp.sum(v))
+                          for k, v in mets.items()}
             return state, folded
 
         def ladder_plan(state, slots_ep, nb, region_src=None,
@@ -1888,6 +1918,17 @@ class FFModel:
             return TrainState(new_params, opt_state,
                               state.bn_state, state.rng, state.step)
 
+        def cached_plan(state, inputs, nb):
+            """What both epoch programs do before their scans: the
+            row-cache prologue, then the ladder's slot plans."""
+            with jax.named_scope("ff.cache.prologue"):
+                state, slots_ep, writebacks, orig, rsrc, rsingle = \
+                    cache_prologue(state, inputs)
+            with jax.named_scope("ff.cache.plan"):
+                meta, arrs = ladder_plan(state, slots_ep, nb, rsrc,
+                                         rsingle)
+            return state, slots_ep, writebacks, orig, meta, arrs
+
         def train_epoch(state: TrainState, inputs, labels):
             """Scan a whole epoch on device — one dispatch for nb steps.
 
@@ -1897,13 +1938,12 @@ class FFModel:
             dispatch.  ``inputs``: dict name -> (nb, batch, ...) stacked
             batches resident on device; ``labels``: (nb, batch, ...).
             """
-            state, slots_ep, writebacks, orig, rsrc, rsingle = \
-                cache_prologue(state, inputs)
-            meta, arrs = ladder_plan(state, slots_ep, labels.shape[0],
-                                     rsrc, rsingle)
+            state, slots_ep, writebacks, orig, meta, arrs = \
+                cached_plan(state, inputs, labels.shape[0])
             state, folded = epoch_scan(state, inputs, labels, slots_ep,
                                        meta, arrs)
-            return cache_epilogue(state, writebacks, orig), folded
+            with jax.named_scope("ff.cache.epilogue"):
+                return cache_epilogue(state, writebacks, orig), folded
 
         def train_epochs(state: TrainState, inputs, labels, n_epochs: int):
             """``n_epochs`` passes over the same stacked batches in ONE
@@ -1915,17 +1955,17 @@ class FFModel:
             across epochs performs the same adds on the same values.
             Returns per-epoch folded metrics stacked on a leading
             (n_epochs,) axis."""
-            state, slots_ep, writebacks, orig, rsrc, rsingle = \
-                cache_prologue(state, inputs)
-            meta, arrs = ladder_plan(state, slots_ep, labels.shape[0],
-                                     rsrc, rsingle)
+            state, slots_ep, writebacks, orig, meta, arrs = \
+                cached_plan(state, inputs, labels.shape[0])
 
             def ep_body(st, _):
                 return epoch_scan(st, inputs, labels, slots_ep, meta, arrs)
 
-            state, stacked = jax.lax.scan(ep_body, state, None,
-                                          length=n_epochs)
-            return cache_epilogue(state, writebacks, orig), stacked
+            with jax.named_scope("ff.ladder"):
+                state, stacked = jax.lax.scan(ep_body, state, None,
+                                              length=n_epochs)
+            with jax.named_scope("ff.cache.epilogue"):
+                return cache_epilogue(state, writebacks, orig), stacked
 
         donate = (0,) if donate_state else ()
         self._donate_argnums = donate  # telemetry: compile-event stats
@@ -2081,10 +2121,24 @@ class FFModel:
         (dlrm.cc:166-187).  ``donate=False`` keeps the input state's
         buffers alive after the call (the resilient loop's sentinel
         rejects anomalous updates by simply not adopting the result)."""
+        # the step's host time in two spans: placing the batch (H2D),
+        # then the jitted call.  Only inside a chain somebody traces (a
+        # current span on this thread, as fit's per-batch train.dispatch):
+        # a bare call would root a one-span trace of its own each time
+        log = active_log()
+        parent = current_span() if log is not None else None
+        sp = start_span("train.shard", parent=parent, annotate=True) \
+            if parent else NULL_SPAN
         inputs = {k: self.shard_batch(v) for k, v in inputs.items()}
         labels = self.shard_batch(labels)
+        sp.end()
         step_fn = self._train_step if donate else self._train_step_nodonate
+        if log is not None:
+            note_program(log, step_fn, (state, inputs, labels))
+        sp = start_span("train.launch", parent=parent, annotate=True) \
+            if parent else NULL_SPAN
         out = step_fn(state, inputs, labels)
+        sp.end()
         if self._hetero_ops:
             # host-side optimizer step for CPU-placed tables (their grads
             # were deposited by the backward callback this step)
@@ -2137,11 +2191,17 @@ class FFModel:
         inputs, labels = self.place_dataset(inputs, labels)
         log = active_log()
         t0 = time.perf_counter()
+        dspan = start_span("train.dispatch", attrs={"fn": "train_epoch"},
+                           annotate=True)
         bounds = self._epoch_chunk_bounds(labels.shape[0])
         if bounds is None:
+            if log is not None:
+                note_program(log, self._train_epoch,
+                             (state, inputs, labels))
             out = self._train_epoch(state, inputs, labels)
         else:
             out = self._run_epoch_chunks(state, inputs, labels, bounds)
+        dspan.end()
         if log is not None:
             # dispatch-only wall (fenced=False): the scan returns before
             # the device finishes; fenced walls come from fit/bench which
@@ -2166,8 +2226,14 @@ class FFModel:
         inputs, labels = self.place_dataset(inputs, labels)
         log = active_log()
         t0 = time.perf_counter()
+        dspan = start_span("train.dispatch",
+                           attrs={"fn": "train_epochs",
+                                  "epochs": int(epochs)}, annotate=True)
         bounds = self._epoch_chunk_bounds(labels.shape[0])
         if bounds is None:
+            if log is not None:
+                note_program(log, self._train_epochs,
+                             (state, inputs, labels, int(epochs)))
             out = self._train_epochs(state, inputs, labels, int(epochs))
         else:
             mets = []
@@ -2178,6 +2244,7 @@ class FFModel:
             stacked = {k: np.stack([np.asarray(m[k]) for m in mets])
                        for k in (mets[0] if mets else ())}
             out = (state, stacked)
+        dspan.end()
         if log is not None:
             # dispatch-only wall — see train_epoch's emission
             nb = int(labels.shape[0])
@@ -2251,9 +2318,13 @@ class FFModel:
         maps chunk length -> precompiled epoch executable (fit's untimed
         AOT compile)."""
         sums, loss_num, n_steps = {}, 0.0, 0
+        log = active_log()
         for lo, hi in bounds:
             cin = {k: v[lo:hi] for k, v in inputs.items()}
             fn = (aot or {}).get(hi - lo, self._train_epoch)
+            if log is not None:  # an AOT executable ran the same program
+                note_program(log, self._train_epoch,
+                             (state, cin, labels[lo:hi]))
             state, mets = fn(state, cin, labels[lo:hi])
             w = hi - lo
             for k, v in mets.items():
@@ -2470,15 +2541,17 @@ class FFModel:
             first = dataloader.peek()
             state, _ = self.train_step(state, first[0], first[1])
             device_fence(state.step)
-        def aot_compile(fn_name, build):
+        def aot_compile(fn_name, fn, args):
             """One explicit lower().compile() with its wall time and
             donated-argument count recorded as a ``compile`` telemetry
             event (the jax.monitoring hook sees the same compile as a
-            bare backend_compile; this event adds the attribution)."""
+            bare backend_compile; this event adds the attribution),
+            and the program named for ``profiling.program_phases``."""
             tc = time.perf_counter()
-            exe = build()
+            exe = fn.lower(*args).compile()
             log = active_log()
             if log is not None:
+                note_program(log, fn, args)
                 log.emit("compile", kind="aot", fn=fn_name,
                          duration_s=time.perf_counter() - tc,
                          donated_args=len(getattr(self, "_donate_argnums",
@@ -2496,15 +2569,11 @@ class FFModel:
                 # no per-epoch host work pending: fuse ALL epochs into ONE
                 # dispatch (train_epochs) — launch overhead + row-cache
                 # sweeps amortize over the whole run
-                fused_fn = aot_compile(
-                    "train_epochs",
-                    lambda: self._train_epochs.lower(
-                        state, *scan_data, epochs).compile())
+                fused_fn = aot_compile("train_epochs", self._train_epochs,
+                                       (state, *scan_data, epochs))
             elif chunk_bounds is None:
-                scan_fn = aot_compile(
-                    "train_epoch",
-                    lambda: self._train_epoch.lower(state,
-                                                    *scan_data).compile())
+                scan_fn = aot_compile("train_epoch", self._train_epoch,
+                                      (state, *scan_data))
             else:
                 # chunked epoch (epoch row-cache): precompile each
                 # distinct chunk shape
@@ -2514,10 +2583,9 @@ class FFModel:
                     if hi - lo not in chunk_aot:
                         chunk_aot[hi - lo] = aot_compile(
                             f"train_epoch[chunk={hi - lo}]",
-                            lambda lo=lo, hi=hi: self._train_epoch.lower(
-                                state,
-                                {k: v[lo:hi] for k, v in sin.items()},
-                                slab[lo:hi]).compile())
+                            self._train_epoch,
+                            (state, {k: v[lo:hi] for k, v in sin.items()},
+                             slab[lo:hi]))
         # span chain (telemetry/trace.py): train.fit covers the timed
         # region (warmup/AOT builds excluded — same protocol as the
         # step event's wall); each epoch and each dispatched program
@@ -2531,7 +2599,8 @@ class FFModel:
             # never loop on host, so sample the staged id tensors once
             # here — OUTSIDE the timed window, off the traced graph
             _rowfreq.observe_dataset(scan_data[0])
-        fit_span = start_span("train.fit", attrs={"epochs": int(epochs)})
+        fit_span = start_span("train.fit", attrs={"epochs": int(epochs)},
+                              annotate=True)
         t0 = time.perf_counter()
         pstep = 0                 # per-batch host step counter: the
         #                           global-step key fleet merge aligns on
@@ -2543,7 +2612,7 @@ class FFModel:
             # single-dispatch multi-epoch run (no callbacks to honor)
             dspan = start_span("train.dispatch", parent=fit_span,
                                attrs={"epochs": int(epochs),
-                                      "fused": True})
+                                      "fused": True}, annotate=True)
             state, stacked = fused_fn(state, *scan_data)
             dspan.end()
             if "loss" in stacked and epochs > 0:
@@ -2560,7 +2629,7 @@ class FFModel:
         try:
             for epoch in range(epochs) if fused_fn is None else ():
                 ep_span = start_span("train.epoch", parent=fit_span,
-                                     attrs={"epoch": epoch})
+                                     attrs={"epoch": epoch}, annotate=True)
                 if epoch > 0:
                     for cb in cbs:
                         cb.on_epoch_begin(epoch)
@@ -2568,7 +2637,8 @@ class FFModel:
                 acc.reset()
                 if scan_data is not None:
                     dspan = start_span("train.dispatch", parent=ep_span,
-                                       attrs={"epoch": epoch})
+                                       attrs={"epoch": epoch},
+                                       annotate=True)
                     if chunk_bounds is not None:
                         state, mets = self._run_epoch_chunks(
                             state, scan_data[0], scan_data[1], chunk_bounds,
@@ -2600,10 +2670,17 @@ class FFModel:
                         dspan = start_span("train.dispatch",
                                            parent=ep_span,
                                            attrs={"epoch": epoch,
-                                                  "it": it})
+                                                  "it": it},
+                                           annotate=True)
+                        # train_step's own spans (train.shard,
+                        # train.launch) parent to the thread's current
+                        push_span(dspan)
                         td = time.perf_counter()
-                        state, mets = self.train_step(state, inputs,
-                                                      labels)
+                        try:
+                            state, mets = self.train_step(state, inputs,
+                                                          labels)
+                        finally:
+                            pop_span(dspan)
                         dwall = time.perf_counter() - td
                         dispatch_s += dwall
                         dspan.end()

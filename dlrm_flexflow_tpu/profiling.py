@@ -13,7 +13,11 @@ TPU-native equivalent of the reference's profiling stack (SURVEY §5.1):
 from __future__ import annotations
 
 import contextlib
+import itertools
+import os
+import re
 import time
+import weakref
 from typing import Dict
 
 import jax
@@ -92,29 +96,53 @@ MODULES_TRACK = "XLA Modules"
 OPS_TRACK = "XLA Ops"
 
 
-def parse_device_trace(logdir: str):
-    """Parse the NEWEST ``*.trace.json.gz`` under ``logdir``.
+def _closed_slices(track):
+    """Walk one track's nested slices and yield ``(slice, self_us, name
+    stack)`` as each closes.  Sorted by (start, longest first) a parent
+    precedes the children it contains; their durations are subtracted
+    from it.  The name stack is the slice's ``args.tf_op`` (the
+    instruction's ``op_name`` as the profiler kept it, with a trailing
+    ``:``) or, where the profiler kept none though the instruction has
+    one — every ``while`` — the longest common prefix of its children's:
+    a loop body's instructions all start with the loop's own stack."""
+    open_ = []  # [end_ts, slice, children's dur, their common prefix]
 
-    Returns ``(trace_path, process_names, {op_name: self_us}, busy_ms)``.
+    def close():
+        _end, e, kids, prefix = open_.pop()
+        own = e.get("args", {}).get("tf_op", "").rstrip(":")
+        stack = own or "/".join(prefix or ())
+        if open_ and stack:
+            parts, seen = stack.split("/"), open_[-1][3]
+            open_[-1][3] = parts if seen is None else \
+                os.path.commonprefix([seen, parts])
+        return e, e.get("dur", 0.0) - kids, stack
 
-    ``busy_ms`` is the "XLA Modules" track total of a ``/device:TPU:<n>``
-    process — the wall time that chip was occupied by a program, the
-    number the bench records as ``device_busy_ms``.  With several chips
-    in the trace it is the BUSIEST chip's (an SPMD program occupies
-    every chip for about the same time; a sum would count it once per
-    chip), and ``self_us`` is that same chip's.
+    for e in sorted(track, key=lambda e: (e["ts"], -e.get("dur", 0.0))):
+        ts, dur = e["ts"], e.get("dur", 0.0)
+        while open_ and open_[-1][0] <= ts:
+            yield close()
+        if open_:
+            open_[-1][2] += dur
+        open_.append([ts + dur, e, 0.0, None])
+    while open_:
+        yield close()
 
-    ``self_us`` is per-op SELF time on the "XLA Ops" track: op slices
-    NEST (a scan's ``while`` slice spans every op executed inside it —
-    Ops-track raw sum 4.8 ms against 2.6 ms of module time in a 16-step
-    epoch), so each slice's children are subtracted before accumulating.
-    A trace with a Modules track but no Ops track attributes at module
-    granularity.
 
-    Nothing else is substituted: a trace with no TPU process, or whose
-    TPU process has no "XLA Modules" track, raises ``ValueError`` (a CPU
-    trace has only ``/host:CPU``).  Shared by
-    ``scripts/profile_headline.py`` and ``bench.py``."""
+def _self_times(tracks, key) -> Dict[str, float]:
+    """Self time in us per ``key(slice, name stack)`` over some tracks."""
+    tot: Dict[str, float] = {}
+    for track in tracks:
+        for e, us, stack in _closed_slices(track):
+            k = key(e, stack)
+            tot[k] = tot.get(k, 0.0) + us
+    return tot
+
+
+def _busiest_chip(logdir: str):
+    """``(trace_path, process_names, op tracks, busy_us)`` of the NEWEST
+    ``*.trace.json.gz`` under ``logdir``: the busiest chip's "XLA Ops"
+    slices, one list per track (its "XLA Modules" slices where the
+    trace has no Ops track), and that chip's "XLA Modules" total."""
     import gzip
     import json
     import os
@@ -164,28 +192,219 @@ def parse_device_trace(logdir: str):
         raise ValueError(
             f'no "{MODULES_TRACK}" slices on a TPU process in {path} '
             f"(tracks: {tracks})")
-
-    # self time per op: sort by (ts, -dur) so a parent precedes the
-    # children it contains; a stack tracks open slices per track
-    tot = {}
     by_tid = {}
     for e in _slices(_track(pid, OPS_TRACK) or _track(pid, MODULES_TRACK)):
         by_tid.setdefault((e["pid"], e.get("tid")), []).append(e)
-    for track in by_tid.values():
-        track.sort(key=lambda e: (e["ts"], -e.get("dur", 0.0)))
-        stack = []  # [end_ts, children_dur, name, dur]
-        for e in track:
-            ts, dur = e["ts"], e.get("dur", 0.0)
-            while stack and stack[-1][0] <= ts:
-                _end, kids, nm, d = stack.pop()
-                tot[nm] = tot.get(nm, 0.0) + (d - kids)
-            if stack:
-                stack[-1][1] += dur
-            stack.append([ts + dur, 0.0, e["name"], dur])
-        while stack:
-            _end, kids, nm, d = stack.pop()
-            tot[nm] = tot.get(nm, 0.0) + (d - kids)
-    return path, pnames, tot, busy_us[pid] / 1e3
+    return path, pnames, list(by_tid.values()), busy_us[pid]
+
+
+def parse_device_trace(logdir: str):
+    """Parse the NEWEST ``*.trace.json.gz`` under ``logdir``.
+
+    Returns ``(trace_path, process_names, {op_name: self_us}, busy_ms)``.
+
+    ``busy_ms`` is the "XLA Modules" track total of a ``/device:TPU:<n>``
+    process — the wall time that chip was occupied by a program, the
+    number the bench records as ``device_busy_ms``.  With several chips
+    in the trace it is the BUSIEST chip's (an SPMD program occupies
+    every chip for about the same time; a sum would count it once per
+    chip), and ``self_us`` is that same chip's.
+
+    ``self_us`` is per-op SELF time on the "XLA Ops" track: op slices
+    NEST (a scan's ``while`` slice spans every op executed inside it —
+    Ops-track raw sum 4.8 ms against 2.6 ms of module time in a 16-step
+    epoch), so each slice's children are subtracted before accumulating.
+    A trace with a Modules track but no Ops track attributes at module
+    granularity.
+
+    Nothing else is substituted: a trace with no TPU process, or whose
+    TPU process has no "XLA Modules" track, raises ``ValueError`` (a CPU
+    trace has only ``/host:CPU``).  Shared by
+    ``scripts/profile_headline.py`` and ``bench.py``."""
+    path, pnames, tracks, busy_us = _busiest_chip(logdir)
+    return path, pnames, \
+        _self_times(tracks, lambda e, _stack: e["name"]), busy_us / 1e3
+
+
+def parse_device_trace_phases(logdir: str):
+    """``parse_device_trace``'s sibling: ``(trace_path, {phase: self_us},
+    busy_ms)`` of the same trace and chip, each op slice counted under
+    ``phase_of`` its name stack (``_closed_slices``; a slice with none
+    is ``unattributed``).  The operator's route to what
+    ``program_phases`` gives the benchmark: on one trace the two agree
+    (tests/test_phases.py)."""
+    path, _pnames, tracks, busy_us = _busiest_chip(logdir)
+    return path, _self_times(tracks, lambda _e, stack: phase_of(stack)), \
+        busy_us / 1e3
+
+
+# --------------------------------------------------------------- phases
+#: the phase of an instruction whose name stack holds no phase scope
+UNATTRIBUTED = "unattributed"
+#: a phase scope: model.py::_compile_body opens them, all under the one
+#: prefix ``ff.`` so that a graph op's own scope is never taken for one
+_PHASE = re.compile(r"(?<![\w.])ff\.[a-z_]+(?:\.[a-z_]+)*")
+_WRAPPER = re.compile(r"([A-Za-z_]\w*)?\(|\)")
+
+
+def phase_of(op_name: str) -> str:
+    """The phase an HLO instruction belongs to, from its ``op_name``
+    name stack: the INNERMOST ``ff.*`` scope, seen through ``jvp(...)``
+    / ``transpose(...)`` wrappers; inside a ``transpose(`` it is the
+    scope's backward and reads ``<scope>.bwd``; ``unattributed`` when
+    the stack names no phase.  The one place that knows the naming
+    rule (PERF.md §3 lists the scopes)::
+
+        jit(f)/ff.ladder/while/body/ff.step.gather/gather -> ff.step.gather
+        .../transpose(jvp(ff.step.model))/top_1/dot_general
+                                                    -> ff.step.model.bwd
+        jit(f)/jit(_where)/select_n                 -> unattributed
+
+    XLA joins the stacks of instructions it merged with ``;``: the
+    first is the full one and decides."""
+    stack = op_name.split(";", 1)[0]
+    found = None
+    for found in _PHASE.finditer(stack):
+        pass
+    if found is None:
+        return UNATTRIBUTED
+    wrappers = []
+    for tok in _WRAPPER.finditer(stack, 0, found.start()):
+        if tok.group(0) == ")":
+            if wrappers:
+                wrappers.pop()
+        else:
+            wrappers.append(tok.group(1))
+    return found.group(0) + (".bwd" if "transpose" in wrappers else "")
+
+
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_FUSED = re.compile(r"\sfusion\(.*\bcalls=%?([\w.\-]+)")
+_CALLED = re.compile(r"\b(?:body|condition|to_apply|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)"
+                     r"|\bbranch_computations=\{([^}]*)\}")
+_OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
+
+
+def hlo_phases(hlo_text: str) -> Dict[str, str]:
+    """``{instruction name: phase}`` of an optimized HLO module's text:
+    every instruction of every computation but the fused ones (a
+    profiler trace has one slice per instruction that runs on its own;
+    what sits inside a fusion never shows), through ``phase_of`` its
+    ``metadata={op_name=...}``.  An instruction the compiler made and
+    gave no name (a copy, a converted constant) takes the phase of the
+    instruction that calls its computation — a ``while`` its body and
+    condition, a conditional its branches — as the profiler's
+    ``tf_op`` does; in the entry computation it is ``unattributed``."""
+    lines = hlo_text.splitlines()
+    fused = {m.group(1) for m in map(_FUSED.search, lines) if m}
+    named: Dict[str, str] = {}      # instruction -> its own phase
+    unnamed: Dict[str, str] = {}    # instruction -> its computation
+    callers: Dict[str, set] = {}    # computation -> calling instructions
+    computation = None
+    for line in lines:
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                computation = None if c.group(1) in fused else c.group(1)
+            continue
+        if computation is None:
+            continue
+        op_name = _OP_NAME.search(line)
+        if op_name:
+            named[m.group(1)] = phase_of(op_name.group(1))
+        else:
+            unnamed[m.group(1)] = computation
+        for one, several in _CALLED.findall(line):
+            for callee in [one] if one else re.findall(r"[\w.\-]+", several):
+                callers.setdefault(callee, set()).add(m.group(1))
+
+    def resolve(name):  # HLO's call graph has no cycle
+        if name in named:
+            return named[name]
+        got = {resolve(c) for c in callers.get(unnamed[name], ())}
+        return got.pop() if len(got) == 1 else UNATTRIBUTED
+
+    return {name: resolve(name) for name in [*named, *unnamed]}
+
+
+class _Program:
+    """One jitted program a training wrapper dispatched under an active
+    EventLog: the function (weakly: the registry must not keep a model
+    alive), its abstract arguments, the log it was last named in, and
+    its phase map once asked for."""
+
+    __slots__ = ("name", "fn", "args", "log", "phases")
+
+    def __init__(self, name, fn, args):
+        self.name, self.fn, self.args = name, weakref.ref(fn), args
+        self.log = self.phases = None
+
+
+#: programs by signature and by name.  Filled only under an active
+#: EventLog (FFModel's wrappers call ``note_program`` inside the ``log
+#: is not None`` check they already make).
+_by_signature: Dict[tuple, _Program] = {}
+_programs: Dict[str, _Program] = {}
+_MAX_PROGRAMS = 256
+_program_ids = itertools.count(1)
+
+
+def _abstract(x):
+    """What jit keys its own cache on: shape, dtype and, for a
+    COMMITTED array, its sharding (an uncommitted one leaves the
+    placement to jit; naming its device would lower another program
+    than the one that ran).  Anything else is a static argument."""
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+    return x
+
+
+def note_program(log, fn, args: tuple) -> str:
+    """Remember that the jitted ``fn`` was dispatched with ``args`` and
+    name it in ``log``: one ``program`` event per program and log (so
+    the per-step path pays a signature lookup, not an event).  Keeps
+    shapes, dtypes, shardings and static arguments; reads no device
+    value.  Returns the program's name, ``<fn's name>#<n>``."""
+    leaves, treedef = jax.tree_util.tree_flatten(args)
+    sig = (id(fn), treedef,
+           tuple((x.shape, x.dtype, x.sharding if x.committed else None)
+                 if isinstance(x, jax.Array) else x for x in leaves))
+    prog = _by_signature.get(sig)
+    if prog is None or prog.fn() is not fn:
+        if len(_programs) >= _MAX_PROGRAMS:  # drop what died, else oldest
+            dead = [k for k, p in _by_signature.items() if p.fn() is None]
+            for k in dead or list(_by_signature)[:1]:
+                _programs.pop(_by_signature.pop(k).name, None)
+        prog = _Program(f"{fn.__name__}#{next(_program_ids)}", fn,
+                        treedef.unflatten([_abstract(x) for x in leaves]))
+        _by_signature[sig] = _programs[prog.name] = prog
+    if prog.log is None or prog.log() is not log:
+        prog.log = weakref.ref(log)
+        log.emit("program", name=prog.name, fn=fn.__name__)
+    return prog.name
+
+
+def program_phases(name: str) -> Dict[str, str]:
+    """``{HLO instruction name: phase}`` of the program a ``program``
+    event named: ``hlo_phases`` of its optimized HLO, from
+    ``.lower(<noted abstract arguments>).compile().as_text()``.  Built
+    when first asked and memoised; ask AFTER a measured window (the
+    second ``lower().compile()`` is served by the lowering JAX holds or
+    by the persistent cache — PERF.md §6 has the measured cost — but it
+    is host work).  The executable is dropped once parsed.  Raises
+    ``KeyError`` for a name never noted and ``RuntimeError`` when the
+    program's function is gone."""
+    prog = _programs[name]
+    if prog.phases is None:
+        fn = prog.fn()
+        if fn is None:
+            raise RuntimeError(f"the function of program {name!r} is gone")
+        prog.phases = hlo_phases(fn.lower(*prog.args).compile().as_text())
+    return prog.phases
 
 
 def traced_device_busy_ms(fn, logdir: str | None = None) -> float:

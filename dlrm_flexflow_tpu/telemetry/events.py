@@ -35,6 +35,11 @@ def _jsonable(v):
     """Coerce numpy/jax scalars and arrays to plain JSON types so the
     schema's isinstance checks and ``json.dumps`` both see native
     Python values."""
+    kind = type(v)
+    if kind is str or kind is int or kind is bool or v is None:
+        return v  # what most fields are: skip the isinstance ladder
+    if kind is float:
+        return v if v - v == 0.0 else None  # NaN/Inf, as below
     if isinstance(v, (np.bool_,)):
         return bool(v)
     if isinstance(v, np.integer):
@@ -77,6 +82,7 @@ class EventLog:
         self.path = path
         self.stamp = dict(stamp) if stamp else None
         self._ring: deque = deque(maxlen=ring)
+        self._valid_shapes: set = set()
         self._lock = threading.Lock()
         self._fh = open(path, mode) if path else None
 
@@ -97,10 +103,19 @@ class EventLog:
         if self.stamp:
             for k, v in self.stamp.items():
                 ev.setdefault(k, v)
-        errs = validate_event(ev)
-        if errs:
-            raise ValueError(
-                f"invalid telemetry event: {'; '.join(errs)} — event {ev!r}")
+        # the schema's verdict depends on the event's type, its phase
+        # and each field's name and runtime type, nothing else: sweep
+        # once per such shape (a per-step producer repeats one shape)
+        phase = ev.get("phase")
+        shape = (type, phase if phase.__class__ is str else None,
+                 *[(k, v.__class__) for k, v in ev.items()])
+        if shape not in self._valid_shapes:
+            errs = validate_event(ev)
+            if errs:
+                raise ValueError(f"invalid telemetry event: "
+                                 f"{'; '.join(errs)} — event {ev!r}")
+            if len(self._valid_shapes) < 4096:
+                self._valid_shapes.add(shape)
         with self._lock:
             self._ring.append(ev)
             if self._fh is not None:

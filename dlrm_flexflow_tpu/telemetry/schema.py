@@ -340,11 +340,29 @@ SCHEMA: Dict[str, dict] = {
     # "deadline", "cancelled", "rejected"); ``thread``/``tid`` name the
     # thread that OPENED the span (the export-trace CLI's per-thread
     # tracks).
+    # ``start_mono_s`` is the same start on ``time.perf_counter()``,
+    # the clock ``dur_us`` is taken on and the one a profiler trace's
+    # host events can be lined up with (absent on ``record_span``'s
+    # already-timed spans).  Spans of the training path
+    # (``train.fit`` / ``train.epoch`` / ``train.dispatch`` /
+    # ``train.shard`` / ``train.launch``) also hold a
+    # ``jax.profiler.TraceAnnotation`` of their name while open.
     "span": {
         "required": {"name": str, "trace_id": str, "span_id": str,
                      "start_s": float, "dur_us": float},
         "optional": {"parent_id": str, "status": str, "attrs": dict,
-                     "thread": str, "tid": int},
+                     "thread": str, "tid": int, "start_mono_s": float},
+    },
+    # one jitted training program named at its first dispatch under
+    # this log (FFModel.train_step / train_epoch / train_epochs through
+    # profiling.note_program): ``name`` is the key
+    # ``profiling.program_phases`` takes to say which phase scope of
+    # ``model.py::_compile_body`` each HLO instruction of that program
+    # belongs to; ``fn`` the wrapper's jitted function.  One event per
+    # program and log, not per dispatch.
+    "program": {
+        "required": {"name": str},
+        "optional": {"fn": str},
     },
 }
 

@@ -95,7 +95,13 @@ field without the schema and the report CLI seeing it:
      rising burn spends budget faster, so it must never read as an
      improvement), and docs/slo.md must document the spec
      mini-language, the burn-rate windows, the tail exemplars, and the
-     breach → flight-record flow.
+     breach → flight-record flow;
+ 14. phase contract — every ``jax.named_scope`` literal that
+     ``model.py`` opens must be a phase by ``profiling.phase_of``'s
+     naming rule (and classify as itself) and be documented in
+     docs/telemetry.md, the ``program`` event type and the span's
+     ``start_mono_s`` must be in the schema, and the training path's
+     span names (``train.fit`` … ``train.launch``) documented.
 
 Exit 0 when clean; prints one line per violation and exits 1 otherwise.
 """
@@ -759,6 +765,49 @@ def check_slo_contract(doc_path: str) -> list:
     return errs
 
 
+#: spans of the training path, each held as a profiler annotation too
+TRAIN_SPANS = ("train.fit", "train.epoch", "train.dispatch",
+               "train.shard", "train.launch")
+
+
+def check_phase_contract(doc_path: str) -> list:
+    from dlrm_flexflow_tpu.profiling import UNATTRIBUTED, phase_of
+
+    errs = []
+    with open(doc_path) as f:
+        doc = f.read()
+    with open(os.path.join(REPO, "dlrm_flexflow_tpu", "model.py")) as f:
+        tree = ast.parse(f.read())
+    scopes = set()
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "named_scope" and node.args
+                and isinstance(node.args[0], ast.Constant)):
+            scopes.add(node.args[0].value)
+    if not scopes:
+        errs.append("model.py opens no jax.named_scope literal: the "
+                    "phase scopes of _compile_body are gone")
+    for scope in sorted(scopes):
+        if phase_of(f"jit(f)/{scope}/add") != scope:
+            errs.append(f"model.py scope {scope!r} is not a phase by "
+                        f"profiling.phase_of's naming rule")
+        if f"`{scope}`" not in doc:
+            errs.append(f"docs/telemetry.md does not document the phase "
+                        f"scope `{scope}`")
+    if phase_of("jit(f)/jit(_where)/select_n") != UNATTRIBUTED:
+        errs.append("phase_of names a phase where the stack holds none")
+    if "program" not in SCHEMA:
+        errs.append("schema lacks the `program` event type")
+    if "start_mono_s" not in SCHEMA["span"]["optional"]:
+        errs.append("span schema lacks `start_mono_s`")
+    for name in TRAIN_SPANS + ("phase_of", "program_phases",
+                               "parse_device_trace_phases"):
+        if f"`{name}`" not in doc and f"`profiling.{name}" not in doc:
+            errs.append(f"docs/telemetry.md does not document `{name}`")
+    return errs
+
+
 def main() -> int:
     doc = os.path.join(REPO, "docs", "telemetry.md")
     errs = (check_self_consistency()
@@ -780,7 +829,8 @@ def main() -> int:
             + check_storage_contract(os.path.join(REPO, "docs",
                                                   "storage.md"))
             + check_slo_contract(os.path.join(REPO, "docs",
-                                              "slo.md")))
+                                              "slo.md"))
+            + check_phase_contract(doc))
     for e in errs:
         print(f"check_telemetry_schema: {e}")
     if errs:

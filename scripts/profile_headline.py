@@ -107,6 +107,15 @@ def main():
     for name, dur in sorted(tot.items(), key=lambda kv: -kv[1])[:top]:
         print(f"{dur/1e3:10.2f} ms  {dur/total*100:5.1f}%  "
               f"{dur/steps:8.1f} us/step  {name[:110]}")
+    # the same self time by phase of the compiled program (the scopes of
+    # model.py::_compile_body, read off each slice's name stack)
+    from dlrm_flexflow_tpu.profiling import parse_device_trace_phases
+
+    _path, by_phase, _busy = parse_device_trace_phases(logdir)
+    print("# by phase:")
+    for phase, dur in sorted(by_phase.items(), key=lambda kv: -kv[1]):
+        print(f"{dur/1e3:10.2f} ms  {dur/total*100:5.1f}%  "
+              f"{dur/steps:8.1f} us/step  {phase}")
 
 
 if __name__ == "__main__":
