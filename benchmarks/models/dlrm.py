@@ -13,6 +13,17 @@ from benchmarks.lib import traffic as traffic_lib
 from benchmarks.reference import dlrm_ref
 
 
+#: each number of ``check``'s report that decides ``correct``, beside the
+#: limit it is held to (``reference/dlrm_ref.py`` says why each); ``run.py``
+#: prints the pairs last, on stderr and in the result's line
+LIMITS = {"mlp_update_err": dlrm_ref.MLP_RTOL,
+          "row_err_max": dlrm_ref.ROW_MAX,
+          "row_err_median": dlrm_ref.ROW_MEDIAN,
+          "dup_row_err_q1": dlrm_ref.DUP_Q1,
+          "moved_untouched": 0,
+          "loss_err": dlrm_ref.LOSS_RTOL}
+
+
 def build(config: dict, batch: int, seed: int, devices):
     """The program's model and initial state for a configuration file:
     ``build_dlrm`` -> ``compile`` -> ``init(seed)``, the calls
@@ -84,6 +95,26 @@ def _stray_rows(after, before, named, d: int):
     return stray
 
 
+def _restricted(rows, tix, pos, tables: int):
+    """The reference's tables ``(T, most rows named in one, d)`` holding
+    the named ``rows (U, d)``, pair ``i`` at ``[tix[i], pos[i]]``
+    (``dlrm_ref.restrict``)."""
+    return jnp.zeros((tables, int(pos.max()) + 1, rows.shape[1]),
+                     jnp.float32).at[tix, pos].set(rows)
+
+
+def _reference_steps(ref, batches, lr: float, dtype: str):
+    """``dlrm_ref.sgd_step`` over ``batches = (dense, ids, labels)``,
+    each stacked by step, with matmul operands in ``dtype``.  Returns
+    ``(ref, [loss])``."""
+    step = jax.jit(dlrm_ref.sgd_step, static_argnums=5)
+    losses = []
+    for dense, ids, labels in zip(*batches):
+        ref, loss = step(ref, dense, ids, labels, lr, dtype)
+        losses.append(float(loss))
+    return ref, losses
+
+
 def check(config: dict, traffic: dict, model, state, seed: int, run_steps,
           k: int):
     """Send ``k`` further seeded batches through the reference and
@@ -126,17 +157,11 @@ def check(config: dict, traffic: dict, model, state, seed: int, run_steps,
     del table_before
 
     ref = {"bot": before["bot"], "top": before["top"],
-           "emb": jnp.zeros((len(sizes), int(pos.max()) + 1, d),
-                            jnp.float32).at[tix, pos].set(before["rows"])}
-    step = jax.jit(dlrm_ref.sgd_step, static_argnums=5)
-    lr = float(config["ffconfig"]["learning_rate"])
-    dtype = config["ffconfig"]["compute_dtype"]
-    losses_want = []
-    for i in range(k):
-        ref, loss = step(ref, jax.device_put(inputs["dense"][i], dev),
-                         jax.device_put(ids_ref[i], dev),
-                         jax.device_put(labels[i], dev), lr, dtype)
-        losses_want.append(float(loss))
+           "emb": _restricted(before["rows"], tix, pos, len(sizes))}
+    ref, losses_want = _reference_steps(
+        ref, (inputs["dense"], ids_ref, labels),
+        float(config["ffconfig"]["learning_rate"]),
+        config["ffconfig"]["compute_dtype"])
     want = {"bot": ref["bot"], "top": ref["top"], "rows": ref["emb"][tix, pos]}
     # the path folds its steps' losses as it likes (a scanned epoch
     # returns one mean): fold the reference's the same way
@@ -146,3 +171,42 @@ def check(config: dict, traffic: dict, model, state, seed: int, run_steps,
     ok, report = dlrm_ref.compare(before, got, want, losses_got, losses_want,
                                   k, touches)
     return ok, report, state
+
+
+#: the nearest precision below the one a configuration states: what a
+#: later PR would be tempted to compute in
+LOWER = {"float32": "bfloat16", "bfloat16": "float8_e4m3fn"}
+
+
+def control_steps(config: dict):
+    """The control of the comparison: the reference put in the program's
+    place, with its matmul operands rounded to ``LOWER`` of the
+    configuration's ``compute_dtype``.  Returns a ``run_steps`` for
+    ``check``, which has to come out as not correct (``run.py --control
+    1``; the benchmark's own runs never take it).  The steps' results go
+    back into the program's state through ``get_weights`` /
+    ``set_weights``, the table by way of the host."""
+    shape = config["model"]
+    lr = float(config["ffconfig"]["learning_rate"])
+    dtype = LOWER[config["ffconfig"]["compute_dtype"]]
+
+    def run_steps(model, state, inputs, labels):
+        tix, rix, pos, ids_ref, _ = dlrm_ref.restrict(inputs["sparse"])
+        table = np.array(model.get_weights(state, "emb", "embedding"))
+        ref = dict(_mlps(state.params, shape),
+                   emb=_restricted(table[tix, rix], tix, pos, table.shape[0]))
+        ref, losses = _reference_steps(
+            ref, (inputs["dense"], ids_ref, labels), lr, dtype)
+        table[tix, rix] = np.asarray(ref["emb"][tix, pos])
+        # in the storage layout already: a (.., 64) view of 2 GB on the
+        # device would be padded to 4 GB (see ``_rows``)
+        state = model.set_weights(
+            state, "emb", "embedding",
+            table.reshape(state.params["emb"]["embedding"].shape))
+        for name in ("bot", "top"):
+            for i, (w, b) in enumerate(ref[name]):
+                state = model.set_weights(state, f"{name}_{i}", "kernel", w)
+                state = model.set_weights(state, f"{name}_{i}", "bias", b)
+        return state, losses
+
+    return run_steps

@@ -11,10 +11,12 @@ reference); a per-layer metric is ``layer_metrics/<name>.py``.  A new
 cell or metric is new files and new entries, never an edit here.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, with ``--trace 1``,
-``breakdown``.  Lines before it say what a strange number would need:
-the device, the compile cache, compiles inside the window, the walls of
-the window's dispatches, the comparison's report.
+``failed``, ``metrics``, ``device``, with ``--trace 1`` ``breakdown``,
+and last ``compared``: each number that decided ``correct`` beside its
+limit (also the last line of stderr).  Lines before it say what a
+strange number would need: the device, the compile cache, compiles
+inside the window, the walls of the window's dispatches, the
+comparison's report.
 """
 
 from __future__ import annotations
@@ -126,11 +128,13 @@ def _traced(driver, ctx, seconds: float, limit: int):
 
 
 def measure(cell: dict, seed: int, seconds: float, trace: bool,
-            peaks=None) -> dict:
+            peaks=None, check_steps=None) -> dict:
     """Set up, run the window (traced: a short one), read the memory,
     compare with the reference.  Returns the result object.  Knows no
     device check and no cache: ``main`` does those, and the tests run
-    this on the CPU at a tiny size."""
+    this on the CPU at a tiny size.  ``check_steps`` stands in for the
+    driver's in the comparison: the control, or a test's planted fault,
+    either of which has to read ``correct: false``."""
     import jax
     import numpy as np
 
@@ -171,18 +175,23 @@ def measure(cell: dict, seed: int, seconds: float, trace: bool,
         f"{in_window}" + ("  <-- COMPILED INSIDE THE WINDOW" if in_window
                           else ""))
     walls = window["dispatch_walls_s"]
+    wanted = window.get("dispatches_wanted", len(walls))
     say(f"window: {window['wall_s']:.4f} s, {window['steps']} steps, "
-        f"{len(walls)} dispatches; walls s: "
+        f"{len(walls)} of {wanted} dispatches"
+        + (f" (STOPPED SHORT: --seconds {seconds:g} had passed)"
+           if len(walls) < wanted else "") + "; walls s: "
         + " ".join(f"{w:.4f}" for w in walls))
 
     advanced = int(np.asarray(ctx["state"].step)) - step_before
     counted = window["steps"] + driver.UNCOUNTED_STEPS
     if advanced != counted:
         say(f"state.step advanced by {advanced}, steps counted {counted}")
+    start = step_before + advanced  # where the comparison starts
     ok, report, ctx["state"] = family.check(
-        config, traffic, model, ctx.pop("state"), seed, driver.check_steps,
-        driver.CHECK_BATCHES)
-    say(f"reference: {'agrees' if ok else 'DISAGREES'} {json.dumps(report)}")
+        config, traffic, model, ctx.pop("state"), seed,
+        check_steps or driver.check_steps, driver.CHECK_BATCHES)
+    say(f"reference: {'agrees' if ok else 'DISAGREES'} from state.step "
+        f"{start} {json.dumps(report)}")
 
     # the process's peak as JAX reports it now, the comparison's copy of
     # the table included; the trainer's own is the per-layer peak_hbm_gib
@@ -218,6 +227,21 @@ def measure(cell: dict, seed: int, seconds: float, trace: bool,
             "idle_gaps": trace_lib.longest_gaps(reduced["gaps"],
                                                 reduced["spans"], 5)}
     result["device"] = info
+    # each number compared beside its limit: the last key of the line and
+    # the last line of stderr, which is all the driver keeps of a run that
+    # is not correct.  Where the comparison starts is the traffic file's
+    # to fix, not the program's speed: a window that stopped short starts
+    # it that many steps early
+    short = (wanted - len(walls)) * (window["steps"] // len(walls))
+    compared = {name: [report[name], limit]
+                for name, limit in family.LIMITS.items()}
+    compared.update(check_from_step=[start, start + short],
+                    steps_advanced=[advanced, counted],
+                    nonfinite_steps=[window["failed_steps"], 0])
+    result["compared"] = compared
+    print("compared (value, limit): " + "; ".join(
+        f"{name} {value} {limit}" for name, (value, limit)
+        in compared.items()), file=sys.stderr, flush=True)
     return result
 
 
@@ -227,6 +251,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
+    ap.add_argument("--control", type=int, choices=(0, 1), default=0,
+                    help="1: compare the family's control (its reference one "
+                         "precision down) in the program's place; it has to "
+                         "print correct: false.  Never part of a measurement")
     args = ap.parse_args(argv)
 
     cell = resolve(ROOT, args.workload)
@@ -260,8 +288,13 @@ def main(argv=None) -> int:
     say(f"compile cache: {cache_dir}, {entries} entries at start "
         f"({'warm' if entries else 'cold'})")
 
+    control = None
+    if args.control:
+        control = importlib.import_module(
+            "benchmarks.models." + cell["config"]["family"]).control_steps(
+                cell["config"])
     result = measure(cell, args.seed, args.seconds, bool(args.trace),
-                     peaks=PEAKS[devs[0].device_kind])
+                     peaks=PEAKS[devs[0].device_kind], check_steps=control)
     print(json.dumps(result), flush=True)
     return 0
 

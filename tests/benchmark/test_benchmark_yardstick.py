@@ -333,3 +333,178 @@ def test_a_new_cell_and_metric_are_new_files_and_entries_alone(tiny_root):
     reader = run.load_file(cell["readers"]["made_up_steps"])
     assert reader.read({"window": {"steps": 7}}) == 7
     assert run.measure(cell, 3, 0.1, trace=False)["correct"] is True
+
+
+# ------------------------------- the staged window is fixed work (PR 28)
+STAGED_CELLS = [w["name"] for w in BENCH["workloads"] + LEFT_OUT_CELLS
+                if json.load(open(os.path.join(
+                    ROOT, "benchmarks/traffic", w["traffic"] + ".json")))
+                ["driver"] == "staged"]
+STAGED_FILES = sorted(
+    f for f in os.listdir(os.path.join(ROOT, "benchmarks/traffic"))
+    if json.load(open(os.path.join(ROOT, "benchmarks/traffic", f)))
+    .get("driver") == "staged")
+
+
+def _steps_a_dispatch(cell):
+    return cell["traffic"]["epochs_per_dispatch"] * cell["traffic"]["batches"]
+
+
+def _line(out: str, head: str) -> str:
+    (line,) = [x for x in out.splitlines() if x.startswith(head)]
+    return line
+
+
+@pytest.mark.parametrize("workload", STAGED_CELLS)
+def test_correct_does_not_depend_on_seconds(tiny_root, workload, capsys):
+    """The same seed under ``--seconds`` 5 and 50: the same count of
+    dispatches, so the same ``attempted``, the same ``state.step`` under
+    the comparison and the same report, number for number."""
+    cell = run.resolve(tiny_root, workload)
+    assert cell["traffic"]["dispatches"] == 4
+    seen = []
+    for seconds in (5, 50):
+        result = run.measure(cell, 2 ** 31 + 28, seconds, trace=False)
+        out = capsys.readouterr()
+        seen.append((result["attempted"], result["correct"],
+                     result["compared"], _line(out.out, "reference: ")))
+        assert " 4 of 4 dispatches;" in _line(out.out, "window: ")
+        assert list(result)[-1] == "compared"
+        assert out.err.strip().splitlines()[-1].startswith("compared ")
+    assert seen[0] == seen[1]
+    attempted, correct, compared, line = seen[0]
+    assert correct is True and attempted == 4 * _steps_a_dispatch(cell)
+    # two warm-up dispatches, then the window: where the comparison starts
+    assert compared["check_from_step"] == [6 * _steps_a_dispatch(cell)] * 2
+    assert f"agrees from state.step {6 * _steps_a_dispatch(cell)} " in line
+    assert all(limit is not None and value <= limit
+               for value, limit in compared.values())
+
+
+def test_seconds_too_short_stops_the_window_early_and_says_so(tiny_root,
+                                                              capsys):
+    cell = run.resolve(tiny_root, "dlrm-random.staged-uniform")
+    result = run.measure(cell, 7, 0.0, trace=False)
+    line = _line(capsys.readouterr().out, "window: ")
+    # the ceiling is read when a dispatch completes, one more in flight
+    assert " 2 of 4 dispatches (STOPPED SHORT" in line
+    assert result["attempted"] == 2 * _steps_a_dispatch(cell)
+    assert result["correct"] is True  # trained less: the safe side
+    assert result["compared"]["check_from_step"] == [
+        4 * _steps_a_dispatch(cell), 6 * _steps_a_dispatch(cell)]
+
+
+@pytest.mark.parametrize("limit", [1, 3])
+def test_the_traced_window_keeps_its_own_count(tiny_root, limit):
+    cell = run.resolve(tiny_root, "dlrm-random.staged-zipf")
+    config, mix = cell["config"], cell["traffic"]
+    driver = run.load_file(cell["driver"])
+    from benchmarks.models import dlrm as family
+    model, state = family.build(config, mix["batch"], 3, None)
+    ctx = driver.prepare(model, state, family.make_dataset(config, mix, 3),
+                         mix, 3)
+    window = driver.run_window(ctx, 50, limit=limit)
+    assert window["steps"] == limit * _steps_a_dispatch(cell)
+    assert len(window["dispatch_walls_s"]) == limit \
+        == window["dispatches_wanted"]
+    assert int(ctx["state"].step) == (2 + limit) * _steps_a_dispatch(cell)
+
+
+@pytest.mark.parametrize("value", ["missing", 0, -3, True, 2.5, "24"])
+def test_a_staged_traffic_file_must_name_its_dispatches(value):
+    driver = run.load_file(os.path.join(ROOT, "benchmarks/drivers/staged.py"))
+    mix = json.load(open(os.path.join(
+        ROOT, "benchmarks/traffic/staged-uniform.json")))
+    assert mix.pop("dispatches") >= 1
+    if value != "missing":
+        mix["dispatches"] = value
+    # before the model or the data are looked at: nothing has compiled
+    with pytest.raises(KeyError, match='"dispatches"'):
+        driver.prepare(None, None, None, mix, 0)
+    with pytest.raises(KeyError, match='"dispatches"'):
+        driver.run_window({"model": None, "staged": None, "traffic": mix}, 1)
+
+
+@pytest.mark.parametrize("name", STAGED_FILES)
+def test_every_staged_traffic_file_fixes_its_window(name):
+    mix = json.load(open(os.path.join(ROOT, "benchmarks/traffic", name)))
+    count = mix["dispatches"]
+    assert isinstance(count, int) and count >= 1
+    assert count >= mix["traced_units"]
+
+
+@pytest.mark.parametrize("traffic", ["staged-uniform", "staged-zipf"])
+def test_the_two_cells_compare_at_the_distance_their_limits_were_read_at(
+        traffic):
+    """(2 warm-up + 24) dispatches x 8 epochs x 512 batches: PERF.md
+    section 4 has the curve of the comparison's error against it.  (A
+    cell a later PR adds fixes its own distance in its own file.)"""
+    mix = json.load(open(os.path.join(ROOT, "benchmarks/traffic",
+                                      traffic + ".json")))
+    assert (2 + mix["dispatches"]) * mix["epochs_per_dispatch"] \
+        * mix["batches"] == 106_496
+    (cell,) = [w for w in BENCH["workloads"] if w["traffic"] == traffic]
+    assert "24 dispatches of 8 epochs" in cell["why"]
+
+
+# ------------------- a whole run with the timed path broken underneath
+def _planted(fault: str, real):
+    """``check_steps`` with one fault planted in the path it times."""
+    import jax
+    import jax.numpy as jnp
+
+    def check_steps(model, state, inputs, labels):
+        if fault == "state_unchanged":
+            kept = jax.tree_util.tree_map(jnp.copy, state)  # state is donated
+            _, losses = real(model, state, inputs, labels)
+            return kept, losses
+        if fault == "half_batch":
+            half = labels.shape[1] // 2
+            inputs = {k: v[:, :half] for k, v in inputs.items()}
+            labels = labels[:, :half]
+        return real(model, state, inputs, labels)
+
+    return check_steps
+
+
+@pytest.mark.parametrize("fault,fails", [
+    ("none", ()),
+    ("state_unchanged", ("row_err_max", "row_err_median", "mlp_update_err")),
+    ("half_batch", ("row_err_max", "mlp_update_err"))])
+def test_a_run_with_the_timed_path_broken_is_not_correct(tiny_root, fault,
+                                                         fails, capsys):
+    """``run.measure`` from end to end (all but its look for a chip)
+    over steps that return the state unchanged, or leave half of every
+    batch out and take the mean over the rest: ``correct`` is false, and
+    the numbers printed beside their limits say by what."""
+    cell = run.resolve(tiny_root, "dlrm-random.staged-uniform")
+    real = run.load_file(cell["driver"]).check_steps
+    result = run.measure(cell, 2 ** 31 + 5, 50, trace=False,
+                         check_steps=_planted(fault, real))
+    err = capsys.readouterr().err
+    over = {name for name, (value, limit) in result["compared"].items()
+            if value > limit}
+    assert result["correct"] is (fault == "none"), result["compared"]
+    assert over >= set(fails) and (fails or not over), result["compared"]
+    assert all(f"{n} {result['compared'][n][0]} " in err for n in over)
+
+
+@pytest.mark.parametrize("seed", [1, 3, 2 ** 31 + 7])
+def test_the_control_one_precision_down_is_not_correct(tiny_root, seed):
+    """The reference in the program's place with bfloat16 operands, where
+    ``tiny.json`` states float32 (``run.py --control 1`` on the chip:
+    float8 where the configuration states bfloat16).  At this size only
+    the widest row gap tells the two apart, and on some seeds (2 is one:
+    0.40) not even that: the limits were read at full width on the chip,
+    and PERF.md section 4 has the control's readings there."""
+    from benchmarks.models import dlrm as family
+    cell = run.resolve(tiny_root, "dlrm-random.staged-uniform")
+    assert family.LOWER[cell["config"]["ffconfig"]["compute_dtype"]] \
+        == "bfloat16"
+    result = run.measure(cell, seed, 50, trace=False,
+                         check_steps=family.control_steps(cell["config"]))
+    assert result["correct"] is False
+    value, limit = result["compared"]["row_err_max"]
+    assert value > limit
+    # a sound run at this precision agrees to the bit (0.0 everywhere)
+    assert result["compared"]["row_err_median"][0] > 0
