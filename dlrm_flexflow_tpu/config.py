@@ -119,12 +119,20 @@ class FFConfig:
     # STREAM each block's writeback into its own region
     # (dynamic_update_slice — measured 8.4x the scatter emitter's
     # density-scaled RMW sweep at the boundary shape, ab_boundary.py);
-    # cross-block coherence moves into the fetch, a same-cost gather at
-    # prologue-computed circular-predecessor positions
-    # (ops/slotting.py::region_plan), and the epilogue gathers each
-    # row's last copy.  Bit-exact with shared-slot mode (tests).
+    # cross-block coherence moves into the fetch, which reads each
+    # position from the row's newest copy at prologue-computed
+    # circular-predecessor positions (ops/slotting.py::region_plan),
+    # and the epilogue gathers each row's last copy.  In the
+    # single-level layout (auto's, whenever every cache op engages) a
+    # region holds its FOREIGN rows first — those another block holds
+    # too, the only positions whose newest copy is not their own — and
+    # the fetch is one dynamic_slice of the block's own region plus a
+    # gather of just those rows (region_slots, model.py _region_fetch;
+    # on the v5e: 133.9 -> 41 us a block on uniform ids, 57 on
+    # Zipf 1.05).  Bit-exact with shared-slot mode (tests).
     # With a two-level ladder the L1 cache is itself L0-region-major
-    # (grouped circular plan), so the L0 writebacks stream too.
+    # (grouped circular plan), so the L0 writebacks stream too; its
+    # fetches gather every position.
     # Engages for single-device packed-storage ops when the ladder top
     # level divides the epoch and segmented slots are off.  "auto" = on
     # (round-5 headline A/B: busy 243.5 -> 219.0 ms); "off" restores

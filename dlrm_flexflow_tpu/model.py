@@ -73,6 +73,69 @@ def _validated_epoch_cache_view(config) -> str:
     return view_mode
 
 
+# rows a trip of _region_fetch's loop gathers: a measurement of XLA:TPU's
+# gather emitter at 512 B rows and libtpu 0.0.34, not a law;
+# scripts/ab_fetch.py times this very function at other sizes
+REGION_FETCH_CHUNK = 768
+
+
+def _region_fetch(parent, src, base, foreign, chunk=None):
+    """Leaf-block fetch of the SINGLE-LEVEL region layout: one
+    ``dynamic_slice`` streams the block's own region [base, base+m) of
+    the epoch cache, then only the FOREIGN positions — ``region_slots``
+    puts them first, ``foreign`` of them — are gathered from their
+    newest copy (``src``) and laid over it, chunk by chunk, in a loop
+    whose trip count follows the count: the cost follows the data, with
+    no budget and no branch.  Positions past ``foreign`` hold
+    ``src[p] == p`` (the slice brought exactly that row) or a sentinel
+    nothing addresses, so a last chunk that runs past the count rewrites
+    what is there.  Value-identical to the full gather at every live
+    position.
+
+    Measured on the v5e at m = 16,384 rows of 512 B (my chip runs, PR
+    29; PERF.md §6): the full gather 134 us a block; here the slice 12
+    us, and for the uniform cell's 3,719 foreign rows the gather 18.8
+    us + laying 8.9 us (1.8 us a trip).  ``scripts/ab_fetch.py`` runs
+    THIS function at other chunks (us a block at 3,800 foreign rows,
+    the fetch with 29.6 us of stand-in work): one piece 182.5; 128:
+    121.2, 256: 104.6, 384: 114.5, 512: 98.8, 640: 101.8, 768: 90.1,
+    896: 100.5, 1,024: 107.5, 1,280: 87.7, 1,536: 93.5, 1,792: 96.6,
+    2,048: 105.2, 3,072: 125.2.  768 is the smallest chunk within 3 us
+    of the best; 1,280 read 2.3-2.4 us a block (0.3 us a step) better
+    at 3,800 and 5,600 rows and 12.8 better when all 16,384 are foreign
+    (174.2 against 187.0; one piece 184.4), and has not been run in
+    the benchmark (PERF.md §7).  Nobody has explained the emitter's
+    dependence on the piece size.  The other exact form, a static 3m/8
+    prefix behind a ``lax.cond`` with the full gather as its other
+    branch, lost by 31.6 us a step (PR 27's builder's chip runs): the
+    conditional took the leaf cache out of fast memory
+    (``ff.step.gather`` 3.3 -> 14.8 us a step) and added a copy of the
+    block per fetch, as round 4's ``_seg_fetch`` had.
+
+    ``chunk`` defaults to ``min(REGION_FETCH_CHUNK, max(m // 16, 1))``;
+    only ``scripts/ab_fetch.py`` and the tests pass another.  The ``m // 16`` arm
+    keeps the trip count following ``foreign`` where a block has fewer
+    than 12,288 positions (the tests' tiny epochs, a small batch); the
+    benchmark's traffic never meets it."""
+    m, d = src.shape[0], parent.shape[-1]
+    if chunk is None:
+        chunk = min(REGION_FETCH_CHUNK, max(m // 16, 1))
+    with jax.named_scope("ff.ladder.fetch.own"):
+        own = jax.lax.dynamic_slice(parent, (base, 0), (m, d))
+
+    def lay(i, blk):
+        # both the index slice and the placement clamp a last chunk
+        # that would run past m to [m - chunk, m)
+        at = jnp.minimum(i * chunk, m - chunk)
+        idx = jax.lax.dynamic_slice(src, (at,), (chunk,))
+        rows = jnp.take(parent, idx, axis=0, mode="clip")
+        return jax.lax.dynamic_update_slice(blk, rows, (at, 0))
+
+    with jax.named_scope("ff.ladder.fetch.foreign"):
+        return jax.lax.fori_loop(
+            0, (foreign + chunk - 1) // chunk, lay, own)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class TrainState:
@@ -669,11 +732,17 @@ class FFModel:
         region_auto_on = True
         # When EVERY cache op takes the region path, auto's ladder
         # collapses to the single leaf level ([inner]): under regions
-        # the mid level saves no HBM gather issues (the fetch row count
-        # per epoch is the occurrence count either way) while adding
-        # its own S(1) rebuild gather + dus layer — measured busy
-        # 185.0 -> 171.6 ms at the headline, bench-recorded 171.5
-        # (round 5).  cache_prologue decides the flag once per trace and
+        # the mid level saves no HBM gather issues while adding its own
+        # S(1) rebuild gather + dus layer — measured busy 185.0 ->
+        # 171.6 ms at the headline, bench-recorded 171.5 (round 5).
+        # Only this single-level layout has the streamed fetch: a
+        # position's fetch index differs from the position itself only
+        # where ANOTHER block holds the row too (23% of a block's
+        # positions on uniform ids at the benchmark's shape, 33% on
+        # Zipf 1.05), so each region holds those foreign rows first
+        # (ops/slotting.py::region_slots) and _region_fetch streams
+        # the region and gathers only them.
+        # cache_prologue decides the flag once per trace and
         # THREADS IT EXPLICITLY through every ladder_sizes consumer
         # (advisor r5: the previous mutable-closure read relied on trace
         # ordering); mixed eligibility keeps the two-level shape so
@@ -1493,7 +1562,8 @@ class FFModel:
             fv = op.flat_ids(ids)
             n_occ = int(np.prod(fv.shape))
             from .ops.slotting import (grouped_region_plan, region_plan,
-                                       region_plan_l0, slot_rows)
+                                       region_plan_l0, region_slots,
+                                       slot_rows)
             sentinel = flat.shape[0]
             inner = sizes[1] if len(sizes) >= 2 else 0
             if 0 < inner < top and top % inner == 0:
@@ -1531,10 +1601,14 @@ class FFModel:
                 }
                 return cache, slots, info, final_rowof, final_src, \
                     rowof_all
+            # SINGLE-LEVEL regions: each region holds its block's
+            # FOREIGN rows first (rows another block holds too — the
+            # only positions whose src differs from themselves), so
+            # the leaf fetch streams the region and gathers only
+            # those (_region_fetch)
             m_occ = n_occ // nblk
             v = fv.reshape(nblk, m_occ)
-            rowof_blocks, vslots = jax.vmap(
-                lambda b: slot_rows(b // sp, sentinel))(v)
+            rowof_blocks, vslots, foreign = region_slots(v // sp, sentinel)
             base = (jnp.arange(nblk, dtype=jnp.int32) * m_occ)[:, None]
             slots = ((base + vslots) * sp
                      + (v % sp).astype(jnp.int32)).reshape(fv.shape)
@@ -1543,7 +1617,8 @@ class FFModel:
             src, final_rowof, final_src = region_plan(rowof_blocks,
                                                       sentinel)
             info = {"src": src,
-                    "base": jnp.arange(nblk, dtype=jnp.int32) * m_occ}
+                    "base": jnp.arange(nblk, dtype=jnp.int32) * m_occ,
+                    "foreign": foreign}
             return cache, slots, info, final_rowof, final_src, rowof_all
 
         def ladder_sizes(nb, region_single):
@@ -1585,11 +1660,13 @@ class FFModel:
             # over 8 inner blocks.
             #
             # Under REGIONS for every cache op the mid level loses its
-            # reason to exist — the region fetch issues one HBM gather
-            # row per occurrence per epoch whether it reads into a mid
-            # cache or straight into the leaf block, so the mid level
-            # only adds its own S(1) rebuild + dus layer: the ladder
-            # collapses to [inner] (busy 185.0 -> 171.6 ms, bench-recorded 171.5, round 5).
+            # reason to exist — the region fetch's HBM gather issues
+            # are no fewer for reading into a mid cache than straight
+            # into the leaf block, so the mid level only adds its own
+            # S(1) rebuild + dus layer: the ladder collapses to [inner]
+            # (busy 185.0 -> 171.6 ms, bench-recorded 171.5, round 5),
+            # and only that single-level layout has the streamed fetch
+            # (_region_fetch).
             if 0 < inner < nb:
                 if region_single and nb % inner == 0:
                     return [inner]
@@ -1689,8 +1766,10 @@ class FFModel:
             # dense ranks diverge from positions), and the writeback
             # streams into the block's own region (outer() keys on
             # "region_base").  ``region_src`` entries:
-            # {"src": (nblk, m), "base": (nblk,), ["inner": ...]} —
-            # "inner" recurses one level down.
+            # {"src": (nblk, m), "base": (nblk,), ["foreign": (nblk,)],
+            # ["inner": ...]} — "inner" recurses one level down;
+            # "foreign" (single-level layout only) is the count of
+            # leading positions the fetch has to gather.
             srcs = {n: s for n, s in (region_src or {}).items()
                     if n in part}
 
@@ -1729,6 +1808,9 @@ class FFModel:
             arrs = jax.vmap(per_block)(blks, srcs)
             if srcs:
                 arrs["region_base"] = {n: srcs[n]["base"] for n in srcs}
+                arrs["region_foreign"] = {
+                    n: srcs[n]["foreign"] for n in srcs
+                    if "foreign" in srcs[n]}
             if top and nblk > 1:
                 segP = {}
                 for name in part:
@@ -1776,6 +1858,7 @@ class FFModel:
                 seg_ps = a_k.get("segP", {})
                 seg_k = a_k.get("segk")
                 reg_b = a_k.get("region_base", {})
+                reg_f = a_k.get("region_foreign", {})
                 params2 = dict(st.params)
                 opt2 = st.opt_state
                 wb, slot_wb = [], []
@@ -1785,9 +1868,17 @@ class FFModel:
                     seg = ((seg_k, seg_ps[name], part[name])
                            if name in seg_ps else None)
                     base_k = reg_b.get(name)
+                    foreign_k = reg_f.get(name)
 
-                    def _fetch(fl, r=rowof, s=seg):
-                        # region mode: r IS the src plan — same gather
+                    def _fetch(fl, r=rowof, s=seg, b=base_k,
+                               f=foreign_k):
+                        # region mode: r IS the src plan — the
+                        # single-level layout streams its own region
+                        # and gathers the foreign positions, the
+                        # grouped two-level one gathers every position
+                        if f is not None:
+                            return _region_fetch(
+                                fl.reshape(-1, fl.shape[-1]), r, b, f)
                         if s is None:
                             return _cache_fetch(fl, r)
                         return _seg_fetch(fl.reshape(-1, fl.shape[-1]),

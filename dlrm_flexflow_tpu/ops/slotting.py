@@ -73,6 +73,121 @@ def slot_rows(ids, num_rows: int):
     return rowof, slots.reshape(ids.shape)
 
 
+def region_slots(blocks, num_rows: int):
+    """Per-block slot plan of the SINGLE-LEVEL region layout, FOREIGN
+    ROWS FIRST: ``(rowof_blocks, slots, foreign)`` for ``blocks``
+    (nblk, m) rows per occurrence, 0 <= row < num_rows < 2^30.
+
+    A block's row is FOREIGN iff another block of the epoch holds it
+    too; only those have ``region_plan``'s ``src[p] != p``.  Region k's
+    positions are assigned ``slot_rows``-wise but ordered (foreign rows
+    ascending, then the block's own rows ascending, then sentinels), so
+    the fetch streams the whole region with one ``dynamic_slice`` and
+    gathers only the first ``foreign[k]`` positions over it.
+
+    ``rowof_blocks`` (nblk, m): the row at each region position,
+    sentinel ``num_rows`` after the distinct ones.  ``slots`` (nblk, m):
+    each occurrence's position in its block, ``rowof_blocks[k,
+    slots[k]] == blocks[k]``.  ``foreign`` (nblk,): the block's distinct
+    foreign rows.  Nothing downstream reads the order inside a region:
+    ``region_plan`` sorts globally and step slots are positions.
+
+    Two sorts and two cumulative maxima on top of the per-block
+    ``slot_rows``: one stable global sort by row puts a row's blocks
+    side by side, ``_run_has_mark`` says which runs change block
+    somewhere, one sort by position carries the verdict back as a key
+    offset, and ``slot_rows`` then ranks ``row + (0 | num_rows)`` —
+    scatter-free and gather-free like every plan here.  The count is
+    what ``scripts/profile_headline.py`` prints as ``region fetch:``.
+    """
+    nblk, m = blocks.shape
+    n = nblk * m
+    assert 2 * max(num_rows, n) < (1 << 31) - 1, (num_rows, n)
+    rows = blocks.reshape(n).astype(jnp.int32)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    srows, spos = jax.lax.sort((rows, pos), num_keys=1, is_stable=True)
+    first = jnp.concatenate(
+        [jnp.ones((1,), bool), srows[1:] != srows[:-1]])
+    # stable: positions ascend within a run, so its blocks do, and the
+    # row is foreign iff the block changes between two of its entries
+    sblk = spos // m
+    change = jnp.concatenate(
+        [jnp.zeros((1,), bool), sblk[1:] != sblk[:-1]]) & ~first
+    foreign = _run_has_mark(first, change)
+    _, key = jax.lax.sort(
+        (spos, srows + jnp.where(foreign, 0, jnp.int32(num_rows))),
+        num_keys=1)
+    rowof_key, slots = jax.vmap(
+        lambda b: slot_rows(b, 2 * num_rows))(key.reshape(nblk, m))
+    is_foreign = rowof_key < num_rows
+    rowof_blocks = jnp.where(is_foreign, rowof_key,
+                             rowof_key - jnp.int32(num_rows))
+    return rowof_blocks, slots, jnp.sum(is_foreign, axis=1, dtype=jnp.int32)
+
+
+def _cummax(x):
+    """Cumulative maximum of a 1-D int32 vector by shift-and-max
+    doubling: ten steps along the minor axis of a (r, 1024) reshape,
+    then the same over the rows' last elements as a carry.  Measured
+    on the v5e over 2^20 elements (``scripts/ab_scan.py``; PERF.md §6,
+    PR 29): 28 us, against 372 us for a 1-D ``jax.lax.cummax`` and, for
+    its two-pass form, 258 us on a (1024, 1024) reshape but 29 us on
+    (4096, 256): speed does not decide against that last one.  Names
+    do: ``python scripts/ab_scan.py names`` compiles this module's two
+    callers under one scope for the v5e (libtpu 0.0.34; PR 29).  On
+    the two-pass ``jax.lax.cummax`` 36 of 45 fusions and
+    ``reduce-window`` instructions, the scans themselves among them,
+    come out with no metadata or with the bare ``op_name``
+    ``reduce_window_max`` and no name stack, so a profile reads their
+    time as ``unattributed`` and ``phase_attributed_pct``, which guards
+    the per-phase metrics, falls; on this form 3 of 99 do (the last
+    combine, fused with a reshape).  Traced in the uniform cell with
+    the two-pass form here (my chip run, PR 29; PERF.md §6):
+    ``phase_attributed_pct`` 99.940 -> 99.914, ``cache_us_per_step``
+    8.435 -> 8.414: 0.02 us a step left its group, not the program."""
+    n = x.shape[0]
+    c = min(1024, n)
+    r = -(-n // c)
+    lo = jnp.iinfo(jnp.int32).min
+    if r * c > n:
+        x = jnp.concatenate([x, jnp.full((r * c - n,), lo, jnp.int32)])
+
+    def along_minor(a):
+        k = 1
+        while k < a.shape[-1]:
+            pad = jnp.full(a.shape[:-1] + (k,), lo, jnp.int32)
+            a = jnp.maximum(a, jnp.concatenate([pad, a[..., :-k]], axis=-1))
+            k *= 2
+        return a
+
+    row = along_minor(x.reshape(r, c))
+    carry = jnp.concatenate(
+        [jnp.full((1,), lo, jnp.int32), along_minor(row[:, -1])[:-1]])
+    return jnp.maximum(row, carry[:, None]).reshape(-1)[:n]
+
+
+def _run_has_mark(first, marked):
+    """Per entry, whether ANY entry of its run is ``marked``; runs start
+    where ``first`` holds (``first[0]`` must, and no run-first is
+    marked).  A segmented OR without a segmented scan: number the
+    boundaries 2*i and the marks 2*i+1, and the nearest of either kind
+    at or before an entry is one cumulative maximum: odd iff a mark
+    lies between the run's start and the entry.  The same from the
+    other end (a mark at i+1 is seen from i, so that marks and run ends
+    never coincide) covers the marks after it."""
+    n = first.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    none = jnp.int32(-1)
+    before = _cummax(jnp.where(marked, 2 * idx + 1,
+                               jnp.where(first, 2 * idx, none)))
+    last = jnp.concatenate([first[1:], jnp.ones((1,), bool)])
+    nxt = jnp.concatenate([marked[1:], jnp.zeros((1,), bool)])
+    back = 2 * (n - 1 - idx)
+    after = jnp.flip(_cummax(jnp.flip(
+        jnp.where(nxt, back + 1, jnp.where(last, back, none)))))
+    return ((before | after) & 1) == 1
+
+
 def region_plan(rowof_blocks, num_rows: int):
     """Circular-predecessor plan for BLOCK-MAJOR epoch-cache regions
     (round 5 — built on the ab_boundary.py measurement: a
@@ -87,8 +202,10 @@ def region_plan(rowof_blocks, num_rows: int):
     coherence across blocks moves into the FETCH, which gathers each
     region position's value from the row's most recent prior copy.
 
-    ``rowof_blocks``: (nblk, m) int32 — per-block sorted distinct rows
-    with sentinel (``num_rows``) padding.  Returns
+    ``rowof_blocks``: (nblk, m) int32 — per-block distinct rows with
+    sentinel (``num_rows``) padding after them, in any order inside a
+    block (``slot_rows``' ascending, ``region_slots``' foreign-first:
+    the sort below is global).  Returns
     ``(src, final_rowof, final_src)``:
 
     - ``src`` (nblk, m): for region position p = k*m + j, the cache
@@ -133,50 +250,38 @@ def region_plan(rowof_blocks, num_rows: int):
 def _fill_from_marked(vals, marked, *, reverse=False):
     """``out[i] = vals[j]`` at the nearest marked ``j <= i`` (``>= i``
     when ``reverse``) — the segmented broadcast every region plan
-    needs, scatter-free AND gather-free.
+    needs, scatter-free AND gather-free.  ``vals``: int32 positions,
+    ``0 <= vals < n`` for ``n`` entries (what the plans broadcast).
 
-    The first cut of these plans broadcast run values with
-    ``jnp.take(vals, per_entry_idx)``; on this platform a 1-D gather
-    pays the emitter's per-ROW issue cost (~7.5 ns/element) regardless
-    of element size, so each 2^20-element broadcast cost 7.48 ms — the
-    three of them were 10% of headline busy (round-5 trace).  An
-    associative forward-fill moves the same data at vector rates
-    (~0.2 ms): scan along the minor axis of a (r, 256) reshape
-    (vectorized over rows), then a tiny cross-row carry pass.
+    Neither a gather nor a pair-valued scan: a 1-D ``jnp.take`` pays
+    the gather emitter's per-ROW issue cost (~7.5 ns/element: 7.48 ms
+    per 2^20-element broadcast, round-5 trace), and a pair-valued
+    ``associative_scan`` compiles to 83 MB of TPU code and a minute of
+    compile per call site (83 of the parent's 104 MB benchmark program
+    and 2.7 ms a dispatch, PERF.md §6, PR 29).  The value rides on
+    ``_cummax``, the region plans' one scan primitive: key a marked
+    entry ``index << k | piece of its value`` and every other one -1,
+    and the cumulative maximum carries the nearest mark's piece in its
+    low ``k = 31 - bits`` bits, ``bits`` those of ``n - 1``;
+    ``ceil(bits / k)`` passes give the whole position (two for the
+    2^20 positions of the benchmark's epoch).
 
     Positions before the first mark (after the last, when ``reverse``)
     are undefined; every plan below guarantees a mark at the boundary.
     """
-    n = vals.shape[0]
-    c = min(256, n)
-    r = -(-n // c)
-    pad = r * c - n
-    if pad:
-        vals = jnp.concatenate([vals, jnp.zeros((pad,), vals.dtype)])
-        marked = jnp.concatenate([marked, jnp.zeros((pad,), bool)])
-
-    def op(a, b):
-        # b is the later element in scan order: its mark wins
-        av, am = a
-        bv, bm = b
-        return jnp.where(bm, bv, av), am | bm
-
-    sv, sm = jax.lax.associative_scan(
-        op, (vals.reshape(r, c), marked.reshape(r, c)),
-        axis=1, reverse=reverse)
-    # cross-row carries: exclusive pair-scan of each row's full combine
-    edge = (sv[:, 0], sm[:, 0]) if reverse else (sv[:, -1], sm[:, -1])
-    cv, cm = jax.lax.associative_scan(op, edge, axis=0, reverse=reverse)
     if reverse:
-        cv = jnp.concatenate([cv[1:], cv[-1:]])
-        cm = jnp.concatenate([cm[1:], jnp.zeros((1,), bool)])
-    else:
-        cv = jnp.concatenate([cv[:1], cv[:-1]])
-        cm = jnp.concatenate([jnp.zeros((1,), bool), cm[:-1]])
-    out = jnp.where(sm, sv, jnp.where(cm, cv, jnp.zeros((), vals.dtype)
-                                      )[:, None])
-    out = out.reshape(-1)
-    return out[:n] if pad else out
+        return jnp.flip(_fill_from_marked(jnp.flip(vals), jnp.flip(marked)))
+    n = vals.shape[0]
+    bits = max((n - 1).bit_length(), 1)
+    k = 31 - bits
+    assert k >= 1, n
+    at = jnp.arange(n, dtype=jnp.int32) << k
+    out = jnp.zeros((n,), jnp.int32)
+    for shift in range(0, bits, k):
+        piece = (vals >> shift) & ((1 << k) - 1)
+        near = _cummax(jnp.where(marked, at | piece, jnp.int32(-1)))
+        out = out | ((near & ((1 << k) - 1)) << shift)
+    return out
 
 
 def region_plan_l0(rowof_l0, num_rows: int):

@@ -7,9 +7,11 @@ round-3 roofline: ~1.0 of the 1.14 ms step) is measured, not inferred.
 
 Usage: python scripts/profile_headline.py [nb] [epochs]
 Env: PROF_ROWS (default 1e6), PROF_BATCH (256), PROF_LEVELS (ladder
-override, e.g. "256,32,8"), PROF_TOP (default 30 lines).
+override, e.g. "256,32,8"), PROF_TOP (default 30 lines), PROF_ZIPF (a
+Zipf exponent, e.g. 1.05, for the ids; default uniform).
 """
 
+import math
 import os
 import sys
 import time
@@ -43,12 +45,16 @@ def build():
                   mesh=False if jax.device_count() == 1 else None)
     state = model.init(seed=0)
     rng = np.random.default_rng(0)
+    shape = (nb, batch, 8, cfg.embedding_bag_size)
+    if os.environ.get("PROF_ZIPF"):
+        from dlrm_flexflow_tpu.data.loader import zipf_ids
+        sparse = zipf_ids(rng, rows, shape, a=float(os.environ["PROF_ZIPF"]))
+    else:
+        sparse = rng.integers(0, rows, size=shape, dtype=np.int64)
     inputs = {
         "dense": rng.standard_normal(
             (nb, batch, cfg.mlp_bot[0])).astype(np.float32),
-        "sparse": rng.integers(
-            0, rows, size=(nb, batch, 8, cfg.embedding_bag_size),
-            dtype=np.int64),
+        "sparse": sparse,
     }
     labels = rng.integers(0, 2, size=(nb, batch, 1)).astype(np.float32)
     inputs, labels = model.place_dataset(inputs, labels)
@@ -66,6 +72,37 @@ def parse_trace(logdir, min_frac=0.001):
         return parse_device_trace(logdir)
     except FileNotFoundError as e:
         raise SystemExit(str(e))
+
+
+def region_fetch_line(model, state, inputs, labels, epochs):
+    """What the single-level region fetch (``model._region_fetch``)
+    meets on these ids: per leaf block, the positions it gathers (rows
+    another block holds too: the plan's own count,
+    ``ops/slotting.py::region_slots``) and the share it streams with
+    its one ``dynamic_slice``.  ``None`` unless the program that ran
+    contains that fetch: its scope ``ff.ladder.fetch.own`` in the
+    lowered text is the one test, as in ``check_region_exact.py``; the
+    rule that decides it lives in ``model.py`` alone."""
+    import jax.numpy as jnp
+    from dlrm_flexflow_tpu.ops.slotting import region_slots
+
+    hlo = model._train_epochs.lower(state, inputs, labels, epochs).as_text(
+        debug_info=True)
+    if "ff.ladder.fetch.own" not in hlo:
+        return None
+    op = model.get_op("emb")
+    # the single-level layout's block: the ladder's one level
+    levels = os.environ.get("PROF_LEVELS")
+    steps = int(levels) if levels else int(model.config.epoch_cache_inner)
+    ids = inputs["sparse"]
+    view_rows = math.prod(state.params["emb"]["embedding"].shape[:-1])
+    blocks = (op.flat_ids(ids.astype(jnp.int32))
+              // op.storage_pack).reshape(ids.shape[0] // steps, -1)
+    counts = region_slots(blocks, view_rows)[2]
+    m = blocks.shape[1]
+    mean, top = float(counts.mean()), int(counts.max())
+    return (f"# region fetch: m {m}, foreign rows a block mean {mean:.0f} / "
+            f"max {top}, share of positions streamed {1 - mean / m:.3f}")
 
 
 def main():
@@ -116,6 +153,9 @@ def main():
     for phase, dur in sorted(by_phase.items(), key=lambda kv: -kv[1]):
         print(f"{dur/1e3:10.2f} ms  {dur/total*100:5.1f}%  "
               f"{dur/steps:8.1f} us/step  {phase}")
+    line = region_fetch_line(model, state, inputs, labels, epochs)
+    if line:
+        print(line)
 
 
 if __name__ == "__main__":

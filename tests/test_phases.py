@@ -30,6 +30,9 @@ SCOPES = {"ff.cache.prologue", "ff.cache.plan", "ff.cache.epilogue",
           "ff.ladder", "ff.ladder.fetch", "ff.ladder.writeback",
           "ff.step.gather", "ff.step.model", "ff.step.model.bwd",
           "ff.step.row_update", "ff.step.dense_update", "ff.step.metrics"}
+#: the single-level region layout's leaf fetch runs wholly inside its
+#: two sub-scopes (the streamed own region, the gathered foreign rows)
+REGION_FETCH = {"ff.ladder.fetch.own", "ff.ladder.fetch.foreign"}
 
 
 @pytest.mark.parametrize("stack, phase", [
@@ -37,6 +40,10 @@ SCOPES = {"ff.cache.prologue", "ff.cache.plan", "ff.cache.epilogue",
      "ff.step.gather"),                      # nested: the innermost wins
     ("jit(f)/ff.cache.prologue/ff.ladder.fetch/jit(_take)/gather:",
      "ff.ladder.fetch"),                     # a tf_op's trailing colon
+    ("jit(f)/ff.ladder/while/body/ff.ladder.fetch/ff.ladder.fetch.own/"
+     "dynamic_slice", "ff.ladder.fetch.own"),  # a sub-scope is a phase
+    ("jit(f)/ff.ladder/while/body/ff.ladder.fetch/ff.ladder.fetch.foreign/"
+     "while/body/jit(_take)/gather:", "ff.ladder.fetch.foreign"),
     ("jit(f)/ff.ladder/while/body/jvp(ff.step.model)/top_1/dot_general",
      "ff.step.model"),                       # forward, seen through jvp
     ("jit(f)/ff.ladder/while/body/transpose(jvp(ff.step.model))/top_1/mul",
@@ -85,17 +92,19 @@ def test_hlo_phases_reads_every_computation_but_the_fused_ones():
         "copy.2": UNATTRIBUTED}
 
 
+def meta(tid, tname):
+    return [{"ph": "M", "pid": 1, "name": "process_name",
+             "args": {"name": "/device:TPU:0"}},
+            {"ph": "M", "pid": 1, "tid": tid, "name": "thread_name",
+             "args": {"name": tname}}]
+
+
+def op(name, ts, dur, tf_op=None):
+    return {"ph": "X", "pid": 1, "tid": 3, "name": name, "ts": ts,
+            "dur": dur, "args": {"tf_op": tf_op} if tf_op else {}}
+
+
 def test_trace_route_gives_a_while_the_stack_its_children_share(tmp_path):
-    def meta(tid, tname):
-        return [{"ph": "M", "pid": 1, "name": "process_name",
-                 "args": {"name": "/device:TPU:0"}},
-                {"ph": "M", "pid": 1, "tid": tid, "name": "thread_name",
-                 "args": {"name": tname}}]
-
-    def op(name, ts, dur, tf_op=None):
-        return {"ph": "X", "pid": 1, "tid": 3, "name": name, "ts": ts,
-                "dur": dur, "args": {"tf_op": tf_op} if tf_op else {}}
-
     body = "jit(f)/ff.ladder/while/body/closed_call/"
     events = meta(2, "XLA Modules") + meta(3, "XLA Ops") + [
         {"ph": "X", "pid": 1, "tid": 2, "name": "jit_f(1)", "ts": 0,
@@ -110,6 +119,75 @@ def test_trace_route_gives_a_while_the_stack_its_children_share(tmp_path):
     assert busy_ms == pytest.approx(0.120)
     assert by_phase == {"ff.ladder": 30.0, "ff.step.gather": 30.0,
                         "ff.step.model.bwd": 40.0, UNATTRIBUTED: 15.0}
+
+
+FETCH_HLO = '''HloModule jit_f, is_scheduled=true
+
+%fetch_body (t: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %t = (s32[], f32[8]{0}) parameter(0)
+  %fusion.144 = f32[8]{0} fusion(%t), kind=kCustom, calls=%fc, metadata={op_name="jit(f)/ff.ladder/while/body/ff.ladder.fetch/ff.ladder.fetch.foreign/while/body/jit(_take)/gather"}
+  ROOT %dynamic_update_slice.129 = f32[8]{0} dynamic-update-slice(%t, %fusion.144), metadata={op_name="jit(f)/ff.ladder/while/body/ff.ladder.fetch/ff.ladder.fetch.foreign/while/body/dynamic_update_slice"}
+}
+
+%body (t: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %t.1 = (s32[], f32[8]{0}) parameter(0)
+  %dynamic_slice.124 = f32[8]{0} dynamic-slice(%t.1), metadata={op_name="jit(f)/ff.ladder/while/body/ff.ladder.fetch/ff.ladder.fetch.own/dynamic_slice"}
+  %while.228 = (s32[], f32[8]{0}) while(%dynamic_slice.124), condition=%fetch_cond, body=%fetch_body, metadata={op_name="jit(f)/ff.ladder/while/body/ff.ladder.fetch/ff.ladder.fetch.foreign/while"}
+  %copy.7 = f32[8]{0} copy(%while.228)
+  ROOT %dynamic_update_slice.95 = f32[8]{0} dynamic-update-slice(%t.1, %copy.7), metadata={op_name="jit(f)/ff.ladder/while/body/ff.ladder.writeback/dynamic_update_slice"}
+}
+
+ENTRY %main.9 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  ROOT %while.1 = (s32[], f32[8]{0}) while(%x), condition=%cond, body=%body, metadata={op_name="jit(f)/ff.ladder/while"}
+}
+'''
+
+
+def test_region_fetch_sub_scopes_are_ladder_time_on_both_routes(tmp_path):
+    """``ff.ladder.fetch.own`` / ``.foreign`` are phases of their own
+    and fall into the benchmark's ladder group by their prefix, read
+    off the program's map (the chunk loop's ``while`` and what runs in
+    its body included) and off a trace's ``tf_op`` alike."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.lib import phases as bench_phases
+
+    for scope in REGION_FETCH:
+        assert bench_phases.group_of(scope) == "ladder"
+    # the map route
+    by_map = hlo_phases(FETCH_HLO)
+    assert by_map["dynamic_slice.124"] == "ff.ladder.fetch.own"
+    assert {by_map[n] for n in ("while.228", "fusion.144", "t",
+                                "dynamic_update_slice.129")} \
+        == {"ff.ladder.fetch.foreign"}
+    assert by_map["dynamic_update_slice.95"] == "ff.ladder.writeback"
+    assert by_map["copy.7"] == "ff.ladder"   # unnamed: its caller's
+    self_us = {"dynamic_slice.124": 40.0, "fusion.144": 30.0,
+               "dynamic_update_slice.129": 2.0, "while.228": 1.0,
+               "dynamic_update_slice.95": 35.0}
+    parts = bench_phases.split(self_us, by_map, 110.0)
+    assert parts["ladder"] == 108.0 and parts[UNATTRIBUTED] == 2.0
+    # the trace route
+    stack = "jit(f)/ff.ladder/while/body/ff.ladder.fetch/"
+    events = meta(2, "XLA Modules") + meta(3, "XLA Ops") + [
+        {"ph": "X", "pid": 1, "tid": 2, "name": "jit_f(1)", "ts": 0,
+         "dur": 120},
+        op("dynamic_slice.124", 0, 40,
+           stack + "ff.ladder.fetch.own/dynamic_slice:"),
+        op("while.228", 40, 33),                   # the profiler keeps none
+        op("fusion.144", 41, 30, stack + "ff.ladder.fetch.foreign/while/"
+           "body/jit(_take)/gather:"),
+        op("dynamic_update_slice.129", 71, 2, stack
+           + "ff.ladder.fetch.foreign/while/body/dynamic_update_slice:")]
+    with gzip.open(tmp_path / "t.trace.json.gz", "wt") as f:
+        json.dump({"traceEvents": events}, f)
+    _path, by_phase, _busy = parse_device_trace_phases(str(tmp_path))
+    assert by_phase == {"ff.ladder.fetch.own": 40.0,
+                        "ff.ladder.fetch.foreign": 33.0}
+    assert {bench_phases.group_of(p) for p in by_phase} == {"ladder"}
 
 
 # ------------------------------------------------------- the tiny model
@@ -176,7 +254,10 @@ def test_program_phases_of_the_tiny_train_epochs(tiny):
     assert all(s["start_mono_s"] > 0 and s["attrs"]["epochs"] == 2
                for s in spans)
     phases = program_phases(named[0]["name"])
-    assert SCOPES <= set(phases.values())
+    # regions are on: the leaf fetch is its two sub-scopes and nothing
+    # is left under ``ff.ladder.fetch`` itself
+    assert (SCOPES - {"ff.ladder.fetch"}) | REGION_FETCH \
+        <= set(phases.values())
     assert program_phases(named[0]["name"]) is phases  # memoised
     # a second log names the program again, under the same name
     with event_log() as log2:

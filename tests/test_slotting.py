@@ -74,17 +74,21 @@ def test_jittable_and_deterministic():
     (1, 1.0, True, 7),
     (257, 0.2, False, 8),     # one element past a full row
     (257, 0.2, True, 9),
+    (5000, 0.01, False, 10),  # runs that span rows of the reshape
+    (5000, 0.01, True, 11),
+    (1 << 16, 0.3, True, 12),  # 15 bits a pass: two passes for 16
+    (3, 0.5, False, 13),       # 29 bits a pass: one pass
 ])
 def test_fill_from_marked_brute_force(n, frac, reverse, seed):
     """The segmented broadcast under every region plan: out[i] = vals
     at the nearest marked index at-or-before i (at-or-after when
-    reverse).  The boundary position is always marked, matching the
-    plans' contract."""
+    reverse).  The boundary position is always marked and the values
+    are positions below n, matching the plans' contract."""
     from dlrm_flexflow_tpu.ops.slotting import _fill_from_marked
     rng = np.random.default_rng(seed)
     marked = rng.random(n) < frac
     marked[-1 if reverse else 0] = True
-    vals = rng.integers(0, 1 << 30, size=n).astype(np.int32)
+    vals = rng.integers(0, n, size=n).astype(np.int32)
     got = np.asarray(_fill_from_marked(
         jnp.asarray(vals), jnp.asarray(marked), reverse=reverse))
     exp = np.empty(n, np.int32)
@@ -101,3 +105,84 @@ def test_fill_from_marked_brute_force(n, frac, reverse, seed):
                 cur = vals[i]
             exp[i] = cur
     np.testing.assert_array_equal(got, exp)
+
+
+def _foreign_brute(blocks):
+    """Distinct rows of each block that another block holds too."""
+    sets = [set(b.ravel().tolist()) for b in blocks]
+    return np.array([sum(any(r in s for j, s in enumerate(sets) if j != k)
+                         for r in sets[k]) for k in range(len(sets))],
+                    np.int32)
+
+
+@pytest.mark.parametrize("blocks,num_rows", [
+    # shaped ids: a table of 12 rows, 4 blocks of 6 occurrences
+    (np.random.default_rng(11).integers(0, 12, size=(4, 6)), 12),
+    (np.random.default_rng(12).integers(0, 500, size=(8, 64)), 500),
+    (np.full((3, 4), 3), 10),                   # all duplicates: 1 row
+    (np.arange(12).reshape(3, 4), 12),          # nothing shared: 0
+    (np.tile(np.arange(4), (3, 1)), 4),         # every row everywhere
+    (np.array([[5, 5, 5, 5]]), 6),              # one block: no other
+])
+def test_foreign_counts_brute_force(blocks, num_rows):
+    from dlrm_flexflow_tpu.ops.slotting import region_slots
+    got = np.asarray(region_slots(jnp.asarray(blocks, jnp.int32),
+                                  num_rows)[2])
+    np.testing.assert_array_equal(got, _foreign_brute(np.asarray(blocks)))
+
+
+def test_foreign_counts_jittable_and_deterministic():
+    import jax
+    from dlrm_flexflow_tpu.ops.slotting import region_slots
+    rng = np.random.default_rng(13)
+    blocks = jnp.asarray(rng.integers(0, 40, size=(4, 16), dtype=np.int32))
+    a = jax.jit(lambda b: region_slots(b, 40)[2])(blocks)
+    b = region_slots(blocks, 40)[2]
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(a),
+                                  _foreign_brute(np.asarray(blocks)))
+
+
+@pytest.mark.parametrize("n,p_first,p_mark,seed", [
+    (1, 1.0, 0.0, 0),
+    (64, 0.3, 0.2, 1),
+    (1000, 0.05, 0.02, 2),   # long runs, few marks; pads to 1024
+    (1025, 0.5, 0.5, 3),     # one element past a full row
+    (5000, 0.01, 0.001, 4),  # runs that span rows of the reshape
+    (4096, 0.2, 0.0, 5),     # no mark at all
+])
+def test_run_has_mark_brute_force(n, p_first, p_mark, seed):
+    """The segmented OR under ``region_slots``: whether any entry of an
+    entry's run is marked (no run-first is, as in the plan: a block
+    cannot change at a row's first entry)."""
+    from dlrm_flexflow_tpu.ops.slotting import _run_has_mark
+    rng = np.random.default_rng(seed)
+    first = rng.random(n) < p_first
+    first[0] = True
+    marked = (rng.random(n) < p_mark) & ~first
+    got = np.asarray(_run_has_mark(jnp.asarray(first), jnp.asarray(marked)))
+    run = np.cumsum(first) - 1
+    exp = np.bincount(run, weights=marked, minlength=run[-1] + 1)[run] > 0
+    np.testing.assert_array_equal(got, exp)
+
+
+@pytest.mark.parametrize("n,seed", [
+    (1, 0),
+    (3, 1),          # shorter than a row of the reshape
+    (1024, 2),       # exactly one row: no carry
+    (1025, 3),       # one element past it: the carry's first use
+    (5000, 4),       # a maximum that rides over several rows
+    (1 << 16, 5),
+])
+def test_cummax_brute_force(n, seed):
+    """The region plans' one scan primitive against
+    ``np.maximum.accumulate``, negative entries (the plans' "no mark"
+    is -1) and int32's extremes included."""
+    from dlrm_flexflow_tpu.ops.slotting import _cummax
+    rng = np.random.default_rng(seed)
+    x = rng.integers(-5, 1 << 30, size=n).astype(np.int32)
+    x[rng.random(n) < 0.7] = -1          # long stretches without a mark
+    x[rng.integers(0, n)] = np.iinfo(np.int32).max
+    x[0] = np.iinfo(np.int32).min
+    got = np.asarray(_cummax(jnp.asarray(x)))
+    np.testing.assert_array_equal(got, np.maximum.accumulate(x))
