@@ -82,6 +82,11 @@ class MetricsAccumulator:
         # accumulate device-side (no float() here: a host sync per step
         # would serialize dispatch and depress measured throughput)
         for k, v in batch_metrics.items():
+            if "/" in k:
+                # "<op>/<counter>": an op's own counters (ops/moe.py), not
+                # per-sample sums; train_epochs' metrics and the
+                # op_counters telemetry events carry them
+                continue
             self.totals[k] = self.totals.get(k, 0.0) + v
 
     def _finalized(self):

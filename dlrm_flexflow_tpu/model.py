@@ -30,6 +30,7 @@ task-per-op translation.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from dataclasses import dataclass
@@ -136,6 +137,21 @@ def _region_fetch(parent, src, base, foreign, chunk=None):
             0, (foreign + chunk - 1) // chunk, lay, own)
 
 
+def _fold_steps(name: str, per_step, counter_rank=None):
+    """One metric over an epoch's steps: the loss is their mean, anything
+    else their sum (``PerfMetrics`` are sums).  An op's counter
+    (``<op>/<counter>``, ``counter_rank`` its rank in one step) keeps its
+    own shape: summed over the leading step axes, the largest for a name
+    ending ``_max``."""
+    if name == "loss":
+        return jnp.mean(per_step)
+    if counter_rank is None:
+        return jnp.sum(per_step)
+    steps = tuple(range(per_step.ndim - counter_rank))
+    return (jnp.max if name.endswith("_max") else jnp.sum)(per_step,
+                                                           axis=steps)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class TrainState:
@@ -181,6 +197,8 @@ class FFModel:
         self._pending_lr: Optional[float] = None
         self._fit_state: Optional[TrainState] = None
         self._epoch_cache_active = False
+        # further loss terms: (tensor uid, labels' input name, weight)
+        self._aux_losses: List[Tuple[int, str, float]] = []
 
     # ------------------------------------------------------------------ utils
     def _name(self, base: str, name: Optional[str] = None) -> str:
@@ -193,6 +211,63 @@ class FFModel:
     def _add(self, op: Op) -> Tensor:
         self.layers.append(op)
         return op.outputs[0] if len(op.outputs) == 1 else op.outputs
+
+    @contextlib.contextmanager
+    def scope(self, phase: Optional[str] = None,
+              recompute: Optional[str] = None):
+        """Tag the ops built inside the block.  ``phase``: the ``ff.*``
+        scope their device time is read under (an op that names its own
+        keeps it).  ``recompute``: the ops form one run whose
+        activations are not kept for the backward pass but computed
+        again there (``jax.checkpoint`` around the run, one decoder
+        layer as a rule); only what leaves the run is saved.  Blocks
+        nest; the inner word wins (the ops are tagged as a block closes,
+        and a tag once given stays)."""
+        first = len(self.layers)
+        try:
+            yield self
+        finally:
+            for op in self.layers[first:]:
+                if phase is not None and op.phase is None:
+                    op.phase = phase
+                if recompute is not None and op.recompute is None:
+                    op.recompute = recompute
+
+    def tie(self, tensor: Tensor, owner: str) -> Tensor:
+        """Make the op that produced ``tensor`` read the parameters of
+        the op named ``owner`` in place of its own (same names, same
+        shapes): an output head over the input embedding's table, a
+        second module over the first one's head.  The tensor exists
+        once, so it gets one gradient, the sum over its readers, and one
+        optimizer slot.  The owner may be built later; ``compile``
+        checks the pair.  Returns ``tensor``."""
+        op = tensor.owner_op
+        op._tied_specs = op.param_specs()  # compile holds them to owner's
+        op.params_of = owner  # from here on the op declares none (ops/base)
+        return tensor
+
+    def _check_ties(self):
+        for op in self.layers:
+            if op.params_of is None:
+                continue
+            mine = {(s.param_name, s.shape) for s in op._tied_specs}
+            theirs = {(s.param_name, s.shape)
+                      for s in self.get_op(op.params_of).param_specs()}
+            if mine != theirs:
+                raise ValueError(
+                    f"{op.name} cannot read the parameters of "
+                    f"{op.params_of}: {sorted(mine)} != {sorted(theirs)}")
+
+    def add_aux_loss(self, tensor: Tensor, labels: Tensor,
+                     weight: float = 1.0) -> None:
+        """A further term of the training loss: ``weight`` x the compiled
+        loss function of ``tensor`` against ``labels``, an input tensor
+        (``create_tensor``) fed with every batch.  Inception's auxiliary
+        classifiers and a multi-token-prediction module are such terms."""
+        if labels.name not in {t.name for t in self._inputs}:
+            raise ValueError(f"the labels of an auxiliary loss are an "
+                             f"input tensor; {labels.name!r} is none")
+        self._aux_losses.append((tensor.uid, labels.name, float(weight)))
 
     # ------------------------------------------------------- tensor creation
     def create_tensor(self, shape, dtype="float32", name: Optional[str] = None
@@ -360,6 +435,42 @@ class FFModel:
                               num_experts, hidden_dim, top_k, activation)
         return self._add(op)
 
+    def held_experts_moe(self, input_tensor, num_experts, hidden_dim, top_k,
+                         held=None, num_shared=0, scaling=1.0,
+                         bias_update_speed=0.0, kernel_initializer=None,
+                         name=None):
+        from .ops.moe import HeldExpertsMoE
+        op = HeldExpertsMoE(self._name("moe", name), input_tensor,
+                            num_experts, hidden_dim, top_k, held, num_shared,
+                            scaling, bias_update_speed, kernel_initializer,
+                            self._op_compute_dtype())
+        return self._add(op)
+
+    def rms_norm(self, input_tensor, eps=1e-6, name=None):
+        from .ops.transformer import RMSNorm
+        return self._add(RMSNorm(self._name("rms_norm", name), input_tensor,
+                                 eps))
+
+    def gated_ffn(self, input_tensor, hidden_dim, kernel_initializer=None,
+                  name=None):
+        from .ops.transformer import GatedFFN
+        return self._add(GatedFFN(self._name("gated_ffn", name),
+                                  input_tensor, hidden_dim,
+                                  kernel_initializer,
+                                  self._op_compute_dtype()))
+
+    def latent_attention(self, input_tensor, num_heads, q_lora_rank,
+                         kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+                         v_head_dim, rope_theta=10000.0, eps=1e-6,
+                         kernel_initializer=None, name=None):
+        from .ops.attention import LatentAttention
+        op = LatentAttention(self._name("latent_attention", name),
+                             input_tensor, num_heads, q_lora_rank,
+                             kv_lora_rank, qk_nope_head_dim,
+                             qk_rope_head_dim, v_head_dim, rope_theta, eps,
+                             kernel_initializer, self._op_compute_dtype())
+        return self._add(op)
+
     def dropout(self, input_tensor, rate=0.5, seed=0, name=None):
         op = Dropout(self._name("dropout", name), input_tensor, rate, seed)
         return self._add(op)
@@ -460,42 +571,102 @@ class FFModel:
         return self.layers[-1].outputs[0]
 
     # ------------------------------------------------------------- forward fn
+    def _run_op(self, i: int, op: Op, values, params, *, training, rng,
+                bn_state, new_bn):
+        """One op of the sweep: read its inputs from ``values``, write
+        its outputs there and its new state into ``new_bn``."""
+        xs = [values[t.uid] for t in op.inputs]
+        p = params.get(op.params_of or op.name, {})
+        kw = {}
+        if getattr(op, "has_state", False):
+            kw["state"] = bn_state.get(op.name) if bn_state else None
+        op_rng = None
+        if isinstance(op, Dropout) and training and rng is not None:
+            op_rng = jax.random.fold_in(rng, i)
+        outs = op.forward(p, xs, training=training, rng=op_rng, **kw)
+        if getattr(op, "has_state", False):
+            new_bn[op.name] = op._last_state
+        # per-op placement constraint — the strategy's imprint on XLA
+        # (skipped for manual-exchange ops: their shard_map out_specs
+        # already fix the output layout, and re-constraining forces a
+        # pointless reshard)
+        if (self.mesh is not None and op.parallel_config is not None
+                and not getattr(op, "exchange_mode", None)):
+            if hasattr(op, "output_pspec"):
+                spec = op.output_pspec(op.parallel_config, self.mesh)
+            else:
+                spec = pspec_for_config(op.parallel_config,
+                                        op.outputs[0].ndim, self.mesh)
+            if spec is not None:
+                outs = [constrain(outs[0], self.mesh, spec)] + list(outs[1:])
+        for o, t in zip(outs, op.outputs):
+            values[t.uid] = o
+
+    def _runs(self):
+        """The sweep as runs of ``(recompute tag or None, [(index, op)])``:
+        consecutive ops with one tag form one run."""
+        runs = []
+        for i, op in enumerate(self.layers):
+            if runs and op.recompute is not None \
+                    and runs[-1][0] == op.recompute:
+                runs[-1][1].append((i, op))
+            else:
+                runs.append((op.recompute, [(i, op)]))
+        return runs
+
+    def _run_recomputed(self, run, values, params, *, rng, bn_state, new_bn):
+        """A tagged run under ``jax.checkpoint``: its inputs, parameters
+        and state go in as arguments, what later ops (or the loss) read
+        and the ops' new state come out, and nothing inside is kept for
+        the backward pass but what an op names (``saved_in_recompute``)."""
+        made = {t.uid for _, op in run for t in op.outputs}
+        last = run[-1][0]
+        wanted = {t.uid for op in self.layers[last + 1:] for t in op.inputs}
+        wanted |= {self.layers[-1].outputs[0].uid,
+                   getattr(self, "_loss_uid", None)}
+        wanted |= {uid for uid, _, _ in self._aux_losses}
+        out_uids = sorted(made & wanted)
+        in_uids = sorted({t.uid for _, op in run for t in op.inputs} - made)
+        names = sorted({op.params_of or op.name for _, op in run}
+                       & set(params))
+        stateful = [op.name for _, op in run
+                    if getattr(op, "has_state", False)]
+
+        def body(p, ins, st, key):
+            local, nb = dict(zip(in_uids, ins)), {}
+            for i, op in run:
+                self._run_op(i, op, local, p, training=True, rng=key,
+                             bn_state=st, new_bn=nb)
+            return [local[u] for u in out_uids], nb
+
+        keep = sorted({n for _, op in run for n in op.saved_in_recompute})
+        policy = (jax.checkpoint_policies.save_only_these_names(*keep)
+                  if keep else None)
+        outs, nb = jax.checkpoint(body, policy=policy)(
+            {n: params[n] for n in names}, [values[u] for u in in_uids],
+            {n: bn_state[n] for n in stateful} if bn_state else {}, rng)
+        values.update(zip(out_uids, outs))
+        new_bn.update(nb)
+
     def _apply(self, params, input_values: Dict[str, jnp.ndarray], *,
                training: bool, rng, bn_state):
         """Run the graph (the functional replacement of the reference's
-        per-layer IndexLauncher sweep, model.cc:948-959)."""
+        per-layer IndexLauncher sweep, model.cc:948-959).  In training,
+        a run of ops tagged ``recompute`` (``scope``) goes through
+        ``jax.checkpoint`` as one function."""
         values: Dict[int, jnp.ndarray] = {}
         for t in self._inputs:
             if t.name in input_values:
                 values[t.uid] = input_values[t.name]
         new_bn: Dict[str, Any] = {}
-        for i, op in enumerate(self.layers):
-            xs = [values[t.uid] for t in op.inputs]
-            p = params.get(op.name, {})
-            kw = {}
-            if getattr(op, "has_state", False):
-                kw["state"] = bn_state.get(op.name) if bn_state else None
-            op_rng = None
-            if isinstance(op, Dropout) and training and rng is not None:
-                op_rng = jax.random.fold_in(rng, i)
-            outs = op.forward(p, xs, training=training, rng=op_rng, **kw)
-            if getattr(op, "has_state", False):
-                new_bn[op.name] = op._last_state
-            # per-op placement constraint — the strategy's imprint on XLA
-            # (skipped for manual-exchange ops: their shard_map out_specs
-            # already fix the output layout, and re-constraining forces a
-            # pointless reshard)
-            if (self.mesh is not None and op.parallel_config is not None
-                    and not getattr(op, "exchange_mode", None)):
-                if hasattr(op, "output_pspec"):
-                    spec = op.output_pspec(op.parallel_config, self.mesh)
-                else:
-                    spec = pspec_for_config(op.parallel_config,
-                                            op.outputs[0].ndim, self.mesh)
-                if spec is not None:
-                    outs = [constrain(outs[0], self.mesh, spec)] + list(outs[1:])
-            for o, t in zip(outs, op.outputs):
-                values[t.uid] = o
+        for tag, run in self._runs():
+            if tag is not None and training:
+                self._run_recomputed(run, values, params, rng=rng,
+                                     bn_state=bn_state, new_bn=new_bn)
+                continue
+            for i, op in run:
+                self._run_op(i, op, values, params, training=training,
+                             rng=rng, bn_state=bn_state, new_bn=new_bn)
         return values, new_bn
 
     # ---------------------------------------------------------------- compile
@@ -555,6 +726,7 @@ class FFModel:
                 alpha=self.config.search_alpha, verbose=True)
             if self.config.export_strategy_file:
                 self.strategy.save(self.config.export_strategy_file)
+        self._check_ties()
         self._hetero_ops = []
         for op in self.layers:
             if op.name in self.strategy:
@@ -781,17 +953,32 @@ class FFModel:
         # cache on metadata too.
         jax.config.update("jax_compilation_cache_include_metadata_in_key",
                           True)
+        def _total_loss(values, inputs, labels):
+            """The compiled loss of the loss input, plus every auxiliary
+            term (``add_aux_loss``) against its labels among the inputs."""
+            loss = self._loss_fn(_loss_in(values), labels)
+            for uid, labels_name, weight in self._aux_losses:
+                loss = loss + weight * self._loss_fn(
+                    values[uid].astype(final_dtype), inputs[labels_name])
+            return loss
+
         def loss_and_preds(params, inputs, labels, rng, bn_state):
             with jax.named_scope("ff.step.model"):
                 values, new_bn = self._apply(params, inputs, training=True,
                                              rng=rng, bn_state=bn_state)
                 preds = _final(values)
-                loss = self._loss_fn(_loss_in(values), labels)
+                loss = _total_loss(values, inputs, labels)
             return loss, (preds, new_bn)
 
         # only Dropout consumes per-step randomness; skipping the split for
         # deterministic graphs keeps the threefry kernel out of the hot loop
         has_stochastic = self.has_stochastic
+        # ops whose state counts (ops/moe.py): each step's counters join
+        # the step's metrics as "<op>/<counter>"
+        counting_ops = [op for op in self.layers
+                        if hasattr(op, "step_metrics")]
+        self._counting_ops = [op.name for op in counting_ops]
+        counter_ranks: Dict[str, int] = {}  # filled as train_step is traced
 
         # ---- sparse embedding update fast path ---------------------------
         # Under plain SGD (no momentum / weight decay, which would touch
@@ -872,6 +1059,11 @@ class FFModel:
             view_rows = int(np.prod(spec.shape[:-1])) // pack
             return view_rows % msize == 0
 
+        # a table a second op reads (``tie``) is updated densely: the
+        # row-sparse path hands each op its own gathered rows
+        read_by_others = {op.params_of for op in self.layers
+                          if op.params_of is not None}
+
         def _device_table_op(op):
             """THE per-op eligibility both packed storage and the
             sparse-update loop share: a device-resident embedding op on
@@ -883,6 +1075,8 @@ class FFModel:
             bottom-MLP weights)."""
             return (isinstance(op, (Embedding, StackedEmbedding,
                                     RaggedStackedEmbedding))
+                    and op.name not in read_by_others
+                    and op.params_of is None
                     and getattr(op, "placement", "tpu") != "cpu"
                     and not getattr(op, "use_pallas", False)
                     and not getattr(op, "exchange_mode", None)
@@ -933,7 +1127,7 @@ class FFModel:
                 values, new_bn = self._apply(p, inputs, training=True,
                                              rng=rng, bn_state=bn_state)
                 preds = _final(values)
-                loss = self._loss_fn(_loss_in(values), labels)
+                loss = _total_loss(values, inputs, labels)
             return loss, (preds, new_bn)
 
         def _cache_gather(op, cache, slots):
@@ -1145,6 +1339,11 @@ class FFModel:
                 mets = compute_metrics(preds, labels, self.metrics,
                                        loss_type)
             mets["loss"] = loss
+            for op in counting_ops:
+                for k, v in op.step_metrics(state.bn_state[op.name],
+                                            new_bn[op.name]).items():
+                    mets[f"{op.name}/{k}"] = v
+                    counter_ranks[f"{op.name}/{k}"] = v.ndim
             new_state = TrainState(new_params, new_opt, new_bn, next_rng,
                                    state.step + 1)
             return new_state, mets
@@ -1941,7 +2140,7 @@ class FFModel:
                 else:
                     state, mets = jax.lax.scan(step_body, state,
                                                (inputs, labels, slots_ep))
-                folded = {k: (jnp.mean(v) if k == "loss" else jnp.sum(v))
+                folded = {k: _fold_steps(k, v, counter_ranks.get(k))
                           for k, v in mets.items()}
             return state, folded
 
@@ -2294,6 +2493,7 @@ class FFModel:
             out = self._run_epoch_chunks(state, inputs, labels, bounds)
         dspan.end()
         if log is not None:
+            self._emit_op_counters(log, out[1], "train_epoch")
             # dispatch-only wall (fenced=False): the scan returns before
             # the device finishes; fenced walls come from fit/bench which
             # own the device_fence.  No device values are read here — a
@@ -2337,6 +2537,7 @@ class FFModel:
             out = (state, stacked)
         dspan.end()
         if log is not None:
+            self._emit_op_counters(log, out[1], "train_epochs")
             # dispatch-only wall — see train_epoch's emission
             nb = int(labels.shape[0])
             log.emit("step", wall_s=time.perf_counter() - t0,
@@ -2345,6 +2546,25 @@ class FFModel:
                      phase="train_epochs")
             sample_memory(phase="train_epochs", log=log)
         return out
+
+    def _emit_op_counters(self, log, mets, fn: str):
+        """One ``op_counters`` event per counting op (ops/moe.py) for
+        the dispatch that returned ``mets``: its ``<op>/<counter>``
+        entries, folded over the epochs of a fused dispatch.  Reads
+        device values, so with a log active the host waits for the
+        dispatch here; a model without such ops emits and waits for
+        nothing."""
+        for name in self._counting_ops:
+            counters = {}
+            for key, value in mets.items():
+                if not key.startswith(name + "/"):
+                    continue
+                value = np.asarray(value)
+                if fn == "train_epochs":
+                    value = (value.max(axis=0) if key.endswith("_max")
+                             else value.sum(axis=0))
+                counters[key[len(name) + 1:]] = value
+            log.emit("op_counters", op=name, fn=fn, counters=counters)
 
     def _epoch_chunk_bounds(self, nb: int):
         """(lo, hi) chunk slices for a chunked epoch dispatch, or None

@@ -17,15 +17,18 @@ See parallel/ring_attention.py for the ring kernel itself.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from ..initializers import DEFAULT_KERNEL_INIT
+from ..initializers import ConstantInitializer, DEFAULT_KERNEL_INIT
 from ..tensor import ParameterSpec
-from .base import Op
+from .base import Op, matmul
+from .transformer import rms_norm, rope_interleaved
 
 
 def sdpa(q, k, v, causal: bool = False, scale: Optional[float] = None):
@@ -41,6 +44,168 @@ def sdpa(q, k, v, causal: bool = False, scale: Optional[float] = None):
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bhst,bhtd->bhsd", probs, v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _block_of(seq: int, block: int) -> int:
+    """The largest divisor of ``seq`` that is at most ``block``."""
+    block = min(block, seq)
+    while seq % block:
+        block -= 1
+    return block
+
+
+def _causal_mask(i, j, block: int):
+    """(block, block) bool: key ``j*block + c`` visible to query
+    ``i*block + r``."""
+    rows = i * block + jnp.arange(block)[:, None]
+    cols = j * block + jnp.arange(block)[None, :]
+    return cols <= rows
+
+
+def _blocks(x, block: int):
+    b, h, s, d = x.shape
+    return x.reshape(b, h, s // block, block, d)
+
+
+def _take(xb, i):
+    return jax.lax.dynamic_index_in_dim(xb, i, axis=2, keepdims=False)
+
+
+def _blockwise_fwd(q, k, v, scale: float, block: int):
+    """Online-softmax forward.  Returns ``(o (B,H,S,Dv) f32, lse
+    (B,H,S) f32)``; the (S, S) logits exist one (block, block) tile at
+    a time, and the key blocks above the diagonal are never visited."""
+    b, h, s, _ = q.shape
+    dv = v.shape[-1]
+    nb = s // block
+    qb, kb, vb = _blocks(q, block), _blocks(k, block), _blocks(v, block)
+
+    def q_block(i):
+        q_i = _take(qb, i)
+
+        def body(j, carry):
+            m, l, acc = carry
+            logits = jnp.einsum("bhqd,bhkd->bhqk", q_i, _take(kb, j),
+                                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(_causal_mask(i, j, block), logits, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
+            p = jnp.exp(logits - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "bhqk,bhkd->bhqd", p.astype(v.dtype), _take(vb, j),
+                preferred_element_type=jnp.float32)
+            return m_new, l * corr + jnp.sum(p, axis=-1), acc
+
+        init = (jnp.full((b, h, block), -jnp.inf, jnp.float32),
+                jnp.zeros((b, h, block), jnp.float32),
+                jnp.zeros((b, h, block, dv), jnp.float32))
+        m, l, acc = jax.lax.fori_loop(0, i + 1, body, init)
+        return acc / l[..., None], m + jnp.log(l)
+
+    o, lse = jax.lax.map(q_block, jnp.arange(nb))  # (nb, B, H, block, ..)
+    o = jnp.moveaxis(o, 0, 2).reshape(b, h, s, dv)
+    return o, jnp.moveaxis(lse, 0, 2).reshape(b, h, s)
+
+
+def _blockwise_bwd(q, k, v, o, lse, do, scale: float, block: int):
+    """Backward from the saved output and log-sum-exp: every visited
+    tile's probabilities are rebuilt as ``exp(logits - lse)``, so
+    nothing of size (S, S) is ever kept.  One pass over the key blocks,
+    each over the query blocks at or below it."""
+    b, h, s, dk = q.shape
+    nb = s // block
+    cd = q.dtype
+    delta = jnp.sum(do * o, axis=-1)                     # (B, H, S) f32
+    qb, kb, vb = _blocks(q, block), _blocks(k, block), _blocks(v, block)
+    dob = _blocks(do.astype(cd), block)
+    lseb = lse.reshape(b, h, nb, block)
+    deltab = delta.reshape(b, h, nb, block)
+
+    def kv_block(dq, j):
+        k_j, v_j = _take(kb, j), _take(vb, j)
+
+        def body(i, carry):
+            dk_j, dv_j, dq = carry
+            q_i, do_i = _take(qb, i), _take(dob, i)
+            logits = jnp.einsum("bhqd,bhkd->bhqk", q_i, k_j,
+                                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(_causal_mask(i, j, block), logits, -jnp.inf)
+            p = jnp.exp(logits - _take(lseb, i)[..., None])
+            dv_j = dv_j + jnp.einsum("bhqk,bhqd->bhkd", p.astype(cd), do_i,
+                                     preferred_element_type=jnp.float32)
+            dp = jnp.einsum("bhqd,bhkd->bhqk", do_i, v_j,
+                            preferred_element_type=jnp.float32)
+            ds = (p * (dp - _take(deltab, i)[..., None]) * scale).astype(cd)
+            dk_j = dk_j + jnp.einsum("bhqk,bhqd->bhkd", ds, q_i,
+                                     preferred_element_type=jnp.float32)
+            dq_i = _take(dq, i) + jnp.einsum(
+                "bhqk,bhkd->bhqd", ds, k_j,
+                preferred_element_type=jnp.float32)
+            dq = jax.lax.dynamic_update_index_in_dim(dq, dq_i, i, axis=2)
+            return dk_j, dv_j, dq
+
+        dk_j, dv_j, dq = jax.lax.fori_loop(
+            j, nb, body, (jnp.zeros(k_j.shape, jnp.float32),
+                          jnp.zeros(v_j.shape, jnp.float32), dq))
+        return dq, (dk_j, dv_j)
+
+    dq, (dk_, dv_) = jax.lax.scan(
+        kv_block, jnp.zeros((b, h, nb, block, dk), jnp.float32),
+        jnp.arange(nb))
+    unblock = lambda x: jnp.moveaxis(x, 0, 2).reshape(b, h, s, x.shape[-1])
+    return dq.reshape(b, h, s, dk), unblock(dk_), unblock(dv_)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _blockwise_core(q, k, v, scale, block):
+    return _blockwise_fwd(q, k, v, scale, block)[0]
+
+
+#: what a recomputed run (``FFModel.scope(recompute=...)``) keeps of the
+#: core: its output and log-sum-exp (134 + 1 MB a layer in f32 at 32 heads
+#: x 8,192 tokens x 128), so that the backward pass rebuilds the
+#: projections but never runs the core's forward a second time
+CORE_SAVED = ("attention_core_out", "attention_core_lse")
+
+
+def _blockwise_core_fwd(q, k, v, scale, block):
+    o, lse = _blockwise_fwd(q, k, v, scale, block)
+    o = checkpoint_name(o, CORE_SAVED[0])
+    lse = checkpoint_name(lse, CORE_SAVED[1])
+    return o, (q, k, v, o, lse)
+
+
+def _blockwise_core_bwd(scale, block, res, do):
+    q, k, v, o, lse = res
+    dq, dk, dv = _blockwise_bwd(q, k, v, o, lse, do, scale, block)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_blockwise_core.defvjp(_blockwise_core_fwd, _blockwise_core_bwd)
+
+
+#: key (and query) rows of one tile of the blockwise core.  On the v5e at
+#: 32 heads x 8,192 tokens x 192 / 128, forward + backward: 27.7 ms at
+#: 512, 43.0 at 256, 56.3 at 1,024 (``scripts/ab_lm_kernels.py``)
+ATTENTION_BLOCK = 512
+
+
+def blockwise_causal_attention(q, k, v, scale: Optional[float] = None,
+                               block: Optional[int] = None):
+    """Causal attention that never builds (B, H, S, S): online softmax
+    over key blocks forward, tile-by-tile recomputation backward
+    (``jax.custom_vjp``; plain ``jax.numpy`` under ``lax`` loops).
+    ``q``, ``k``: (B, H, S, Dk); ``v``: (B, H, S, Dv), Dv free of Dk.
+    Matmul operands keep the dtype they come in (bf16 operands give
+    bf16 x bf16 -> f32 on the MXU, the probabilities rounded to it
+    before ``P v``); logits, softmax and accumulators are f32.
+    Returns (B, H, S, Dv) f32.  The same function as ``sdpa(...,
+    causal=True)`` up to rounding.  ``block``: ``ATTENTION_BLOCK``
+    unless given, cut to the largest divisor of S below it."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    return _blockwise_core(q, k, v, float(scale),
+                           _block_of(q.shape[2], block or ATTENTION_BLOCK))
 
 
 class MultiHeadAttention(Op):
@@ -117,3 +282,120 @@ class MultiHeadAttention(Op):
         e = self.embed_dim
         # 4 projections + 2 attention matmuls
         return batch * (4 * 2 * s * e * e + 2 * 2 * s * s * e)
+
+
+class LatentAttention(Op):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434
+    section 2.1; the layout of the released DeepSeek-V3 code): (B, S, d)
+    -> (B, S, d), causal, no biases.
+
+    ``c_q = RMSNorm(x W_qa)``; ``q = c_q W_qb``, per head ``nope +
+    rope`` wide; ``[c_kv ; k_r] = x W_kva``; ``[k_nope ; v] =
+    RMSNorm(c_kv) W_kvb``, per head ``nope + v_dim`` wide; one rotary
+    key ``RoPE(k_r)`` for all heads and ``RoPE`` on each head's rotary
+    query part, on interleaved pairs; logits ``(q_nope k_nope + q_rope
+    k_rope) / sqrt(nope + rope)``; ``out = concat_h(P v) W_o``.  The
+    query/key width (``nope + rope``) need not equal ``v_dim``.
+
+    The core never builds (B, H, S, S): ``blockwise_causal_attention``,
+    ``ATTENTION_BLOCK`` keys at a time.  Scopes: ``<phase>.proj`` and
+    ``<phase>.core`` (the op's ``phase`` is ``FFModel.scope``'s word,
+    ``ff.attn`` without one).
+    """
+
+    op_type = "LatentAttention"
+    saved_in_recompute = CORE_SAVED
+
+    def __init__(self, name, input_tensor, num_heads: int, q_lora_rank: int,
+                 kv_lora_rank: int, qk_nope_head_dim: int,
+                 qk_rope_head_dim: int, v_head_dim: int,
+                 rope_theta: float = 10000.0, eps: float = 1e-6,
+                 kernel_initializer=None, compute_dtype=None):
+        super().__init__(name, [input_tensor])
+        self.model_dim = input_tensor.shape[-1]
+        self.num_heads = int(num_heads)
+        self.q_lora_rank, self.kv_lora_rank = int(q_lora_rank), \
+            int(kv_lora_rank)
+        self.nope, self.rope, self.v_dim = int(qk_nope_head_dim), \
+            int(qk_rope_head_dim), int(v_head_dim)
+        self.rope_theta, self.eps = float(rope_theta), float(eps)
+        self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT
+        self.compute_dtype = compute_dtype
+        self.outputs = [self._make_output(input_tensor.shape,
+                                          input_tensor.dtype)]
+
+    def param_specs(self):
+        d, h = self.model_dim, self.num_heads
+        init, one = self.kernel_initializer, ConstantInitializer(1.0)
+        return [
+            ParameterSpec(self.name, "w_qa", (d, self.q_lora_rank),
+                          initializer=init),
+            ParameterSpec(self.name, "q_norm", (self.q_lora_rank,),
+                          initializer=one),
+            ParameterSpec(self.name, "w_qb",
+                          (self.q_lora_rank, h * (self.nope + self.rope)),
+                          initializer=init, sharded_dim=1),
+            ParameterSpec(self.name, "w_kva",
+                          (d, self.kv_lora_rank + self.rope),
+                          initializer=init),
+            ParameterSpec(self.name, "kv_norm", (self.kv_lora_rank,),
+                          initializer=one),
+            ParameterSpec(self.name, "w_kvb",
+                          (self.kv_lora_rank, h * (self.nope + self.v_dim)),
+                          initializer=init, sharded_dim=1),
+            ParameterSpec(self.name, "w_o", (h * self.v_dim, d),
+                          initializer=init, sharded_dim=0)]
+
+    def _core(self, q, k, v, scale: float):
+        """(B, H, S, .) f32 in, (B, H, S, v_dim) f32 out; operands go to
+        the compute dtype here, each rounded once."""
+        cd = (jnp.bfloat16 if self.compute_dtype in ("bfloat16", jnp.bfloat16)
+              else jnp.float32)
+        return blockwise_causal_attention(q.astype(cd), k.astype(cd),
+                                          v.astype(cd), scale)
+
+    def forward(self, params, xs, *, training=False, rng=None):
+        (x,) = xs
+        b, s, _ = x.shape
+        h, nope, rope, vd = self.num_heads, self.nope, self.rope, self.v_dim
+        cdt = self.compute_dtype
+        positions = jnp.arange(s)
+        scope = self.phase or "ff.attn"
+        with jax.named_scope(scope + ".proj"):
+            c_q = rms_norm(matmul(x, params["w_qa"], cdt), params["q_norm"],
+                           self.eps)
+            q = matmul(c_q, params["w_qb"], cdt).reshape(b, s, h,
+                                                         nope + rope)
+            kva = matmul(x, params["w_kva"], cdt)
+            c_kv, k_r = kva[..., :self.kv_lora_rank], \
+                kva[..., self.kv_lora_rank:]
+            kv = matmul(rms_norm(c_kv, params["kv_norm"], self.eps),
+                        params["w_kvb"], cdt).reshape(b, s, h, nope + vd)
+            q_rope = rope_interleaved(q[..., nope:], positions,
+                                      self.rope_theta, seq_axis=1)
+            k_rope = rope_interleaved(k_r, positions, self.rope_theta,
+                                      seq_axis=1)
+            q_all = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+            k_all = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, rope))],
+                axis=-1)
+            heads_first = lambda t: t.transpose(0, 2, 1, 3)
+            q_all, k_all, v = heads_first(q_all), heads_first(k_all), \
+                heads_first(kv[..., nope:])
+        with jax.named_scope(scope + ".core"):
+            o = self._core(q_all, k_all, v, 1.0 / math.sqrt(nope + rope))
+        with jax.named_scope(scope + ".proj"):
+            o = o.transpose(0, 2, 1, 3).reshape(b, s, h * vd)
+            out = matmul(o, params["w_o"], cdt)
+        return [out.astype(self.outputs[0].dtype)]
+
+    def flops(self, batch):
+        s, d, h = self.inputs[0].shape[1], self.model_dim, self.num_heads
+        proj = (d * self.q_lora_rank
+                + self.q_lora_rank * h * (self.nope + self.rope)
+                + d * (self.kv_lora_rank + self.rope)
+                + self.kv_lora_rank * h * (self.nope + self.v_dim)
+                + h * self.v_dim * d)
+        core = s * h * (self.nope + self.rope + self.v_dim)  # causal: half
+        return batch * s * 2 * (proj + core)

@@ -35,9 +35,25 @@ def _named_scope_forward(fwd):
         import jax
 
         with jax.named_scope(self.name):
-            return fwd(self, *args, **kwargs)
+            if self.phase is None:
+                return fwd(self, *args, **kwargs)
+            # a phase scope (profiling.phase_of): the builder's word on
+            # which per-layer metric this op's device time belongs to
+            with jax.named_scope(self.phase):
+                return fwd(self, *args, **kwargs)
 
     wrapper.__named_scope_wrapped__ = True
+    return wrapper
+
+
+def _untied_param_specs(specs):
+    """Wrap a subclass ``param_specs``: an op that reads another op's
+    parameters (``params_of``, set by ``FFModel.tie``) declares none."""
+    @functools.wraps(specs)
+    def wrapper(self):
+        return [] if self.params_of is not None else specs(self)
+
+    wrapper.__untied_wrapped__ = True
     return wrapper
 
 
@@ -78,6 +94,18 @@ class Op:
 
     #: class-level default op-type string (reference uses OperatorType enum)
     op_type: str = "op"
+    #: ``ff.*`` phase scope opened around ``forward`` (``FFModel.scope``)
+    phase: Optional[str] = None
+    #: name of the op whose parameters this op reads in place of its own
+    #: (``FFModel.tie``): one tensor, one gradient (the sum), one slot;
+    #: ``param_specs`` is then empty
+    params_of: Optional[str] = None
+    #: tag of the run of ops that is recomputed in the backward pass
+    #: (``FFModel.scope(recompute=...)``), or None
+    recompute: Optional[str] = None
+    #: ``jax.ad_checkpoint.checkpoint_name`` names inside ``forward`` that
+    #: a recomputed run keeps instead of computing again
+    saved_in_recompute: tuple = ()
 
     def __init_subclass__(cls, **kwargs):
         # every subclass's forward runs under jax.named_scope(op.name)
@@ -91,6 +119,10 @@ class Op:
         if fwd is not None and not getattr(fwd, "__named_scope_wrapped__",
                                            False):
             cls.forward = _named_scope_forward(fwd)
+        specs = cls.__dict__.get("param_specs")
+        if specs is not None and not getattr(specs, "__untied_wrapped__",
+                                             False):
+            cls.param_specs = _untied_param_specs(specs)
 
     def __init__(self, name: str, inputs: Sequence[Tensor]):
         self.name = name

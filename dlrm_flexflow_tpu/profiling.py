@@ -260,6 +260,18 @@ def phase_of(op_name: str) -> str:
                                                     -> ff.step.model.bwd
         jit(f)/jit(_where)/select_n                 -> unattributed
 
+    A run under ``jax.checkpoint`` (``FFModel.scope(recompute=...)``)
+    is differentiated apart: in the backward pass its own stack follows
+    the *closed* ``transpose(...)`` of the enclosing scope.  There the
+    forward computed again reads ``<scope>.remat`` and the rest
+    ``<scope>.bwd``::
+
+        .../transpose(jvp(ff.step.model))/jvp(ff.step.model)/checkpoint/
+            rematted_computation/ff.lm.ffn/dot_general -> ff.lm.ffn.remat
+        .../transpose(jvp(ff.step.model))/jvp(ff.step.model)/checkpoint/
+            ff.lm.ffn/mul                              -> ff.lm.ffn.bwd
+        jit(f)/jvp(ff.step.model)/checkpoint/ff.lm.ffn/tanh -> ff.lm.ffn
+
     XLA joins the stacks of instructions it merged with ``;``: the
     first is the full one and decides."""
     stack = op_name.split(";", 1)[0]
@@ -268,14 +280,22 @@ def phase_of(op_name: str) -> str:
         pass
     if found is None:
         return UNATTRIBUTED
-    wrappers = []
+    wrappers, closed_transpose_at = [], None
     for tok in _WRAPPER.finditer(stack, 0, found.start()):
         if tok.group(0) == ")":
-            if wrappers:
-                wrappers.pop()
+            if wrappers and wrappers.pop() == "transpose" \
+                    and "transpose" not in wrappers:
+                closed_transpose_at = tok.end()
         else:
             wrappers.append(tok.group(1))
-    return found.group(0) + (".bwd" if "transpose" in wrappers else "")
+    if "transpose" in wrappers:
+        return found.group(0) + ".bwd"
+    if closed_transpose_at is not None:
+        frames = stack[closed_transpose_at:found.start()].split("/")
+        if "checkpoint" in frames:
+            return found.group(0) + (".remat" if "rematted_computation"
+                                     in frames else ".bwd")
+    return found.group(0)
 
 
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")

@@ -364,6 +364,16 @@ SCHEMA: Dict[str, dict] = {
         "required": {"name": str},
         "optional": {"fn": str},
     },
+    # what one dispatch of FFModel.train_epoch / train_epochs counted
+    # inside one op that keeps counters in its state (ops/moe.py
+    # HeldExpertsMoE: ``tokens_per_expert`` over all experts,
+    # ``held_assignments``, ``padded_rows``, ``bias_abs_max``), summed
+    # over the dispatch's steps (``*_max``: the largest).  Emitting it
+    # reads device values: the host waits for the dispatch.
+    "op_counters": {
+        "required": {"op": str, "counters": dict},
+        "optional": {"fn": str},
+    },
 }
 
 
