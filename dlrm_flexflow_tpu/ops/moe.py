@@ -16,10 +16,13 @@ dispatch is cheaper; that variant can reuse this op's parameters).
 ``HeldExpertsMoE`` below is the routed form: a layer that is told which
 of the experts it holds, routes every token over all of them, and
 computes the part of the result its own experts give, through a sort
-and a grouped matmul instead of every expert on every token.
+and grouped matmuls over the rows it was sent instead of every expert
+on every token.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -105,48 +108,35 @@ class MixtureOfExperts(Op):
 
 
 # ------------------------------------------------- the held-experts layer
-@jax.custom_vjp
-def _spread_rows(x, order, inverse):
-    """Row ``order[a] // k`` of ``x`` (T, d) for each of the ``A = T*k``
-    sorted assignments.  ``order`` is a permutation of the assignments
-    and ``inverse`` its inverse, so the transpose is a gather too (sum
-    over each token's ``k`` rows), never a scatter-add."""
-    return jnp.take(x, order // (order.shape[0] // x.shape[0]), axis=0)
-
-
-def _spread_fwd(x, order, inverse):
-    return _spread_rows(x, order, inverse), (inverse, x.shape[0])
-
-
-def _spread_bwd(res, g):
-    inverse, tokens = res
-    back = jnp.take(g, inverse, axis=0)
-    return back.reshape(tokens, -1, g.shape[-1]).sum(axis=1), None, None
-
-
-_spread_rows.defvjp(_spread_fwd, _spread_bwd)
-
-
-@jax.custom_vjp
-def _unsort_rows(y, order, inverse):
-    """``y`` (A, d) in sorted order back in assignment order: the
-    inverse permutation's gather, whose transpose is ``order``'s."""
-    return jnp.take(y, inverse, axis=0)
-
-
-def _unsort_fwd(y, order, inverse):
-    return _unsort_rows(y, order, inverse), order
-
-
-def _unsort_bwd(order, g):
-    return jnp.take(g, order, axis=0), None, None
-
-
-_unsort_rows.defvjp(_unsort_fwd, _unsort_bwd)
-
-
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+# what the layer's state counts beside ``tokens_per_expert``
+COUNTERS = ("held_assignments", "padded_rows", "buffer_rows",
+            "overflow_steps")
+# the grouped matmul's row tile on the chip; a slab is whole tiles
+ROW_TILE = 512
+# A slab holds this many even shares of the held experts' assignments
+# (``slab_rows``).  The language-model cell (16 of 256 experts held, an
+# even share 4,096 rows) sends a layer 2.9-6.8 k rows a step and none
+# of 1,200 layer-steps overflowed two shares, while every row-shaped pass
+# costs in proportion to the slab: the routed part of one layer takes
+# 12.2 ms forward + backward at 2 shares, 15.4 at 4, and a further slab
+# in the rare step that needs one 6.7 (v5e; PERF.md section 6, PR 32).
+SHARES = 2
+
+
+def slab_rows(assignments: int, num_held: int, num_experts: int) -> int:
+    """Rows of one slab of a layer that holds ``num_held`` of
+    ``num_experts`` experts and routes ``assignments = T * top_k``:
+    ``SHARES`` even shares, in whole row tiles of the grouped matmul
+    (under one tile: whole bf16 sublane tiles of 16 rows, and the slab
+    is the tile), and never more than all of them (a layer that holds
+    every expert)."""
+    rows = SHARES * -(-assignments * num_held // num_experts)
+    tile = ROW_TILE if rows > ROW_TILE else 16
+    return min(assignments, -(-rows // tile) * tile)
 
 
 def grouped_matmul(rows, weights, group_sizes):
@@ -160,11 +150,136 @@ def grouped_matmul(rows, weights, group_sizes):
     never visits their tiles), and the caller masks them."""
     if _on_tpu():
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-        tiling = (min(512, rows.shape[0]), min(1024, rows.shape[1]),
+        tiling = (min(ROW_TILE, rows.shape[0]), min(1024, rows.shape[1]),
                   min(1024, weights.shape[2]))
         return megablox.gmm(rows, weights, group_sizes, jnp.float32, tiling)
     return jax.lax.ragged_dot(rows, weights, group_sizes,
                               preferred_element_type=jnp.float32)
+
+
+def _slab_experts(rows, w_gate, w_up, w_down, live, sizes):
+    """The held experts' SwiGLU over one slab's rows; a row that is not
+    ``live`` comes back zero whatever the grouped matmul left there."""
+    gate = grouped_matmul(rows, w_gate, sizes)
+    up = grouped_matmul(rows, w_up, sizes)
+    act = jnp.where(live, jax.nn.silu(gate) * up, 0.0)
+    y = grouped_matmul(act.astype(rows.dtype), w_down, sizes)
+    return jnp.where(live, y, 0.0)
+
+
+def _each_slab(slabs, body, carry):
+    """``carry = body(s, carry)`` for ``s`` in ``range(slabs)``,
+    ``slabs`` a traced count."""
+    return jax.lax.while_loop(
+        lambda sc: sc[0] < slabs,
+        lambda sc: (sc[0] + 1, body(sc[0], sc[1])), (0, carry))[1]
+
+
+def _slabs_of(static, x, gates, order, group_sizes):
+    """``(slabs, fetch)`` for both passes of ``_held_experts``: how many
+    slabs hold an assignment to a held expert (at least one), and
+    ``fetch(s) -> (ids, tokens, live, sizes, rows, weights)`` for slab
+    ``s``, sorted positions ``[s * slab, (s + 1) * slab)``: the
+    assignments there and their tokens, which positions hold an
+    assignment to a held expert ((slab, 1) bool), how many rows of each
+    group fall inside, the tokens' rows of ``x`` and the assignments'
+    gates, both zero where not ``live``."""
+    slab, k, scope = static
+    order = jnp.pad(order, (0, -order.shape[0] % slab))   # whole slabs
+    ends = jnp.cumsum(group_sizes)
+
+    def fetch(s):
+        with jax.named_scope(scope + ".dispatch"):
+            lo = s * slab
+            ids = jax.lax.dynamic_slice(order, (lo,), (slab,))
+            tokens = ids // k
+            live = (lo + jnp.arange(slab) < ends[-1])[:, None]
+            sizes = (jnp.clip(ends, lo, lo + slab)
+                     - jnp.clip(ends - group_sizes, lo, lo + slab))
+            rows = jnp.where(live, jnp.take(x, tokens, axis=0), 0)
+        with jax.named_scope(scope + ".combine"):
+            weights = jnp.where(live[:, 0], jnp.take(gates, ids), 0.0)
+        return ids, tokens, live, sizes, rows, weights
+
+    return jnp.maximum(1, -(-ends[-1] // slab)), fetch
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(static, x, w_gate, w_up, w_down, gates, order,
+                  group_sizes):
+    """``(out (T, d) f32, slabs)``: for every assignment to a held
+    expert, ``gate * expert(x[token])`` added into its token's row, a
+    slab of ``static = (slab, top_k, scope)`` sorted positions at a
+    time; ``slabs = max(1, ceil(held assignments / slab))`` is how many
+    ran.  ``order`` (A,) holds the assignments sorted by held expert,
+    the others behind them; ``gates`` (A,) is in assignment order; the
+    experts' weights are rounded to ``x``'s dtype.
+
+    The loop's count is a traced number, so JAX cannot differentiate
+    it and does not have to: the backward pass is written below, loops
+    over the same slabs, and computes each slab's forward again from
+    ``x`` and the (A,) vectors, which are all that is kept.  No array
+    of A rows by a model or hidden width exists in either pass.  A
+    token's rows are added in the order the slabs hold them: the one
+    place where the order of the sums is not the dense layer's."""
+    scope = static[2]
+    slabs, fetch = _slabs_of(static, x, gates, order, group_sizes)
+    with jax.named_scope(scope + ".experts"):
+        experts = [w.astype(x.dtype) for w in (w_gate, w_up, w_down)]
+
+    def add(s, out):
+        _ids, tokens, live, sizes, rows, weights = fetch(s)
+        with jax.named_scope(scope + ".experts"):
+            y = _slab_experts(rows, *experts, live, sizes)
+        with jax.named_scope(scope + ".combine"):
+            return out.at[tokens].add(y * weights[:, None])
+
+    return _each_slab(slabs, add, jnp.zeros(x.shape, jnp.float32)), slabs
+
+
+def _held_experts_fwd(static, *args):
+    return _held_experts(static, *args), args
+
+
+def _held_experts_bwd(static, res, cts):
+    x, w_gate, w_up, w_down, gates, order, group_sizes = res
+    scope, g = static[2], cts[0]
+    slabs, fetch = _slabs_of(static, x, gates, order, group_sizes)
+    with jax.named_scope(scope + ".experts"):
+        experts = [w.astype(x.dtype) for w in (w_gate, w_up, w_down)]
+        dws = [jnp.zeros(w.shape, jnp.float32) for w in experts]
+
+    def add(s, acc):
+        dx, dgates, dws = acc
+        ids, tokens, live, sizes, rows, weights = fetch(s)
+        with jax.named_scope(scope + ".experts"):
+            y, pull = jax.vjp(
+                lambda r, *w: _slab_experts(r, *w, live, sizes), rows,
+                *experts)
+        with jax.named_scope(scope + ".combine"):
+            g_rows = jnp.take(g, tokens, axis=0)
+            dgates = dgates.at[ids].add(
+                jnp.where(live[:, 0], jnp.sum(g_rows * y, axis=-1), 0.0))
+            dy = g_rows * weights[:, None]
+        with jax.named_scope(scope + ".experts"):
+            drows, *dw = pull(dy)
+            dws = [a + b for a, b in zip(dws, dw)]   # summed in f32
+        with jax.named_scope(scope + ".dispatch"):
+            # megablox never visits the padding's tiles, so their
+            # cotangent is whatever was there
+            dx = dx.at[tokens].add(
+                jnp.where(live, drows, 0).astype(jnp.float32))
+        return dx, dgates, dws
+
+    dx, dgates, dws = _each_slab(slabs, add, (
+        jnp.zeros(x.shape, jnp.float32), jnp.zeros(gates.shape, jnp.float32),
+        dws))
+    return (dx.astype(x.dtype), *(d.astype(w.dtype) for d, w in
+                                  zip(dws, (w_gate, w_up, w_down))),
+            dgates, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 class HeldExpertsMoE(Op):
@@ -182,23 +297,41 @@ class HeldExpertsMoE(Op):
     shared experts; what the absent experts would add is left out (one
     chip's share of an expert-parallel layer, without its exchange).
 
-    No assignment to a held expert is dropped: the assignments are
-    sorted by held expert into a buffer of all ``T * top_k`` of them
-    (the worst case: every token choosing held experts only), the
-    grouped matmul runs over the held groups, and the rows behind them
-    are padding that is masked, and counted.
+    No assignment to a held expert is dropped, and none is computed
+    twice: a stable sort puts the assignments to held experts first,
+    grouped by expert, and the layer works through them a slab of
+    ``slab_rows(T * top_k, held, experts)`` sorted positions at a time
+    (``_held_experts``): gather the slab's tokens, three grouped
+    matmuls, scatter-add each row times its gate into its token's
+    output.  One slab in the usual step, ``ceil(held assignments /
+    slab)`` in a step that overflows it; a layer that holds every
+    expert has one slab of all ``T * top_k`` rows.  Only (A,) vectors
+    are as long as the assignments.  The combine is a scatter-add
+    because a slab is short: over a slab's 8,192 rows it takes 1.6 ms
+    forward + backward on the v5e, where gathering all 65,536 rows back
+    to assignment order and summing each token's eight takes 7.0
+    (``scripts/ab_lm_kernels.py rows``).
 
     ``b`` is state, not a parameter (the ``bn_state`` route): it takes
     no gradient, and each training step moves it by ``bias_update_speed
     * sign(mean(c) - c)``, ``c`` the step's tokens per expert.  The state
-    also counts, since ``init``: ``tokens_per_expert`` (all experts),
-    ``held_assignments``, ``padded_rows`` (buffer rows the grouped
-    matmul was given beyond the held assignments).
+    also counts, since ``init``: ``tokens_per_expert`` (all experts);
+    ``held_assignments``; ``padded_rows``, the step's assignments that
+    go to no held expert (``T * top_k - held_assignments``, whatever
+    the buffer: the two add up to every assignment, which the
+    benchmark's comparison holds them to); ``buffer_rows``, the rows
+    the grouped matmuls were given (slabs x the slab's rows, so
+    ``held_assignments / buffer_rows`` is the fill); ``overflow_steps``,
+    the steps that took more than one slab.  A state that lacks a
+    counter (the benchmark's control writes the first three) is
+    carried as it is.
 
     Scopes (the prefix is the op's ``phase``, ``FFModel.scope``'s word,
     ``ff.moe`` without one): ``.route`` (scores, top-k, counts),
-    ``.dispatch`` (sort, gather), ``.experts`` (the grouped matmuls),
-    ``.combine``, ``.shared``.
+    ``.dispatch`` (sort, gather; backward: the scatter-add into the
+    tokens' gradient), ``.experts`` (the grouped matmuls and their
+    activation: forward, again inside the backward, backward),
+    ``.combine`` (gate, scatter-add; backward: gather), ``.shared``.
     """
 
     op_type = "HeldExpertsMoE"
@@ -253,15 +386,13 @@ class HeldExpertsMoE(Op):
         return {"bias": jnp.zeros((self.num_experts,), jnp.float32),
                 "tokens_per_expert": jnp.zeros((self.num_experts,),
                                                jnp.int32),
-                "held_assignments": jnp.zeros((), jnp.int32),
-                "padded_rows": jnp.zeros((), jnp.int32)}
+                **{name: jnp.zeros((), jnp.int32) for name in COUNTERS}}
 
     def step_metrics(self, old, new):
         """This step's counters for the step's metrics (``train_epoch``
         folds them: sums, and the largest for a name ending ``_max``)."""
-        out = {k: new[k] - old[k] for k in ("tokens_per_expert",
-                                             "held_assignments",
-                                             "padded_rows")}
+        out = {k: new[k] - old[k]
+               for k in ("tokens_per_expert",) + COUNTERS if k in old}
         out["bias_abs_max"] = jnp.max(jnp.abs(new["bias"]))
         return out
 
@@ -299,30 +430,18 @@ class HeldExpertsMoE(Op):
             local = idx.reshape(-1) - self.first_held
             here = (local >= 0) & (local < self.num_held)
             key = jnp.where(here, local, self.num_held)
-            # stable, so a group keeps its tokens in sequence order
+            # stable, so a group keeps its tokens in sequence order, and
+            # the assignments to held experts come first
             order = jnp.argsort(key, stable=True).astype(jnp.int32)
-            inverse = jnp.argsort(order).astype(jnp.int32)
             group_sizes = jnp.sum(
                 key[:, None] == jnp.arange(self.num_held), axis=0,
                 dtype=jnp.int32)
             n_here = jnp.sum(group_sizes)
-            live = (jnp.arange(tokens * k) < n_here)[:, None]
-            # the select is for the backward: megablox never visits the
-            # padding's tiles, so their cotangent is whatever was there
-            rows = jnp.where(live, _spread_rows(x.astype(cd), order,
-                                                inverse), 0)
-        with jax.named_scope(scope + ".experts"):
-            gate = grouped_matmul(rows, params["w_gate"].astype(cd),
-                                  group_sizes)
-            up = grouped_matmul(rows, params["w_up"].astype(cd), group_sizes)
-            act = jnp.where(live, jax.nn.silu(gate) * up, 0.0)
-            y = grouped_matmul(act.astype(cd), params["w_down"].astype(cd),
-                               group_sizes)
-            y = jnp.where(live, y, 0.0)
-        with jax.named_scope(scope + ".combine"):
-            weights = jnp.where(here, gates.reshape(-1), 0.0)
-            y = _unsort_rows(y, order, inverse) * weights[:, None]
-            out = jnp.sum(y.reshape(tokens, k, d), axis=1)
+        slab = slab_rows(tokens * k, self.num_held, self.num_experts)
+        out, slabs = _held_experts(
+            (slab, k, scope), x.astype(cd), params["w_gate"],
+            params["w_up"], params["w_down"], gates.reshape(-1), order,
+            group_sizes)
         if self.num_shared:
             with jax.named_scope(scope + ".shared"):
                 out = out + swiglu(x, params["shared_gate"],
@@ -333,12 +452,16 @@ class HeldExpertsMoE(Op):
             with jax.named_scope(scope + ".route"):
                 mean = jnp.mean(counts.astype(jnp.float32))
                 new_state = {
+                    **state,
                     "bias": state["bias"] + self.bias_update_speed
                     * jnp.sign(mean - counts.astype(jnp.float32)),
                     "tokens_per_expert": state["tokens_per_expert"] + counts,
-                    "held_assignments": state["held_assignments"] + n_here,
-                    "padded_rows": state["padded_rows"]
-                    + (tokens * k - n_here)}
+                    **{name: state[name] + count for name, count in (
+                        ("held_assignments", n_here),
+                        ("padded_rows", tokens * k - n_here),
+                        ("buffer_rows", slabs * slab),
+                        ("overflow_steps", (slabs > 1).astype(jnp.int32)))
+                       if name in state}}
         self._last_state = new_state
         return [out.reshape(x_in.shape).astype(self.outputs[0].dtype)]
 

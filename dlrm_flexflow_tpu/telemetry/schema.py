@@ -367,9 +367,12 @@ SCHEMA: Dict[str, dict] = {
     # what one dispatch of FFModel.train_epoch / train_epochs counted
     # inside one op that keeps counters in its state (ops/moe.py
     # HeldExpertsMoE: ``tokens_per_expert`` over all experts,
-    # ``held_assignments``, ``padded_rows``, ``bias_abs_max``), summed
-    # over the dispatch's steps (``*_max``: the largest).  Emitting it
-    # reads device values: the host waits for the dispatch.
+    # ``held_assignments``, ``padded_rows`` (assignments to no held
+    # expert), ``buffer_rows`` (rows the grouped matmuls were given:
+    # slabs x the slab's rows), ``overflow_steps`` (steps that took more
+    # than one slab), ``bias_abs_max``), summed over the dispatch's
+    # steps (``*_max``: the largest).  Emitting it reads device values:
+    # the host waits for the dispatch.
     "op_counters": {
         "required": {"op": str, "counters": dict},
         "optional": {"fn": str},

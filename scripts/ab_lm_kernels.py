@@ -1,7 +1,7 @@
 """On the chip: the two kernels of the language-model family at the
 shapes of ``joyai-flash-ep16.pretrain-8k``, each beside its rival.
 
-    chiprun -- python scripts/ab_lm_kernels.py [attn] [gmm]
+    chiprun -- python scripts/ab_lm_kernels.py [attn] [gmm] [rows] [slabs]
 
 attn: the causal attention core (32 heads, 8,192 tokens, query/key
 width 192, value width 128, bf16), forward + backward: the blockwise
@@ -16,8 +16,22 @@ backward, the two forms of ``ops/moe.py::grouped_matmul``:
 ``jax.lax.ragged_dot`` and megablox ``gmm`` at its tiles, each checked
 against a per-group dense matmul (PR 31, v5e: 3.96 ms and 1.95, both
 exact; megablox at (128, 128, 128) tiles 6.68, (256, 2048, 768) does
-not fit VMEM).  Prints ms per call; nothing here is read by the
-benchmark.
+not fit VMEM), and the same over one slab of 8,192 rows (PR 32).
+rows: the held-experts layer's row movement (8,192 tokens, 2,048 wide,
+top-8: 65,536 assignments of which ~4,096 go to held experts), forward
++ backward: the dispatch as a gather to all 65,536 sorted positions
+(the layer before PR 32) against a gather to one slab of ``SHARES``
+even shares (``ops/moe.py::slab_rows``), and the combine as a 65,536-row
+gather back to assignment order + a sum over each token's 8 against a
+scatter-add of the slab's rows into their tokens (PR 32, v5e: PERF.md
+section 6 has the numbers).
+slabs: the whole routed part of ``HeldExpertsMoE`` (16 of 256 experts
+held, no shared expert, bf16), forward + backward, under a bias on the
+held experts that sends them about 4 k, more than one slab, and all
+65,536 assignments: in slabs of ``SHARES`` even shares, of 4, and in
+one slab of all rows (``SHARES`` = 16), with the slabs each took and the
+largest differences between the first and the last.  Prints ms per call; nothing here is read
+by the benchmark.
 """
 
 from __future__ import annotations
@@ -111,9 +125,9 @@ def attn():
                   f"{errs}", flush=True)
 
 
-def gmm():
+def gmm(m=65536):
     from dlrm_flexflow_tpu.ops import moe as moe_ops
-    m, k, n, g = 65536, 2048, 768, 16
+    k, n, g = 2048, 768, 16
     rng = np.random.default_rng(0)
     sizes = rng.multinomial(4096, np.ones(g) / g).astype(np.int32)
     keys = jax.random.split(jax.random.PRNGKey(1), 3)
@@ -144,7 +158,7 @@ def gmm():
         ms_f, y = timed(fwd, x, w)
         ms_b, (_, (dx, dw)) = timed(both, x, w)
         err = float(jnp.max(jnp.abs(y[:total] - want)))
-        print(f"gmm {name}: fwd {ms_f:.3f} ms, fwd+bwd {ms_b:.3f} ms, "
+        print(f"gmm {name}, {m} rows: fwd {ms_f:.3f} ms, fwd+bwd {ms_b:.3f} ms, "
               f"max err vs dense {err:.3e}, |dw| "
               f"{float(jnp.linalg.norm(dw.astype(jnp.float32))):.4f} "
               f"|dx| {float(jnp.linalg.norm(dx.astype(jnp.float32))):.4f}",
@@ -158,8 +172,146 @@ def gmm():
                   flush=True)
 
 
+def rows():
+    from dlrm_flexflow_tpu.ops.moe import slab_rows
+    t, d, k, live_n = 8192, 2048, 8, 4096
+    a = t * k
+    c = slab_rows(a, 16, 256)
+    rng = np.random.default_rng(0)
+    held = rng.permutation(a)[:live_n]          # assignments to held experts
+    rest = np.setdiff1d(np.arange(a), held)
+    order = jnp.asarray(np.concatenate([np.sort(held), rest]), jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(2), 5)
+    x = jax.random.normal(keys[0], (t, d), jnp.bfloat16)
+    gates = jax.random.uniform(keys[1], (a,), jnp.float32)
+    live = lambda n: (jnp.arange(n) < live_n)[:, None]
+    ct_all = jax.random.normal(keys[2], (a, d), jnp.bfloat16)
+    y_all = jnp.where(live(a), jax.random.normal(keys[3], (a, d)), 0.0)
+    ct_rows, ys = {a: ct_all, c: ct_all[:c]}, {a: y_all, c: y_all[:c]}
+    ct_out = jax.random.normal(keys[4], (t, d), jnp.float32)
+
+    # the forms before PR 32: both directions a gather over all A rows
+    @jax.custom_vjp
+    def spread(x):
+        return jnp.take(x, order // k, axis=0)
+    spread.defvjp(lambda x: (spread(x), None),
+                  lambda _, g: (jnp.take(g, inverse, axis=0)
+                                .reshape(t, k, d).sum(axis=1),))
+
+    @jax.custom_vjp
+    def unsort(y):
+        return jnp.take(y, inverse, axis=0)
+    unsort.defvjp(lambda y: (unsort(y), None),
+                  lambda _, g: (jnp.take(g, order, axis=0),))
+
+    def dispatch_all(x):
+        return jnp.where(live(a), spread(x), 0)
+
+    def dispatch_slab(x):   # its transpose: a scatter-add of c rows
+        return jnp.where(live(c), jnp.take(x, order[:c] // k, axis=0), 0)
+
+    def combine_all(y):
+        weights = jnp.where(inverse < live_n, gates, 0.0)
+        return jnp.sum((unsort(y) * weights[:, None]).reshape(t, k, d), 1)
+
+    def combine_slab(y):    # its transpose: a gather of c rows
+        weights = jnp.where(live(c)[:, 0], jnp.take(gates, order[:c]), 0.0)
+        return jnp.zeros((t, d), jnp.float32).at[order[:c] // k].add(
+            y * weights[:, None])
+
+    outs = {}
+
+    def measure(name, form, arg, ct):
+        fwd = jax.jit(form)
+        both = jax.jit(jax.value_and_grad(
+            lambda v: jnp.sum(form(v).astype(jnp.float32) * ct)))
+        ms_f, out = timed(fwd, arg)
+        ms_b, (_, grad) = timed(both, arg)
+        outs.setdefault(name.split(",")[0], []).append((out, grad))
+        print(f"rows {name}: fwd {ms_f:.3f} ms, fwd+bwd {ms_b:.3f} ms",
+              flush=True)
+
+    for name, form, arg, ct in (
+            ("dispatch, gather to all %d" % a, dispatch_all, x, ct_rows[a]),
+            ("dispatch, gather to a slab of %d" % c, dispatch_slab, x,
+             ct_rows[c]),
+            ("combine, %d-row gather + sum" % a, combine_all, ys[a], ct_out),
+            ("combine, %d-row scatter-add" % c, combine_slab, ys[c],
+             ct_out)):
+        try:
+            measure(name, form, arg, ct)
+        except Exception as e:
+            print(f"rows {name}: FAILED {type(e).__name__}: "
+                  f"{str(e)[:600]}", flush=True)
+    for kind, pair in outs.items():
+        if len(pair) == 2:   # the slab's rows are the first c of all a
+            diffs = [float(jnp.max(jnp.abs(
+                u[:c].astype(jnp.float32) - v[:c].astype(jnp.float32))))
+                for u, v in zip(*pair)]
+            print(f"rows {kind}: slab against all, max |out, grad| diff "
+                  f"{diffs}", flush=True)
+
+
+def slabs():
+    from dlrm_flexflow_tpu.ops import moe as moe_ops
+    from dlrm_flexflow_tpu.tensor import Tensor
+    t, d = 8192, 2048
+    op = moe_ops.HeldExpertsMoE(
+        "moe", Tensor((1, t, d), jnp.float32, name="x"), 256, 768, 8,
+        (0, 16), 0, 2.5, 0.0, compute_dtype="bfloat16")
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    params = op.init_params(keys[0])
+    x = jax.random.normal(keys[1], (1, t, d), jnp.float32)
+    ct = jax.random.normal(keys[2], (1, t, d), jnp.float32)
+
+    def step(params, x, bias):
+        state = dict(op.init_state(), bias=bias)
+        out = op.forward(params, [x], training=True, state=state)[0]
+        return jnp.sum(out * ct), (out, {k: op._last_state[k]
+                                         for k in moe_ops.COUNTERS})
+
+    shares = moe_ops.SHARES
+
+    def measure(name, n, lift, bias, got):
+        moe_ops.SHARES = n
+        try:   # a new function: a new trace under this SHARES
+            both = jax.jit(jax.value_and_grad(
+                lambda p, x, b: step(p, x, b), (0, 1), has_aux=True))
+            ms, ((_, (out, counted)), grads) = timed(both, params, x, bias)
+        finally:
+            moe_ops.SHARES = shares
+        got[name] = (out, grads)
+        print(f"slabs bias {lift}, {name}: fwd+bwd {ms:.3f} ms, "
+              + ", ".join(f"{k} {int(v)}" for k, v in counted.items()),
+              flush=True)
+
+    for lift in (0.0, 0.12, 0.3, 10.0):
+        bias = jnp.zeros((256,)).at[:16].set(lift)
+        got = {}
+        for name, n in (("slabs", shares), ("slabs of 4 shares", 4),
+                        ("all rows", 16)):
+            try:
+                measure(name, n, lift, bias, got)
+            except Exception as e:
+                print(f"slabs {name}: FAILED {type(e).__name__}: "
+                      f"{str(e)[:600]}", flush=True)
+        if "slabs" in got and "all rows" in got:
+            a, b = (jax.tree_util.tree_leaves(got[name])
+                    for name in ("slabs", "all rows"))
+            print(f"slabs bias {lift}: largest |difference| of the output, "
+                  f"the weights' and the input's gradients "
+                  f"{max(float(jnp.max(jnp.abs(u - v))) for u, v in zip(a, b)):.3e}"
+                  f", largest |value| "
+                  f"{max(float(jnp.max(jnp.abs(u))) for u in a):.3e}",
+                  flush=True)
+
+
 if __name__ == "__main__":
     print(f"device: {jax.devices()[0].device_kind}", flush=True)
-    which = sys.argv[1:] or ["attn", "gmm"]
+    which = sys.argv[1:] or ["attn", "gmm", "rows", "slabs"]
     for name in which:
-        {"attn": attn, "gmm": gmm}[name]()
+        {"attn": attn, "gmm": gmm, "rows": rows, "slabs": slabs}[name]()
+        if name == "gmm":   # and over one slab of the layer (PR 32)
+            from dlrm_flexflow_tpu.ops.moe import slab_rows
+            gmm(slab_rows(65536, 16, 256))

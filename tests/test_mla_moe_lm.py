@@ -225,13 +225,68 @@ def test_the_shares_add_up_to_the_uncut_layer(uncut):
         total + shared.reshape(x.shape), want, atol=4e-6)
 
 
+def _steered(cfg, op, whole: int, one_more: bool = False):
+    """Parameters and tokens (2, 32) for a layer that holds experts 4-7
+    in which the first ``whole`` tokens select all four held experts,
+    one further token selects expert 4 alone if ``one_more``, and the
+    others select none of the four: ``4 * whole + one_more``
+    assignments to held experts, exactly."""
+    params = op.init_params(jax.random.PRNGKey(8))
+    x = jax.random.normal(jax.random.PRNGKey(9), (64, cfg.hidden_size))
+    x = x.at[:, 0].set(jnp.where(jnp.arange(64) < whole, 6.0, -6.0))
+    x = x.at[:, 1].set(0.0)
+    router = params["router"].at[:, 4:8].set(0.0).at[0, 4:8].set(5.0)
+    if one_more:
+        x = x.at[whole, 1].set(12.0)
+        router = router.at[1, 4].set(5.0)
+    return dict(params, router=router), x.reshape(2, 32, cfg.hidden_size)
+
+
+def _held_layer_against_the_reference(cfg, op, params, bias, x, atol=2e-5):
+    """Output, every parameter's gradient and the input's, against the
+    reference's expert layer; returns the counters the step left."""
+    hp = dict(_hp(cfg), first_expert_held=op.first_held)
+    flat = x.reshape(-1, cfg.hidden_size)
+    state = dict(op.init_state(), bias=bias)
+    got = op.forward(params, [x], training=True, state=state)[0]
+    new = {k: int(op._last_state[k]) for k in moe_ops.COUNTERS}
+    want, counts = ref.expert_layer(_ref_moe_params(params), bias, flat, hp,
+                                    F32)
+    np.testing.assert_allclose(got.reshape(want.shape), want, atol=1e-5)
+    lo = op.first_held
+    assert new["held_assignments"] == int(counts[lo:lo + op.num_held].sum())
+    loss = lambda f: lambda p, x: jnp.sum(jnp.sin(f(p, x)))
+    got_g = jax.grad(loss(lambda p, x: op.forward(
+        p, [x], training=True, state=state)[0]), (0, 1))(params, x)
+    want_g = jax.grad(loss(lambda p, x: ref.expert_layer(
+        _ref_moe_params(p), bias, x.reshape(flat.shape), hp,
+        F32)[0].reshape(x.shape)), (0, 1))(params, x)
+    for name in params:
+        assert np.all(np.isfinite(got_g[0][name])), name
+        np.testing.assert_allclose(got_g[0][name], want_g[0][name],
+                                   rtol=1e-5, atol=atol, err_msg=name)
+    np.testing.assert_allclose(got_g[1], want_g[1], rtol=1e-5, atol=atol)
+    return new
+
+
+def _slab_counters_hold(new, assignments, slab):
+    """What the counters promise whatever the step held."""
+    here = new["held_assignments"]
+    assert here + new["padded_rows"] == assignments
+    slabs = max(1, -(-here // slab))
+    assert new["buffer_rows"] == slabs * slab
+    assert new["buffer_rows"] % slab == 0
+    assert new["overflow_steps"] == int(here > slab)
+
+
 @pytest.mark.parametrize("impl", ["ragged", "megablox"])
 def test_no_token_is_dropped_when_every_token_selects_held_experts(
         impl, monkeypatch):
-    """A bias that makes every token select the four held experts: the
-    buffer's worst case, all T * k rows live, none padded, and the
-    output still the reference's.  ``megablox`` runs in Pallas
-    interpret mode here."""
+    """A bias that makes every token select the four held experts: all
+    T * k = 256 assignments are held, twice the slab of 128, so the
+    step overflows into a second slab, none is padded or dropped, and
+    output and gradients are still the reference's.  ``megablox`` runs
+    in Pallas interpret mode here."""
     cfg = _small()
     op = _moe(cfg, (4, 4))
     monkeypatch.setattr(moe_ops, "_on_tpu", lambda: impl == "megablox")
@@ -240,20 +295,93 @@ def test_no_token_is_dropped_when_every_token_selects_held_experts(
     x = jax.random.normal(jax.random.PRNGKey(9), (2, 32, cfg.hidden_size))
     if impl == "megablox":
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-        real = megablox.gmm
+        real = megablox.gmm   # its backward takes the option along
         monkeypatch.setattr(
             megablox, "gmm",
             lambda *a, **kw: real(*a, **dict(kw, interpret=True)))
-    got = op.forward(params, [x], training=True,
-                     state=dict(op.init_state(), bias=bias))[0]
-    assert int(op._last_state["held_assignments"]) == 64 * 4
-    assert int(op._last_state["padded_rows"]) == 0
+    new = _held_layer_against_the_reference(cfg, op, params, bias, x)
+    assert new["held_assignments"] == 64 * 4 and new["padded_rows"] == 0
+    assert moe_ops.slab_rows(256, 4, 16) == 128
+    assert new["buffer_rows"] == 256 and new["overflow_steps"] == 1
+    _slab_counters_hold(new, 256, 128)
     np.testing.assert_array_equal(op._last_state["tokens_per_expert"][4:8],
                                   [64] * 4)
-    hp = dict(_hp(cfg), first_expert_held=4)
-    want, _ = ref.expert_layer(_ref_moe_params(params), bias,
-                               x.reshape(-1, cfg.hidden_size), hp, F32)
-    np.testing.assert_allclose(got.reshape(want.shape), want, atol=1e-5)
+
+
+@pytest.mark.parametrize("whole,one_more,slabs", [
+    (None, False, 1),   # a held share as the router deals it: one slab
+    (32, False, 1),     # exactly the slab's 128 rows
+    (32, True, 2),      # one row over: a second slab for it
+    (0, False, 1),      # no token selects a held expert
+    (63, True, 2),      # all but three rows of two slabs
+])
+def test_the_held_share_works_on_slabs_and_drops_nothing(whole, one_more,
+                                                         slabs):
+    """A layer holding 4 of 16 experts, 64 tokens, top-4: A = 256, the
+    slab 128 rows.  However many assignments the step holds, output and
+    every gradient are the reference's and the counters add up."""
+    cfg = _small()
+    op = _moe(cfg, (4, 4))
+    if whole is None:
+        params = op.init_params(jax.random.PRNGKey(8))
+        x = jax.random.normal(jax.random.PRNGKey(9),
+                              (2, 32, cfg.hidden_size))
+    else:
+        params, x = _steered(cfg, op, whole, one_more)
+    new = _held_layer_against_the_reference(cfg, op, params,
+                                            jnp.zeros((16,)), x)
+    if whole is None:
+        assert 0 < new["held_assignments"] < 128
+    else:
+        assert new["held_assignments"] == 4 * whole + one_more
+    assert new["buffer_rows"] == slabs * 128
+    _slab_counters_hold(new, 256, 128)
+    if whole == 0:   # the shared expert's output alone
+        got = op.forward(params, [x])[0].reshape(-1, cfg.hidden_size)
+        np.testing.assert_allclose(got, ref.swiglu(
+            x.reshape(got.shape), _ref_moe_params(params)["shared"], F32),
+            atol=1e-6)
+
+
+@pytest.mark.parametrize("assignments,held,experts,rows", [
+    (65536, 16, 256, 8192),   # the language-model cell: two even shares
+    (65536, 256, 256, 65536),  # every expert held: the layer uncut
+    (256, 4, 16, 128),        # the tests' and the CPU rehearsal's
+    (8192, 3, 64, 1024),      # 768 rows, in whole row tiles of 512
+    (96, 1, 16, 16),          # 12 rows, in whole sublane tiles
+    (40, 8, 16, 40),          # never more than all of them
+])
+def test_a_slab_is_a_few_even_shares_in_whole_tiles(assignments, held,
+                                                    experts, rows):
+    assert moe_ops.slab_rows(assignments, held, experts) == rows
+
+
+def test_no_array_has_all_assignments_by_a_width():
+    """Forward and backward of the held share: only vectors are as long
+    as all T * k assignments; nothing of that many rows has a model or
+    hidden width beside it (the (A, held) compare that counts the groups
+    is the one two-dimensional exception, 4 wide here)."""
+    cfg = _small()
+    op = _moe(cfg, (4, 4))
+    params = op.init_params(jax.random.PRNGKey(8))
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 32, cfg.hidden_size))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(op.forward(
+        p, [x], training=True)[0] ** 2), (0, 1)))(params, x)
+    widths = {cfg.hidden_size, cfg.moe_intermediate_size}
+    assert 4 not in widths and 256 not in widths
+
+    def shapes(jaxpr):
+        for eqn in jaxpr.eqns:
+            for var in list(eqn.invars) + list(eqn.outvars):
+                yield eqn.primitive.name, getattr(var.aval, "shape", ())
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from shapes(sub)
+
+    seen = set(shapes(jaxpr.jaxpr))
+    assert any(shape == (256,) for _, shape in seen)
+    wide = sorted({(name, shape) for name, shape in seen
+                   if shape[:1] == (256,) and set(shape[1:]) & widths})
+    assert wide == []
 
 
 def test_padding_rows_are_masked_whatever_they_hold(monkeypatch):
@@ -399,12 +527,16 @@ def test_every_scope_of_the_compiled_step_is_attributed():
                   "ff.lm.moe.shared", "ff.lm.mtp", "ff.lm.head",
                   "ff.step.dense_update"):
         assert scope in found or scope + ".bwd" in found, scope
-    for scope in ("ff.lm.mla.proj", "ff.lm.moe.experts", "ff.lm.ffn"):
+    for scope in ("ff.lm.mla.proj", "ff.lm.moe.dispatch", "ff.lm.ffn"):
         assert scope + ".remat" in found, scope
         assert scope + ".bwd" in found, scope
-    # the core's output and log-sum-exp are kept: its forward runs once
-    assert "ff.lm.mla.core.bwd" in found
-    assert "ff.lm.mla.core.remat" not in found
+    # the core's output and log-sum-exp are kept: its forward runs once;
+    # the held experts' backward computes its own slabs again from the
+    # tokens, so the recomputation before it has none of them to compute
+    for scope in ("ff.lm.mla.core", "ff.lm.moe.experts",
+                  "ff.lm.moe.combine"):
+        assert scope + ".bwd" in found, scope
+        assert scope + ".remat" not in found, scope
     for phase in found - {profiling.UNATTRIBUTED}:
         assert phases.group_of(phase, family.PHASE_GROUPS), phase
 
