@@ -113,8 +113,8 @@ def assert_auto_picked(model, expect_pack: int):
 
 def row_set_expected(model, n_rows: int) -> bool:
     """Whether ``_cache_writeback`` should take the row-set kernel for an
-    epilogue of ``n_rows`` plan rows — the gate of model.py restated from
-    its inputs, so the lowered text can be held against it."""
+    epilogue of ``n_rows`` plan rows — the gate of row_cache.py restated
+    from its inputs, so the lowered text can be held against it."""
     import jax
 
     from dlrm_flexflow_tpu.ops.kernel_costs import row_set_wins

@@ -52,6 +52,9 @@ LAYERS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("storage", ("storage",)),
     ("parallel", ("parallel",)),
     ("sim", ("sim", "profiling")),
+    # the epoch row-cache: ops' slot plans and kernels below it, the
+    # model (which hands it the step as a function) above
+    ("row-cache", ("row_cache",)),
     ("model", ("model",)),
     ("checkpoint", ("checkpoint",)),
     ("subsystems", ("resilience", "serving")),

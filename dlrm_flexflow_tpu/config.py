@@ -78,13 +78,14 @@ class FFConfig:
     # optimum 8 with chunk 256, PERF.md).  0 disables.
     epoch_cache_inner: int = 8
     # In-graph cache-ladder shape ("auto" | "off" | explicit sizes like
-    # "256,32,8").  "auto" runs the chunk as an in-graph scan level (so
-    # a multi-epoch run fuses into one dispatch with one prologue),
-    # inserts a geometric mid level between chunk and inner when
-    # chunk/inner > 8, and ends at epoch_cache_inner — each level pulls
-    # its block's rows from the parent cache so no rebuild sweeps more
-    # than ~8 blocks' rows (PERF.md round 3).  "off" restores flat
-    # host-side chunking with no in-graph levels.
+    # "256,32,8", outermost first).  "auto" is the rule of
+    # row_cache.py::CachePolicy.ladder_sizes: [8*inner, inner], the leaf
+    # level alone when every cached table takes the region layout, a
+    # chunk-sized level when inner <= 1 — each level pulls its block's
+    # rows from the parent cache, and a level that engages over the
+    # whole epoch makes a multi-epoch run one dispatch with one
+    # prologue.  "off" restores flat host-side chunking with no
+    # in-graph levels.
     epoch_cache_levels: str = "auto"
     # Top-level cache transport unit ("auto"|"on"|"off").  "on"/"auto"
     # fetch and write back the epoch cache in 128-lane VIEW rows
@@ -99,21 +100,6 @@ class FFConfig:
     # active); "on" forces it on any backend (tests); "off" restores
     # logical-row transport.
     epoch_cache_view: str = "auto"
-    # First-touch-SEGMENTED epoch slot assignment ("auto"|"on"|"off"):
-    # with an engaged ladder top level and packed table storage, each
-    # distinct row's epoch-cache slot lives in the segment of the first
-    # scan block that touches it, so the top level's block fetch and
-    # writeback stream their own-segment rows (dynamic_slice/
-    # dynamic_update_slice) instead of random-gathering them, plus a
-    # B=m/4-prefix scatter for reused rows; blocks whose reuse exceeds
-    # the budget fall back to the full gather/scatter per block
-    # (lax.cond — heavy-reuse ids land there).  Value-identical at the
-    # table level (tests).  "auto" == "off": measured NEGATIVE on the
-    # headline (PERF.md round 4 — when epoch draws ~= table rows, later
-    # blocks reuse ~60% of their rows from earlier blocks, so the
-    # fallback dominates while paying the branch overhead); "on" opts
-    # in for genuinely low-reuse regimes (epoch draws << rows).
-    epoch_cache_segmented: str = "auto"
     # BLOCK-MAJOR epoch-cache regions ("auto"|"on"|"off"): lay the epoch
     # cache out as one occurrence-sized region per ladder-top block and
     # STREAM each block's writeback into its own region
@@ -127,16 +113,17 @@ class FFConfig:
     # region holds its FOREIGN rows first — those another block holds
     # too, the only positions whose newest copy is not their own — and
     # the fetch is one dynamic_slice of the block's own region plus a
-    # gather of just those rows (region_slots, model.py _region_fetch;
+    # gather of just those rows (region_slots, row_cache.py _region_fetch;
     # on the v5e: 133.9 -> 41 us a block on uniform ids, 57 on
     # Zipf 1.05).  Bit-exact with shared-slot mode (tests).
     # With a two-level ladder the L1 cache is itself L0-region-major
     # (grouped circular plan), so the L0 writebacks stream too; its
     # fetches gather every position.
     # Engages for single-device packed-storage ops when the ladder top
-    # level divides the epoch and segmented slots are off.  "auto" = on
-    # (round-5 headline A/B: busy 243.5 -> 219.0 ms); "off" restores
-    # shared-slot mode.
+    # level divides the epoch (row_cache.py: region_engages,
+    # region_layout).  "auto" = on from 2^18 id occurrences an epoch
+    # (round-5 headline A/B: busy 243.5 -> 219.0 ms); "on" at any size;
+    # "off" restores shared-slot mode.
     epoch_cache_regions: str = "auto"
     # Physical embedding-table storage ("auto"|"on"|"off").  "auto"/"on"
     # store d<128 tables lane-PACKED as (R/pack, 128) arrays end-to-end

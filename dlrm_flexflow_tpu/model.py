@@ -31,7 +31,6 @@ task-per-op translation.
 from __future__ import annotations
 
 import contextlib
-import os
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -54,6 +53,7 @@ from .parallel.mesh import (DATA_AXIS, MODEL_AXIS, constrain, make_mesh,
                             param_pspec, pspec_for_config, sharding)
 from .parallel.parallel_config import Strategy
 from .profiling import note_program
+from .row_cache import CacheOp, CachePolicy, RowCache
 from .telemetry import active_log, sample_memory
 from .telemetry import fleet as _fleet
 from .telemetry import metrics as _tmetrics
@@ -61,80 +61,6 @@ from .telemetry import rowfreq as _rowfreq
 from .telemetry.trace import (NULL_SPAN, current_span, pop_span, push_span,
                               start_span)
 from .tensor import Tensor, as_dtype
-
-
-def _validated_epoch_cache_view(config) -> str:
-    """epoch_cache_view, validated — one shared check so compile (always)
-    and cache_prologue (re-reads config, catches post-compile mutation)
-    can't drift apart."""
-    view_mode = getattr(config, "epoch_cache_view", "auto")
-    if view_mode not in ("auto", "on", "off"):
-        raise ValueError(
-            f"epoch_cache_view must be 'auto'|'on'|'off', got {view_mode!r}")
-    return view_mode
-
-
-# rows a trip of _region_fetch's loop gathers: a measurement of XLA:TPU's
-# gather emitter at 512 B rows and libtpu 0.0.34, not a law;
-# scripts/ab_fetch.py times this very function at other sizes
-REGION_FETCH_CHUNK = 768
-
-
-def _region_fetch(parent, src, base, foreign, chunk=None):
-    """Leaf-block fetch of the SINGLE-LEVEL region layout: one
-    ``dynamic_slice`` streams the block's own region [base, base+m) of
-    the epoch cache, then only the FOREIGN positions — ``region_slots``
-    puts them first, ``foreign`` of them — are gathered from their
-    newest copy (``src``) and laid over it, chunk by chunk, in a loop
-    whose trip count follows the count: the cost follows the data, with
-    no budget and no branch.  Positions past ``foreign`` hold
-    ``src[p] == p`` (the slice brought exactly that row) or a sentinel
-    nothing addresses, so a last chunk that runs past the count rewrites
-    what is there.  Value-identical to the full gather at every live
-    position.
-
-    Measured on the v5e at m = 16,384 rows of 512 B (my chip runs, PR
-    29; PERF.md §6): the full gather 134 us a block; here the slice 12
-    us, and for the uniform cell's 3,719 foreign rows the gather 18.8
-    us + laying 8.9 us (1.8 us a trip).  ``scripts/ab_fetch.py`` runs
-    THIS function at other chunks (us a block at 3,800 foreign rows,
-    the fetch with 29.6 us of stand-in work): one piece 182.5; 128:
-    121.2, 256: 104.6, 384: 114.5, 512: 98.8, 640: 101.8, 768: 90.1,
-    896: 100.5, 1,024: 107.5, 1,280: 87.7, 1,536: 93.5, 1,792: 96.6,
-    2,048: 105.2, 3,072: 125.2.  768 is the smallest chunk within 3 us
-    of the best; 1,280 read 2.3-2.4 us a block (0.3 us a step) better
-    at 3,800 and 5,600 rows and 12.8 better when all 16,384 are foreign
-    (174.2 against 187.0; one piece 184.4), and has not been run in
-    the benchmark (PERF.md §7).  Nobody has explained the emitter's
-    dependence on the piece size.  The other exact form, a static 3m/8
-    prefix behind a ``lax.cond`` with the full gather as its other
-    branch, lost by 31.6 us a step (PR 27's builder's chip runs): the
-    conditional took the leaf cache out of fast memory
-    (``ff.step.gather`` 3.3 -> 14.8 us a step) and added a copy of the
-    block per fetch, as round 4's ``_seg_fetch`` had.
-
-    ``chunk`` defaults to ``min(REGION_FETCH_CHUNK, max(m // 16, 1))``;
-    only ``scripts/ab_fetch.py`` and the tests pass another.  The ``m // 16`` arm
-    keeps the trip count following ``foreign`` where a block has fewer
-    than 12,288 positions (the tests' tiny epochs, a small batch); the
-    benchmark's traffic never meets it."""
-    m, d = src.shape[0], parent.shape[-1]
-    if chunk is None:
-        chunk = min(REGION_FETCH_CHUNK, max(m // 16, 1))
-    with jax.named_scope("ff.ladder.fetch.own"):
-        own = jax.lax.dynamic_slice(parent, (base, 0), (m, d))
-
-    def lay(i, blk):
-        # both the index slice and the placement clamp a last chunk
-        # that would run past m to [m - chunk, m)
-        at = jnp.minimum(i * chunk, m - chunk)
-        idx = jax.lax.dynamic_slice(src, (at,), (chunk,))
-        rows = jnp.take(parent, idx, axis=0, mode="clip")
-        return jax.lax.dynamic_update_slice(blk, rows, (at, 0))
-
-    with jax.named_scope("ff.ladder.fetch.foreign"):
-        return jax.lax.fori_loop(
-            0, (foreign + chunk - 1) // chunk, lay, own)
 
 
 def _fold_steps(name: str, per_step, counter_rank=None):
@@ -197,6 +123,7 @@ class FFModel:
         self._pending_lr: Optional[float] = None
         self._fit_state: Optional[TrainState] = None
         self._epoch_cache_active = False
+        self._cache_policy: Optional[CachePolicy] = None
         # further loss terms: (tensor uid, labels' input name, weight)
         self._aux_losses: List[Tuple[int, str, float]] = []
 
@@ -879,47 +806,6 @@ class FFModel:
             raise ValueError(
                 f"activation_dtype must be 'float32'|'bfloat16', "
                 f"got {act_dtype!r}")
-        # validate epoch_cache_view unconditionally here (like the two
-        # checks above) — cache_prologue only runs when the epoch
-        # row-cache is active, which would let a typo pass silently
-        _validated_epoch_cache_view(self.config)
-        _seg_mode = getattr(self.config, "epoch_cache_segmented", "auto")
-        if _seg_mode not in ("auto", "on", "off"):
-            raise ValueError(
-                f"epoch_cache_segmented must be 'auto'|'on'|'off', "
-                f"got {_seg_mode!r}")
-        # auto == OFF: measured NEGATIVE on the headline (307 vs 243.5
-        # ms busy, PERF.md round 4) — at uniform epoch-draws ~= table
-        # rows, later blocks reuse ~60% of their rows from ANY earlier
-        # block, so most blocks take the fallback branch while paying
-        # the cond's broken carry aliasing + the segmented prologue
-        # sorts.  "on" remains for genuinely low-reuse regimes
-        # (epoch draws << rows), pinned bit-exact by
-        # TestSegmentedEpochSlots.
-        seg_enabled = _seg_mode == "on"
-        # epoch_cache_regions "auto" resolution (see FFConfig): ON —
-        # round-5 headline A/B measured busy 243.5 -> 219.0 ms
-        # (two-level, scatter-free plans), bit-exact incl. lazy Adam
-        # and Zipf ids
-        region_auto_on = True
-        # When EVERY cache op takes the region path, auto's ladder
-        # collapses to the single leaf level ([inner]): under regions
-        # the mid level saves no HBM gather issues while adding its own
-        # S(1) rebuild gather + dus layer — measured busy 185.0 ->
-        # 171.6 ms at the headline, bench-recorded 171.5 (round 5).
-        # Only this single-level layout has the streamed fetch: a
-        # position's fetch index differs from the position itself only
-        # where ANOTHER block holds the row too (23% of a block's
-        # positions on uniform ids at the benchmark's shape, 33% on
-        # Zipf 1.05), so each region holds those foreign rows first
-        # (ops/slotting.py::region_slots) and _region_fetch streams
-        # the region and gathers only them.
-        # cache_prologue decides the flag once per trace and
-        # THREADS IT EXPLICITLY through every ladder_sizes consumer
-        # (advisor r5: the previous mutable-closure read relied on trace
-        # ordering); mixed eligibility keeps the two-level shape so
-        # non-region ops never rebuild straight from the table every 8
-        # steps.
         if not hasattr(self, "_orig_out_dtypes"):
             self._orig_out_dtypes = {}
         for op in self.layers:
@@ -1021,13 +907,11 @@ class FFModel:
         # row/table dim (see _storage_ok_under_mesh); only the manual
         # exchange paths (excluded via _device_table_op) and
         # feature-sharded single Embeddings keep logical storage.
-        packed_mode = getattr(self.config, "packed_tables", "auto")
-        if packed_mode not in ("auto", "on", "off"):
-            raise ValueError(
-                f"packed_tables must be 'auto'|'on'|'off', "
-                f"got {packed_mode!r}")
-        storage_on = (packed_mode == "on"
-                      or (packed_mode == "auto" and backend == "tpu"))
+        # The cache's options and this one are resolved and validated
+        # here, once: nothing under a trace reads the config.
+        policy = CachePolicy.resolve(self.config, backend, mesh_)
+        self._cache_policy = policy
+        storage_on = policy.packed_storage
 
         def _storage_ok_under_mesh(op):
             """Packed storage under a mesh (round 4: replicated/DP
@@ -1108,9 +992,7 @@ class FFModel:
                         and op.inputs[0].uid in input_name_of
                         and not (sparse_mode == "auto" and backend == "tpu"
                                  and self.mesh is None
-                                 and not op.sparse_update_ok(
-                                     getattr(self.config, "epoch_row_cache",
-                                             "auto") != "off"))):
+                                 and not op.sparse_update_ok(policy.cache))):
                     sparse_emb.append(op)
         self._sparse_emb_ops = [op.name for op in sparse_emb]
         emb_names = {op.name for op in sparse_emb}
@@ -1146,7 +1028,7 @@ class FFModel:
         def _slot_space(st, sn, name):
             """The optimizer-slot table row-addressed like the param
             (cache mode swaps it for a slot cache, exactly as the
-            param's table — see cache_prologue)."""
+            param's table — see row_cache.py)."""
             return st.opt_state[sn][name]["embedding"]
 
         def lazy_update(state, op, tb, slots, inputs, w_rows, g_rows):
@@ -1361,863 +1243,33 @@ class FFModel:
                                     bn_state=bn_state or {})
             return _final(values)
 
-        # Epoch row-cache: big-table gather/scatter lowers to a full-table
-        # SWEEP per step on TPU (cost scales with table bytes, PERF.md).
-        # But train_epoch knows the WHOLE epoch's ids up front, so the
-        # touched rows can be pulled into a small cache with ONE sweep,
-        # the scan then gathers/scatters the cache by slot (exact: unique
-        # slots keep cross-step updates coherent), and one scatter-set
-        # writes the final rows back.  Per-step table cost becomes
-        # O(cache bytes) instead of O(table bytes).
-        cache_mode = getattr(self.config, "epoch_row_cache", "auto")
-        if cache_mode not in ("auto", "on", "off"):
-            raise ValueError(
-                f"epoch_row_cache must be 'auto'|'on'|'off', "
-                f"got {cache_mode!r}")
-        # "auto": tpu only (the sweep it amortizes is a TPU lowering;
-        # cpu/gpu scatter is already per-row).  "on": force anywhere
-        # (tests exercise the cached path on the CPU suite).  "off": never.
-        # Mesh-compatible: the cache is built from the full epoch's ids
-        # inside the jitted epoch program, so under a mesh XLA SPMD owns
-        # its placement (the two full-table sweeps it amortizes are then
-        # per-shard sweeps of the table's local rows).
-        epoch_cache = (bool(sparse_emb)
-                       and (cache_mode == "on"
-                            or (cache_mode == "auto" and backend == "tpu")))
-        self._epoch_cache_active = epoch_cache
+        # Epoch row-cache (row_cache.py): only a model with a row-sparse
+        # table has one; without it the epoch programs are a plain scan
+        # of the step and never enter that module.
+        self._epoch_cache_active = bool(sparse_emb) and policy.cache
+        cache = None
+        if self._epoch_cache_active:
+            from .ops.pallas_scatter import lane_pack
+            cache = RowCache(
+                [CacheOp(op.name, id_name[op.name], op.flat_ids,
+                         lane_pack(op.param_specs()[0].shape[-1]),
+                         op.storage_pack) for op in sparse_emb],
+                lazy_slots, mesh_, backend, policy)
 
-        # ---- epoch row-cache pieces (shared by the single-epoch and the
-        # multi-epoch scanned programs) -----------------------------------
-        def _cache_fetch(parent, rowof, pack=1):
-            """THE cache fill all levels share: rows of the flattened
-            parent at ``rowof``; sentinel holes clip to a garbage row
-            that nothing addresses.  Accepts raw (T, R, d) tables and
-            already-flat (R, d) caches alike (the reshape is a no-op
-            for the latter).  ``pack > 1``: rowof addresses 128-lane
-            VIEW rows of the (R/pack, d*pack) view — the top-level form
-            that keeps the big-table gather in the same layout as every
-            other table op (the logical-(R, d<128) form made XLA pick a
-            transposed table layout and pay full-table layout copies +
-            loop transposes around the prologue/epilogue, ~180 ms per
-            fused run at the bench shape — measured via
-            scripts/profile_headline.py, round 3)."""
-            fl = parent.reshape(-1, parent.shape[-1])
-            if pack > 1:
-                view = fl.reshape(fl.shape[0] // pack,
-                                  fl.shape[1] * pack)
-                return jnp.take(view, rowof, axis=0,
-                                mode="clip").reshape(-1, fl.shape[1])
-            return jnp.take(fl, rowof, axis=0, mode="clip")
-
-        def build_cache(flat, ids, pack, view_ok, storage=1, seg_blocks=1):
-            """Shared-slot cache of the rows ``ids`` touches in the
-            (R, d) source ``flat``: (cache, slots, rowof, pack_used) or
-            None when the cache would not be smaller than the source.
-            Slot assignment is sort-position based (ops/slotting.py — no
-            dense-rank inverse, whose scalar scatters dominated the
-            prologue); ``rowof`` maps slot -> row with sentinel holes,
-            which the fill (mode="clip") and the writeback
-            (mode="drop") both tolerate.  Works on traced values; all
-            shapes are static (the cache is sized by the occurrence
-            count, as before — the distinct count is data-dependent).
-
-            ``view_ok`` + pack > 1 selects the VIEW-ROW form: slots are
-            assigned per 128-lane view row (pack logical rows each), so
-            the table-side fetch and writeback move whole view rows —
-            the layout every other table op prefers.  Exact: a touched
-            view row's untouched halves are fetched with it, never
-            addressed by any slot (slots only point at run-first view
-            slots, offset by each id's half), and written back with
-            their original bytes.  Costs up to pack x the cache bytes
-            (view rows rarely coalesce under random ids) in exchange
-            for killing the transposed-layout pathology above."""
-            size = int(np.prod(ids.shape))
-            sentinel = flat.shape[0]  # OOB -> dropped at writeback
-            from .ops.slotting import slot_rows
-            if storage > 1:
-                # packed STORAGE: flat already is the (Rv, 128) view and
-                # rowof addresses its view rows directly — the epoch
-                # cache is packed too, so every later fetch/writeback is
-                # a plain whole-row take/set (wpack=1).  With an engaged
-                # ladder top level, slots are FIRST-TOUCH SEGMENTED
-                # (ops/slotting.py) so the top level's block fetch and
-                # writeback stream their own-segment rows instead of
-                # random-gathering them (PERF.md round 4).
-                if size >= flat.shape[0]:
-                    return None
-                seg = seg_blocks > 1 and size % seg_blocks == 0
-                if seg:
-                    from .ops.slotting import slot_rows_segmented
-                    rowof_v, vslots = slot_rows_segmented(
-                        ids // storage, sentinel, seg_blocks)
-                else:
-                    rowof_v, vslots = slot_rows(ids // storage, sentinel)
-                slots = vslots * storage + (ids % storage).astype(
-                    jnp.int32)
-                # a SEGMENTED rowof is NOT non-decreasing (segments
-                # interleave rows and sentinels) — the epilogue's
-                # scatter must not carry the sorted hint (review r4)
-                return (_cache_fetch(flat, rowof_v), slots, rowof_v, 1,
-                        not seg)
-            if (view_ok and pack > 1 and flat.shape[0] % pack == 0
-                    and size < flat.shape[0] // pack):
-                vrows = flat.shape[0] // pack
-                rowof_v, vslots = slot_rows(ids // pack, vrows)
-                slots = vslots * pack + (ids % pack).astype(jnp.int32)
-                return (_cache_fetch(flat, rowof_v, pack), slots,
-                        rowof_v, pack, True)
-            # pad to the lane-pack multiple so the packed view
-            # applies to the cache too
-            m = -(-size // pack) * pack
-            if m >= flat.shape[0]:
-                return None
-            rowof, slots = slot_rows(ids, sentinel)
-            if m > size:
-                rowof = jnp.concatenate(
-                    [rowof, jnp.full((m - size,), sentinel, rowof.dtype)])
-            return _cache_fetch(flat, rowof), slots, rowof, 1, True
-
-        from .ops.pallas_scatter import lane_pack
-        op_pack = {op.name: lane_pack(op.param_specs()[0].shape[-1])
-                   for op in sparse_emb}
-        # storage form per op: packed-storage ops size and address their
-        # caches in VIEW-row units at every ladder level (see build_cache)
-        op_storage = {op.name: op.storage_pack for op in sparse_emb}
-
-        def _cache_writeback(parent, rowof, cache_final, pack=1,
-                             sorted_rowof=True):
-            """THE cache writeback all levels share: live rows set once,
-            sentinel holes dropped — param and optimizer-slot tables
-            must stay bit-identical in this formulation for the
-            hierarchy's exactness claim.  ``pack > 1``: rowof addresses
-            view rows (see _cache_fetch).  ``rowof`` is non-decreasing
-            by construction for every DENSE-RANK slot plan
-            (ops/slotting.py compacts distinct rows to the front,
-            sentinel pads at the end), so the scatter carries
-            indices_are_sorted — measured 3.8x on the mid-level
-            writeback shape (PERF.md round 3 continuation).  Callers
-            whose rowof is NOT sorted (the first-touch-SEGMENTED epoch
-            plan interleaves segments and sentinels) MUST pass
-            ``sorted_rowof=False`` — lying to the scatter emitter is
-            implementation-defined on TPU (review r4)."""
-            fl = parent.reshape(-1, parent.shape[-1])
-            if pack > 1:
-                target = fl.reshape(fl.shape[0] // pack,
-                                    fl.shape[1] * pack)
-                vals = cache_final.reshape(-1, fl.shape[1] * pack)
-            else:
-                target, vals = fl, cache_final
-            # low-density writebacks take the per-row-DMA SET kernel:
-            # the scatter emitter RMW-sweeps the PARENT, so setting a
-            # few thousand rows of a GB-scale table costs the sweep
-            # (6.1 ms measured at the dlrm_hybrid epilogue) where row
-            # DMAs cost ~64 ns/row.  The static cost-model gate keeps
-            # the emitter everywhere else (ladder levels, dense
-            # epilogues); kernels don't partition under SPMD, so mesh
-            # compiles always use the emitter.  rowof rows are DISTINCT in
-            # every caller (dense-rank/region plans), which the kernel
-            # requires.  FF_ROW_SET_IMPL=emitter|kernel overrides.
-            from .ops.pallas_scatter import _row_set_pallas, row_set_wins
-            impl = os.environ.get("FF_ROW_SET_IMPL", "auto")
-            # eligibility is MANDATORY (the override only bypasses the
-            # cost model, review r5): no mesh (SPMD cannot partition a
-            # pallas_call), TPU backend, and Mosaic-lane-compatible
-            # rows (the kernel DMAs (1, d) row slices)
-            eligible = (mesh_ is None and backend == "tpu"
-                        and target.shape[1] % 128 == 0)
-            # rowof.shape[0] is the PADDED plan length (sentinel holes
-            # included: lane-pack pad, segmented interleave) — the live
-            # distinct-row count is data-dependent and not static here,
-            # so the gate sees an upper bound on the kernel's row DMAs.
-            # The slack only overstates kernel cost (sentinel rows issue
-            # no DMA at runtime), so near the threshold the dispatch
-            # errs toward the proven emitter path — conservative by
-            # construction (advisor r5; see row_set_wins).
-            use_kernel = eligible and impl != "emitter" and (
-                impl == "kernel"
-                or row_set_wins(target.shape[0], target.shape[1],
-                                int(rowof.shape[0]),
-                                target.dtype.itemsize))
-            if use_kernel:
-                out = _row_set_pallas(target, rowof, vals)
-            else:
-                out = target.at[rowof].set(
-                    vals, mode="drop", indices_are_sorted=sorted_rowof)
-            return out.reshape(parent.shape)
-
-        def _seg_fetch(parent, rowof, k, P, m):
-            """Top-level block fetch against FIRST-TOUCH-SEGMENTED epoch
-            slots (ops/slotting.py): the block's OWN rows live
-            contiguously at epoch slots [k*m, k*m+n_new) and land at
-            cache positions [P, P+n_new) (P = reused count, sorted
-            order puts reused slots first) — one streaming
-            dynamic_slice + roll, plus a static B-prefix gather for the
-            reused rows.  Falls back to the full gather when the block
-            reuses more than the B budget (P > B) — e.g. Zipf-skewed
-            ids, where most rows repeat earlier blocks.  Value-identical
-            to the full gather at every LIVE position; sentinel
-            positions may hold different garbage (nothing addresses
-            them — pinned by the equivalence suites at table level)."""
-            d = parent.shape[-1]
-            B = max(m // 4, 1)
-
-            def contig(_):
-                seg = jax.lax.dynamic_slice(parent, (k * m, 0), (m, d))
-                rolled = jnp.roll(seg, P, axis=0)
-                front = jnp.take(parent, rowof[:B], axis=0, mode="clip")
-                return jax.lax.dynamic_update_slice(rolled, front, (0, 0))
-
-            def full(_):
-                return jnp.take(parent, rowof, axis=0, mode="clip")
-
-            return jax.lax.cond(P <= B, contig, full, None)
-
-        def _seg_writeback(parent, rowof, child, k, P, m):
-            """Writeback twin of ``_seg_fetch``: stream the whole block
-            cache into the op's own segment (padding rows land in
-            segment padding slots, which no slot addresses and the
-            epilogue drops), then scatter-set the static B-prefix (the
-            reused rows; own-slot entries in the prefix rewrite the
-            value the slice just wrote — idempotent)."""
-            fl = parent.reshape(-1, parent.shape[-1])
-            B = max(m // 4, 1)
-
-            def contig(p):
-                segw = jnp.roll(child, -P, axis=0)
-                p = jax.lax.dynamic_update_slice(p, segw, (k * m, 0))
-                return p.at[rowof[:B]].set(child[:B], mode="drop",
-                                           indices_are_sorted=True)
-
-            def full(p):
-                return p.at[rowof].set(child, mode="drop",
-                                       indices_are_sorted=True)
-
-            return jax.lax.cond(P <= B, contig, full, fl).reshape(
-                parent.shape)
-
-        def _swap_opt_entry(opt_state, sn, name, arr):
-            """Rebuild opt_state with slot tree ``sn``'s entry for
-            ``name`` replaced by ``arr`` — the one dict-rebuild shared
-            by every slot-cache swap and writeback site."""
-            opt_state = dict(opt_state)
-            tree = dict(opt_state[sn])
-            tree[name] = {"embedding": arr}
-            opt_state[sn] = tree
-            return opt_state
-
-        def _swap_slot_caches(opt_state, name, fn):
-            """Rebuild opt_state with each lazy slot table of ``name``
-            replaced by fn(flat_slot_table)."""
-            for sn in lazy_slots:
-                old = opt_state[sn][name]["embedding"]
-                opt_state = _swap_opt_entry(
-                    opt_state, sn, name,
-                    fn(old.reshape(-1, old.shape[-1])))
-            return opt_state
-
-        def cache_prologue(state, inputs):
-            """Per eligible op, map the epoch's ids to unique cache slots
-            and pull the touched rows in with one table sweep (plus, in
-            lazy mode, the optimizer slot tables — same rowof, same
-            slots).  Returns (state-with-caches, slots, writebacks,
-            originals, region_src, region_single); ``writebacks`` entries
-            are (name, tb_shape, rowof, wpack, sorted_ok, final_src) with
-            final_src None outside region mode.  ``region_single`` (every
-            cache op engaged the region layout — the ladder-collapse
-            flag) is decided HERE, once per trace, and threaded
-            explicitly into every ``ladder_sizes`` consumer."""
-            from .ops.pallas_scatter import use_packed_view
-            view_mode = _validated_epoch_cache_view(self.config)
-            # "on" still requires no mesh (under SPMD the view fights
-            # the sharded layout, like every packed-view path)
-            if view_mode == "on":
-                view_ok = mesh_ is None
-            elif view_mode == "auto":
-                view_ok = use_packed_view(mesh_)
-            else:
-                view_ok = False
-            params = dict(state.params)
-            opt_state = state.opt_state
-            slots_ep, writebacks, originals = {}, [], {}
-            region_src = {}
-            cache_ops = sparse_emb if epoch_cache else ()
-            # one engagement decision per op, shared by the ladder-shape
-            # choice below AND _region_layout (review r5: the gate must
-            # not be evaluated twice or the two could diverge);
-            # parent_rows is pure shape math — no traced reshape
-            region_ok = {
-                op.name: _region_engages(
-                    op, inputs[id_name[op.name]].astype(jnp.int32),
-                    int(np.prod(params[op.name]["embedding"].shape[:-1])))
-                for op in cache_ops}
-            region_single = bool(region_ok) and all(region_ok.values())
-            for op in cache_ops:
-                ids = inputs[id_name[op.name]].astype(jnp.int32)
-                tb = params[op.name]["embedding"]
-                flat = tb.reshape(-1, tb.shape[-1])
-                nb = ids.shape[0]
-                reg = (_region_layout(op, flat, ids, nb, region_single)
-                       if region_ok[op.name] else None)
-                if reg is not None:
-                    cache, slots, rinfo, final_rowof, final_src, \
-                        rowof_all = reg
-                    originals[op.name] = tb
-                    params[op.name] = {"embedding": cache}
-                    slots_ep[op.name] = slots
-                    region_src[op.name] = rinfo
-                    writebacks.append((op.name, tb.shape, final_rowof,
-                                       1, True, final_src))
-                    if lazy_slots:
-                        for sn in lazy_slots:
-                            originals[(sn, op.name)] = (
-                                opt_state[sn][op.name]["embedding"])
-                        opt_state = _swap_slot_caches(
-                            opt_state, op.name,
-                            lambda fl, r=rowof_all: _cache_fetch(fl, r))
-                    continue
-                built = build_cache(flat, op.flat_ids(ids),
-                                    op_pack[op.name], view_ok,
-                                    storage=op.storage_pack,
-                                    seg_blocks=_seg_blocks_for(
-                                        ids.shape[0], region_single))
-                if built is None:
-                    # cache would be as big as the table — no win; keep
-                    # this op on the direct per-step path
-                    continue
-                cache, slots, rowof, wpack, sorted_ok = built
-                originals[op.name] = tb
-                params[op.name] = {"embedding": cache}
-                slots_ep[op.name] = slots
-                writebacks.append((op.name, tb.shape, rowof, wpack,
-                                   sorted_ok, None))
-                if lazy_slots:
-                    for sn in lazy_slots:
-                        originals[(sn, op.name)] = (
-                            opt_state[sn][op.name]["embedding"])
-                    opt_state = _swap_slot_caches(
-                        opt_state, op.name,
-                        lambda fl, r=rowof, p=wpack: _cache_fetch(
-                            fl, r, p))
-            state = TrainState(params, opt_state, state.bn_state,
-                               state.rng, state.step)
-            return (state, slots_ep, writebacks, originals, region_src,
-                    region_single)
-
-        def _region_engages(op, ids, parent_rows):
-            """Size/flag gate of the region layout — everything that
-            does NOT depend on the ladder shape, so cache_prologue can
-            decide the auto ladder (single leaf level when every cache
-            op engages) before any ladder_sizes consumer runs."""
-            mode = getattr(self.config, "epoch_cache_regions", "off")
-            if mode not in ("auto", "on", "off"):
-                raise ValueError(
-                    f"epoch_cache_regions must be 'auto'|'on'|'off', "
-                    f"got {mode!r}")
-            if mode == "off" or (mode == "auto" and not region_auto_on):
-                return False
-            sp = op.storage_pack
-            if sp <= 1 or seg_enabled or mesh_ is not None:
-                # packed-storage ops only; first-touch segmentation owns
-                # the top level whenever it is enabled (checking the
-                # flag itself — not _seg_blocks_for — keeps this gate
-                # free of ladder_sizes, whose region-collapse branch
-                # reads the flag this gate computes; review r5); under
-                # a mesh the region dus/gather would fight the
-                # SPMD-sharded cache layout (untested) — keep shared
-                # slots there
-                return False
-            n_occ = int(np.prod(op.flat_ids(ids).shape))
-            # the region cache holds n_occ PACKED view rows — compare
-            # against the table's packed rows (build_cache's guard),
-            # not the logical count (review r5)
-            if n_occ >= parent_rows:  # cache not smaller: no win
-                return False
-            if mode == "auto" and n_occ < (1 << 18):
-                # the region plan's fixed costs (per-block sorts, the
-                # last-copy epilogue gather) beat the saved scatters
-                # only on big epochs: kaggle-shape A/B measured busy
-                # 4.275 -> 5.252 ms with regions at 26k occurrences,
-                # while the 1M-occurrence headline gains 10 ms
-                # (PERF.md round 5); "on" forces engagement for tests
-                return False
-            return True
-
-        def _region_layout(op, flat, ids, nb, region_single):
-            """Block-major region layout for the epoch cache
-            (FFConfig.epoch_cache_regions; ops/slotting.py::region_plan
-            for the design), or None when the ladder shape does not
-            support it (the size/flag gate is the caller's region_ok —
-            computed ONCE per op in cache_prologue, which also decides
-            ``region_single``).  Returns
-            (cache, slots, src, final_rowof, final_src, rowof_all)."""
-            sp = op.storage_pack
-            sizes = ladder_sizes(nb, region_single)
-            top = sizes[0] if sizes else 0
-            if not (0 < top < nb and nb % top == 0):
-                return None
-            nblk = nb // top
-            if nblk <= 1:
-                return None
-            fv = op.flat_ids(ids)
-            n_occ = int(np.prod(fv.shape))
-            from .ops.slotting import (grouped_region_plan, region_plan,
-                                       region_plan_l0, region_slots,
-                                       slot_rows)
-            sentinel = flat.shape[0]
-            inner = sizes[1] if len(sizes) >= 2 else 0
-            if 0 < inner < top and top % inner == 0:
-                # TWO-LEVEL regions: the L1 cache itself is L0-region-
-                # major, so the L0 writebacks stream too (dus into the
-                # scoped L1 buffer); the L1 fetch uses the GROUPED
-                # circular plan (same-L1-block siblings are not valid
-                # sources — they are written by the same dus)
-                nl0 = top // inner
-                v0 = fv.reshape(nblk * nl0, -1)
-                m0 = v0.shape[1]
-                m1 = nl0 * m0
-                rowof_l0, vs_l0 = jax.vmap(
-                    lambda b: slot_rows(b // sp, sentinel))(v0)
-                base0 = (jnp.arange(nblk * nl0, dtype=jnp.int32)
-                         * m0)[:, None]
-                slots = ((base0 + vs_l0) * sp
-                         + (v0 % sp).astype(jnp.int32)).reshape(fv.shape)
-                rowof_all = rowof_l0.reshape(-1)
-                cache = _cache_fetch(flat, rowof_all)
-                src_l1, final_rowof, final_src = grouped_region_plan(
-                    rowof_l0, nblk, sentinel)
-                src_l0 = jax.vmap(
-                    lambda rb: region_plan_l0(rb, sentinel))(
-                        rowof_l0.reshape(nblk, nl0, m0))
-                info = {
-                    "src": src_l1,
-                    "base": jnp.arange(nblk, dtype=jnp.int32) * m1,
-                    "inner": {
-                        "src": src_l0,
-                        "base": jnp.broadcast_to(
-                            jnp.arange(nl0, dtype=jnp.int32) * m0,
-                            (nblk, nl0)),
-                    },
-                }
-                return cache, slots, info, final_rowof, final_src, \
-                    rowof_all
-            # SINGLE-LEVEL regions: each region holds its block's
-            # FOREIGN rows first (rows another block holds too — the
-            # only positions whose src differs from themselves), so
-            # the leaf fetch streams the region and gathers only
-            # those (_region_fetch)
-            m_occ = n_occ // nblk
-            v = fv.reshape(nblk, m_occ)
-            rowof_blocks, vslots, foreign = region_slots(v // sp, sentinel)
-            base = (jnp.arange(nblk, dtype=jnp.int32) * m_occ)[:, None]
-            slots = ((base + vslots) * sp
-                     + (v % sp).astype(jnp.int32)).reshape(fv.shape)
-            rowof_all = rowof_blocks.reshape(-1)
-            cache = _cache_fetch(flat, rowof_all)
-            src, final_rowof, final_src = region_plan(rowof_blocks,
-                                                      sentinel)
-            info = {"src": src,
-                    "base": jnp.arange(nblk, dtype=jnp.int32) * m_occ,
-                    "foreign": foreign}
-            return cache, slots, info, final_rowof, final_src, rowof_all
-
-        def ladder_sizes(nb, region_single):
-            """Static block sizes of the in-graph cache ladder for an
-            nb-step scan, outermost first.  "auto" is the shallow
-            two-level shape [8*inner, inner] (round-4 measurement — see
-            the comment below; ``epoch_cache_chunk`` no longer shapes
-            the auto ladder, it only sizes host-side dispatch chunks for
-            epochs the ladder cannot engage).  When 8*inner does not
-            divide nb, auto falls back to [geometric mid, inner], and
-            when ``epoch_cache_inner`` <= 1 to a chunk-sized single
-            level.  ``epoch_cache_levels`` overrides: "off" disables the
-            ladder, a comma list (or tuple) names explicit sizes.
-
-            ``region_single`` is cache_prologue's every-cache-op-engaged-
-            regions decision, passed EXPLICITLY (advisor r5: this used to
-            be a mutable closure flag set mid-trace, so a consumer that
-            ran before the prologue would silently read a stale value and
-            pick a ladder shape inconsistent with the region plans)."""
-            cfg_levels = getattr(self.config, "epoch_cache_levels", "auto")
-            if cfg_levels in ("off", "", None):
-                return []
-            if cfg_levels != "auto":
-                if isinstance(cfg_levels, str):
-                    return [int(s) for s in cfg_levels.split(",")
-                            if s.strip()]
-                return [int(s) for s in cfg_levels]
-            inner = int(getattr(self.config, "epoch_cache_inner", 8))
-            # Auto is the SHALLOW two-level shape [8*inner, inner]: the
-            # round-3 deep [chunk, mid, inner] ladder existed because
-            # explicit-level probes looked 3.5x worse — but that was
-            # chunked DISPATCH overhead, not device work (round-4
-            # profile: [64,8] busy 259 ms vs [256,32,8] busy 322 ms at
-            # the headline shape — every extra level adds its own
-            # rebuild+writeback boundary traffic, ~4 bytes moved per
-            # occurrence-row per level).  The mid cache (8*inner steps)
-            # stays small enough for XLA:TPU to keep in fast scoped
-            # memory while its writebacks into the epoch cache amortize
-            # over 8 inner blocks.
-            #
-            # Under REGIONS for every cache op the mid level loses its
-            # reason to exist — the region fetch's HBM gather issues
-            # are no fewer for reading into a mid cache than straight
-            # into the leaf block, so the mid level only adds its own
-            # S(1) rebuild + dus layer: the ladder collapses to [inner]
-            # (busy 185.0 -> 171.6 ms, bench-recorded 171.5, round 5),
-            # and only that single-level layout has the streamed fetch
-            # (_region_fetch).
-            if 0 < inner < nb:
-                if region_single and nb % inner == 0:
-                    return [inner]
-                top = inner * 8
-                if top < nb and nb % top == 0:
-                    return [top, inner]
-                if nb % inner == 0:
-                    # non-divisible top: single level, plus a geometric
-                    # mid when the epoch is long enough to need one
-                    sizes = []
-                    if nb // inner > 8:
-                        import math
-                        target = math.isqrt(nb * inner)
-                        cands = [s for s in range(inner + 1, nb)
-                                 if nb % s == 0 and s % inner == 0]
-                        if cands:
-                            sizes.append(min(cands,
-                                             key=lambda s: abs(s - target)))
-                    sizes.append(inner)
-                    return sizes
-            # inner disabled (<= 1) or not engaging: a chunk-sized
-            # single level still bounds the per-step cache sweep (the
-            # pre-round-3 behavior for epoch_cache_inner=0)
-            chunk = int(getattr(self.config, "epoch_cache_chunk", 256))
-            if 0 < chunk < nb and nb % chunk == 0:
-                return [chunk]
-            return []
-
-        def _seg_blocks_for(nb, region_single):
-            """K for first-touch-segmented epoch slots: the top ladder
-            level's block count, or 1 when no level engages (then
-            nothing exploits segmentation, so plain dense-rank slotting
-            keeps the prologue cheapest)."""
-            if not seg_enabled:
-                return 1
-            sizes = ladder_sizes(nb, region_single)
-            if not sizes:
-                return 1
-            top = sizes[0]
-            if 0 < top < nb and nb % top == 0:
-                return nb // top
-            return 1
-
-        def ladder_meta(nb, slots_ep, rows0, region_single):
-            """Static ladder plan [(size, {op: cache rows}), ...]: at
-            each level every op whose padded block cache would be
-            smaller than its current parent cache participates; a level
-            nobody joins is dropped.  Pure shape math — the traced twin
-            is ladder_arrays.  Row units follow the op's storage form:
-            STORAGE rows (view rows, one per id occurrence) for
-            packed-storage ops, logical rows otherwise — matching the
-            actual cache arrays' shape[0] at every level."""
-            meta, rows, cur = [], dict(rows0), nb
-            for size in ladder_sizes(nb, region_single):
-                if not (0 < size < cur and cur % size == 0):
-                    continue
-                part = {}
-                for name, sl in slots_ep.items():
-                    per_step = int(np.prod(sl.shape[1:]))
-                    if op_storage[name] > 1:
-                        m = size * per_step  # view slots: 1/occurrence
-                    else:
-                        pack = op_pack[name]
-                        m = -(-(size * per_step) // pack) * pack
-                    if m < rows[name]:
-                        part[name] = m
-                if part:
-                    meta.append((size, part))
-                    rows.update(part)
-                    cur = size
-            return meta
-
-        def ladder_arrays(slots, meta, rows, top=True, region_src=None,
-                          region_single=False):
-            """The ladder's slot plans, precomputed OUTSIDE the scans
-            (the slot math — ops/slotting.py sorts — depends only on the
-            epoch's ids, so under ``train_epochs`` it runs once for ALL
-            fused epochs).  Returns a nested pytree consumed as scan xs:
-            each level {"rowof": {op: (nblk, m)}, "next": ...}; the leaf
-            carries the per-step slots into each op's innermost cache.
-            At the TOP level, ops with first-touch-segmented epoch slots
-            also get {"segP": {op: (nblk,)}, "segk": (nblk,)} — the
-            per-block reused-row count and block index the segmented
-            fetch/writeback consume."""
-            if not meta:
-                return {"slots": slots}
-            from .ops.slotting import slot_rows
-            (size, part), rest = meta[0], meta[1:]
-            nb = next(iter(slots.values())).shape[0]
-            nblk = nb // size
-            blks = {n: s.reshape((nblk, size) + s.shape[1:])
-                    for n, s in slots.items()}
-            # block-major region ops: the fetch indices are the
-            # precomputed predecessor src plan, block slots are the
-            # region POSITIONS (a subtraction, not a re-ranking — the
-            # two-level layout's inter-region sentinel holes make
-            # dense ranks diverge from positions), and the writeback
-            # streams into the block's own region (outer() keys on
-            # "region_base").  ``region_src`` entries:
-            # {"src": (nblk, m), "base": (nblk,), ["foreign": (nblk,)],
-            # ["inner": ...]} — "inner" recurses one level down;
-            # "foreign" (single-level layout only) is the count of
-            # leading positions the fetch has to gather.
-            srcs = {n: s for n, s in (region_src or {}).items()
-                    if n in part}
-
-            def per_block(blk, src_blk):
-                rowof_d, slots_d = {}, {}
-                for name, b in blk.items():
-                    if name in part:
-                        sp = op_storage[name]
-                        if name in src_blk:
-                            rowof = src_blk[name]["src"]
-                            s = b - src_blk[name]["base"] * sp
-                        elif sp > 1:
-                            # view-unit slotting: parent rows are view
-                            # rows; each occurrence gets a view slot,
-                            # its logical slot offset by the id's half
-                            rowof, s = slot_rows(b // sp, rows[name])
-                            s = s * sp + (b % sp).astype(jnp.int32)
-                        else:
-                            rowof, s = slot_rows(b, rows[name])
-                        m, n = part[name], int(np.prod(b.shape))
-                        if m > n:
-                            rowof = jnp.concatenate(
-                                [rowof, jnp.full((m - n,), rows[name],
-                                                 rowof.dtype)])
-                        rowof_d[name], slots_d[name] = rowof, s
-                    else:
-                        slots_d[name] = b
-                inner_srcs = {n: s["inner"] for n, s in src_blk.items()
-                              if "inner" in s}
-                return {"rowof": rowof_d,
-                        "next": ladder_arrays(slots_d, rest,
-                                              {**rows, **part},
-                                              top=False,
-                                              region_src=inner_srcs)}
-
-            arrs = jax.vmap(per_block)(blks, srcs)
-            if srcs:
-                arrs["region_base"] = {n: srcs[n]["base"] for n in srcs}
-                arrs["region_foreign"] = {
-                    n: srcs[n]["foreign"] for n in srcs
-                    if "foreign" in srcs[n]}
-            if top and nblk > 1:
-                segP = {}
-                for name in part:
-                    n_occ = int(np.prod(slots[name].shape))
-                    if (op_storage[name] > 1
-                            and nblk == _seg_blocks_for(nb, region_single)
-                            and part[name] * nblk == n_occ):
-                        ro = arrs["rowof"][name]  # (nblk, m)
-                        base = (jnp.arange(nblk, dtype=jnp.int32)
-                                * part[name])
-                        segP[name] = jax.vmap(
-                            lambda r, b: jnp.searchsorted(r, b))(ro, base)
-                if segP:
-                    arrs["segP"] = segP
-                    arrs["segk"] = jnp.arange(nblk, dtype=jnp.int32)
-            return arrs
-
-        def step_body(st, batch):
-            """The innermost scan body, shared by the flat epoch scan
-            and the ladder's leaf level."""
-            binputs, blabels, bslots = batch
-            return train_step(st, binputs, blabels, slot_override=bslots)
-
-        def ladder_scan(state, inputs, labels, meta, arrs):
-            """Nested scans down the ladder: each level pulls its
-            block's rows from the parent cache (one gather at the
-            precomputed rowof), recurses against the block cache, and
-            writes the final rows back — so the per-step table cost
-            scales with the innermost block's rows while each level's
-            rebuild sweep amortizes over its block length.  Exactness:
-            every distinct parent row has exactly ONE slot in the block
-            cache, so the same adds hit the same values in the same
-            order at every level (the single-level proof composes)."""
-            if not meta:
-                return jax.lax.scan(step_body, state,
-                                    (inputs, labels, arrs["slots"]))
-            (size, part), rest = meta[0], meta[1:]
-            nb = labels.shape[0]
-
-            def blk(x):
-                return x.reshape((nb // size, size) + x.shape[1:])
-
-            def outer(st, xs_k):
-                in_k, lab_k, a_k = xs_k
-                seg_ps = a_k.get("segP", {})
-                seg_k = a_k.get("segk")
-                reg_b = a_k.get("region_base", {})
-                reg_f = a_k.get("region_foreign", {})
-                params2 = dict(st.params)
-                opt2 = st.opt_state
-                wb, slot_wb = [], []
-                for name in part:
-                    parent = st.params[name]["embedding"]
-                    rowof = a_k["rowof"][name]
-                    seg = ((seg_k, seg_ps[name], part[name])
-                           if name in seg_ps else None)
-                    base_k = reg_b.get(name)
-                    foreign_k = reg_f.get(name)
-
-                    def _fetch(fl, r=rowof, s=seg, b=base_k,
-                               f=foreign_k):
-                        # region mode: r IS the src plan — the
-                        # single-level layout streams its own region
-                        # and gathers the foreign positions, the
-                        # grouped two-level one gathers every position
-                        if f is not None:
-                            return _region_fetch(
-                                fl.reshape(-1, fl.shape[-1]), r, b, f)
-                        if s is None:
-                            return _cache_fetch(fl, r)
-                        return _seg_fetch(fl.reshape(-1, fl.shape[-1]),
-                                          r, s[0], s[1], s[2])
-
-                    def _wback(p, r, child, s=seg, b=base_k):
-                        if b is not None:
-                            # block-major region: stream the whole block
-                            # cache into the block's own region (the
-                            # measured-8.4x dus; ab_boundary.py)
-                            fl = p.reshape(-1, p.shape[-1])
-                            out = jax.lax.dynamic_update_slice(
-                                fl, child.reshape(-1, fl.shape[-1]),
-                                (b, 0))
-                            return out.reshape(p.shape)
-                        if s is None:
-                            return _cache_writeback(p, r, child)
-                        return _seg_writeback(p, r, child,
-                                              s[0], s[1], s[2])
-
-                    with jax.named_scope("ff.ladder.fetch"):
-                        params2[name] = {"embedding": _fetch(parent)}
-                    wb.append((name, rowof, parent, _wback))
-                    if lazy_slots:
-                        for sn in lazy_slots:
-                            slot_wb.append(
-                                (sn, name, rowof,
-                                 opt2[sn][name]["embedding"], _wback))
-                        with jax.named_scope("ff.ladder.fetch"):
-                            opt2 = _swap_slot_caches(opt2, name, _fetch)
-                st2 = TrainState(params2, opt2, st.bn_state,
-                                 st.rng, st.step)
-                st2, mets_k = ladder_scan(st2, in_k, lab_k, rest,
-                                          a_k["next"])
-                new_p = dict(st2.params)
-                opt3 = st2.opt_state
-                with jax.named_scope("ff.ladder.writeback"):
-                    for name, rowof, parent, _wback in wb:
-                        new_p[name] = {"embedding": _wback(
-                            parent, rowof, st2.params[name]["embedding"])}
-                    for sn, name, rowof, parent, _wback in slot_wb:
-                        final = st2.opt_state[sn][name]["embedding"]
-                        opt3 = _swap_opt_entry(
-                            opt3, sn, name, _wback(parent, rowof, final))
-                st3 = TrainState(new_p, opt3, st2.bn_state,
-                                 st2.rng, st2.step)
-                return st3, mets_k
-
-            return jax.lax.scan(outer, state,
-                                (jax.tree.map(blk, inputs), blk(labels),
-                                 arrs))
-
-        def epoch_scan(state, inputs, labels, slots_ep, meta, arrs):
+        def epoch_scan(state, inputs, labels, plan):
             """Scan one epoch's steps against the (cached) tables; returns
             (state, per-epoch folded metrics)."""
             with jax.named_scope("ff.ladder"):
-                if meta:
-                    state, mets = ladder_scan(state, inputs, labels, meta,
-                                              arrs)
+                if plan is None:
+                    state, mets = jax.lax.scan(
+                        lambda st, b: train_step(st, *b), state,
+                        (inputs, labels))
                 else:
-                    state, mets = jax.lax.scan(step_body, state,
-                                               (inputs, labels, slots_ep))
+                    state, mets = cache.scan(train_step, state, inputs,
+                                             labels, plan)
                 folded = {k: _fold_steps(k, v, counter_ranks.get(k))
                           for k, v in mets.items()}
             return state, folded
-
-        def ladder_plan(state, slots_ep, nb, region_src=None,
-                        region_single=False):
-            """(meta, arrays) of the in-graph ladder, or ({}, None)."""
-            if not slots_ep:
-                return [], None
-            rows0 = {name: state.params[name]["embedding"].shape[0]
-                     for name in slots_ep}
-            meta = ladder_meta(nb, slots_ep, rows0, region_single)
-            if not meta:
-                return [], None
-            if region_src:
-                # region layout presumes its ops engage the top level
-                # at exactly the nblk the plan was built for — and the
-                # TWO-level layout additionally presumes the inner
-                # level engages with exactly nl0 blocks (a row has one
-                # slot PER L0 REGION; without the inner level,
-                # same-L1-block occurrences would stop propagating
-                # updates to each other — silently bit-inexact)
-                top = meta[0][0]
-                for name, info in region_src.items():
-                    assert (name in meta[0][1]
-                            and info["src"].shape[0] == nb // top), \
-                        (name, info["src"].shape, top, nb)
-                    if "inner" in info:
-                        assert (len(meta) >= 2 and name in meta[1][1]
-                                and info["inner"]["src"].shape[1]
-                                == top // meta[1][0]), \
-                            (name, info["inner"]["src"].shape, meta)
-            return meta, ladder_arrays(slots_ep, meta, rows0,
-                                       region_src=region_src,
-                                       region_single=region_single)
-
-        def cache_epilogue(state, writebacks, originals):
-            """Write the final rows back, each live slot exactly once
-            (set, not add — bit-exact with the per-step path); sentinel
-            indices (padding holes) are dropped.  Lazy mode writes the
-            optimizer slot caches back the same way."""
-            if not writebacks:
-                return state
-            new_params = dict(state.params)
-            opt_state = state.opt_state
-            for name, tb_shape, rowof, wpack, sorted_ok, fsrc in writebacks:
-                def _final(cache, fsrc=fsrc):
-                    # region layout: each row's LAST copy, compacted to
-                    # global row order (final_src — region_plan), so the
-                    # table scatter stays sorted
-                    fl = cache.reshape(-1, cache.shape[-1])
-                    if fsrc is None:
-                        return fl
-                    return jnp.take(fl, fsrc, axis=0)
-                new_params[name] = {"embedding": _cache_writeback(
-                    originals[name], rowof,
-                    _final(state.params[name]["embedding"]), wpack,
-                    sorted_rowof=sorted_ok)}
-                for sn in lazy_slots:
-                    opt_state = _swap_opt_entry(
-                        opt_state, sn, name,
-                        _cache_writeback(
-                            originals[(sn, name)], rowof,
-                            _final(state.opt_state[sn][name]["embedding"]),
-                            wpack, sorted_rowof=sorted_ok))
-            return TrainState(new_params, opt_state,
-                              state.bn_state, state.rng, state.step)
-
-        def cached_plan(state, inputs, nb):
-            """What both epoch programs do before their scans: the
-            row-cache prologue, then the ladder's slot plans."""
-            with jax.named_scope("ff.cache.prologue"):
-                state, slots_ep, writebacks, orig, rsrc, rsingle = \
-                    cache_prologue(state, inputs)
-            with jax.named_scope("ff.cache.plan"):
-                meta, arrs = ladder_plan(state, slots_ep, nb, rsrc,
-                                         rsingle)
-            return state, slots_ep, writebacks, orig, meta, arrs
 
         def train_epoch(state: TrainState, inputs, labels):
             """Scan a whole epoch on device — one dispatch for nb steps.
@@ -2228,12 +1280,12 @@ class FFModel:
             dispatch.  ``inputs``: dict name -> (nb, batch, ...) stacked
             batches resident on device; ``labels``: (nb, batch, ...).
             """
-            state, slots_ep, writebacks, orig, meta, arrs = \
-                cached_plan(state, inputs, labels.shape[0])
-            state, folded = epoch_scan(state, inputs, labels, slots_ep,
-                                       meta, arrs)
+            if cache is None:
+                return epoch_scan(state, inputs, labels, None)
+            state, plan = cache.plan(state, inputs, labels.shape[0])
+            state, folded = epoch_scan(state, inputs, labels, plan)
             with jax.named_scope("ff.cache.epilogue"):
-                return cache_epilogue(state, writebacks, orig), folded
+                return cache.finish(state, plan), folded
 
         def train_epochs(state: TrainState, inputs, labels, n_epochs: int):
             """``n_epochs`` passes over the same stacked batches in ONE
@@ -2245,17 +1297,18 @@ class FFModel:
             across epochs performs the same adds on the same values.
             Returns per-epoch folded metrics stacked on a leading
             (n_epochs,) axis."""
-            state, slots_ep, writebacks, orig, meta, arrs = \
-                cached_plan(state, inputs, labels.shape[0])
+            def epochs(state, plan):
+                with jax.named_scope("ff.ladder"):
+                    return jax.lax.scan(
+                        lambda st, _: epoch_scan(st, inputs, labels, plan),
+                        state, None, length=n_epochs)
 
-            def ep_body(st, _):
-                return epoch_scan(st, inputs, labels, slots_ep, meta, arrs)
-
-            with jax.named_scope("ff.ladder"):
-                state, stacked = jax.lax.scan(ep_body, state, None,
-                                              length=n_epochs)
+            if cache is None:
+                return epochs(state, None)
+            state, plan = cache.plan(state, inputs, labels.shape[0])
+            state, stacked = epochs(state, plan)
             with jax.named_scope("ff.cache.epilogue"):
-                return cache_epilogue(state, writebacks, orig), stacked
+                return cache.finish(state, plan), stacked
 
         donate = (0,) if donate_state else ()
         self._donate_argnums = donate  # telemetry: compile-event stats
@@ -2568,57 +1621,11 @@ class FFModel:
 
     def _epoch_chunk_bounds(self, nb: int):
         """(lo, hi) chunk slices for a chunked epoch dispatch, or None
-        when chunking doesn't apply.  Chunks are equalized
-        (nb // ceil(nb/chunk)) so a non-divisible epoch compiles at most
-        TWO scan shapes (equal chunks + one remainder-folded tail), and
-        rounded to a multiple of the inner cache block so the in-graph
-        L0 level stays engaged for non-divisible epoch lengths."""
-        chunk = int(getattr(self.config, "epoch_cache_chunk", 256))
-        if not (self._epoch_cache_active and chunk > 0 and nb > chunk):
+        when chunking doesn't apply (``CachePolicy.chunk_bounds``; a
+        model without the epoch row-cache is never chunked)."""
+        if not self._epoch_cache_active:
             return None
-        levels = getattr(self.config, "epoch_cache_levels", "auto")
-        inner = int(getattr(self.config, "epoch_cache_inner", 8))
-        if levels == "auto" and (nb % chunk == 0
-                                 or (inner > 1 and nb % inner == 0)):
-            # an in-graph ladder level engages over the full epoch, so
-            # the whole (multi-epoch) run is one dispatch with one
-            # prologue; host-side chunking remains only for epochs no
-            # level divides
-            return None
-        if levels not in ("auto", "off", "", None):
-            # explicit ladder sizes: run unchunked whenever at least one
-            # level engages (divides nb) — host-side chunking would pay
-            # one dispatch per chunk plus a per-chunk cache fill, which
-            # is what the round-3 ladder-shape probes actually measured
-            # (the "3.5x worse" shallow shapes have device-busy equal to
-            # auto's; the regression was all dispatch)
-            sizes = ([int(s) for s in levels.split(",") if s.strip()]
-                     if isinstance(levels, str)
-                     else [int(s) for s in levels])
-            if any(0 < s < nb and nb % s == 0 for s in sizes):
-                return None
-        if inner > 1 and chunk > inner:
-            # work in whole inner blocks so every main chunk keeps the
-            # in-graph L0 level; a sub-block remainder becomes one tiny
-            # tail chunk (flat scan).  At most 3 compiled scan shapes,
-            # all chunk sizes <= epoch_cache_chunk.
-            q, r = divmod(nb, inner)
-            per = chunk // inner                   # blocks per chunk
-            k = max(-(-q // per), 1)
-            bq, br = divmod(q, k)                  # equalized blocks
-            sizes = [(bq + (1 if i < br else 0)) * inner for i in range(k)]
-            if r:
-                sizes.append(r)
-        else:
-            k = -(-nb // chunk)
-            base = nb // k
-            sizes = [base] * k
-            sizes[-1] += nb - base * k
-        bounds, lo = [], 0
-        for s in sizes:
-            bounds.append((lo, lo + s))
-            lo += s
-        return bounds
+        return self._cache_policy.chunk_bounds(nb)
 
     def _run_epoch_chunks(self, state: TrainState, inputs, labels, bounds,
                           aot=None):

@@ -503,7 +503,7 @@ def _row_set_pallas(table, ids, rows, interpret=False):
     table costs ~6.1 ms (measured, dlrm_hybrid epilogue); per-row DMAs
     pay ~64 ns/row instead and win whenever the touched rows are a
     small fraction of the parent (the dispatch gate lives in
-    model.py's _cache_writeback)."""
+    row_cache.py's _cache_writeback)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

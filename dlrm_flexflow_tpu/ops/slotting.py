@@ -363,63 +363,3 @@ def grouped_region_plan(rowof_l0, nblk_l1: int, num_rows: int):
     key = jnp.where(row_first, srows, jnp.int32(num_rows))
     final_rowof, final_src = jax.lax.sort((key, canon_wrap), num_keys=1)
     return src.reshape(nblk_l1, m1), final_rowof, final_src
-
-
-def slot_rows_segmented(ids, num_rows: int, nblocks: int):
-    """``slot_rows`` with FIRST-TOUCH-SEGMENTED slot assignment.
-
-    The occurrence stream is split into ``nblocks`` equal scan blocks
-    (m = n/nblocks occurrences each).  A distinct row is assigned a slot
-    in the segment of the FIRST block that touches it:
-    ``slot = first_block * m + rank``, where rank orders the block's new
-    rows ascending.  Consequences the ladder's top level exploits
-    (PERF.md round 4):
-
-      * block k's distinct slots, sorted, are
-        ``[reused (< k*m) ..., k*m .. k*m+n_new-1, sentinels]`` — the
-        OWN rows form a contiguous ascending segment range, so the
-        block cache's fetch and writeback against the epoch cache are
-        a streaming ``dynamic_slice``/``dynamic_update_slice`` plus a
-        small scatter for the reused prefix;
-      * segment padding slots (k*m + j, j >= n_new_k) are assigned to
-        no row: ``rowof`` holds the sentinel there and the epilogue
-        drops them.
-
-    Same contract as ``slot_rows`` otherwise: ``rowof[slots] == ids``
-    everywhere, slots shared by duplicate rows.  Requires
-    ``ids.size % nblocks == 0``.
-    """
-    flat = ids.reshape(-1).astype(jnp.int32)
-    n = flat.shape[0]
-    assert n % nblocks == 0, (n, nblocks)
-    m = n // nblocks
-    pos = jnp.arange(n, dtype=jnp.int32)
-    blk = pos // m
-    # sort by (row, block); block as secondary key makes each run's
-    # first entry carry the row's FIRST-touching block
-    s, sblk, perm = jax.lax.sort((flat, blk, pos), num_keys=2,
-                                 is_stable=False)
-    first = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
-    idx = pos  # sorted-space index
-    run_first_idx = jax.lax.cummax(jnp.where(first, idx, 0))
-    kfirst = sblk[run_first_idx]
-    # second sort: run-firsts grouped by first block (rows ascending
-    # inside each group — s is the secondary key); non-firsts pushed
-    # past every group
-    kkey = jnp.where(first, kfirst, jnp.int32(nblocks))
-    k2, _s2, idx2 = jax.lax.sort((kkey, s, idx), num_keys=2,
-                                 is_stable=False)
-    starts = jnp.full((nblocks + 1,), n, jnp.int32).at[k2].min(pos)
-    rank2 = pos - starts[k2]
-    slot2 = k2 * m + rank2  # valid where k2 < nblocks (run-firsts)
-    # slots back to sorted space (out[idx2] = slot2, expressed as sort)
-    _, slot_sorted = jax.lax.sort((idx2, slot2), num_keys=1,
-                                  is_stable=False)
-    run_slot = jnp.take(slot_sorted, run_first_idx)  # share within runs
-    # back to occurrence order
-    _, slots = jax.lax.sort((perm, run_slot), num_keys=1,
-                            is_stable=False)
-    tgt = jnp.where(first, run_slot, jnp.int32(n))  # non-firsts dropped
-    rowof = jnp.full((n,), jnp.int32(num_rows)).at[tgt].set(
-        s, mode="drop")
-    return rowof, slots.reshape(ids.shape)

@@ -241,8 +241,9 @@ def parse_device_trace_phases(logdir: str):
 # --------------------------------------------------------------- phases
 #: the phase of an instruction whose name stack holds no phase scope
 UNATTRIBUTED = "unattributed"
-#: a phase scope: model.py::_compile_body opens them, all under the one
-#: prefix ``ff.`` so that a graph op's own scope is never taken for one
+#: a phase scope: model.py::_compile_body and row_cache.py open them, all
+#: under the one prefix ``ff.`` so that a graph op's own scope is never
+#: taken for one
 _PHASE = re.compile(r"(?<![\w.])ff\.[a-z_]+(?:\.[a-z_]+)*")
 _WRAPPER = re.compile(r"([A-Za-z_]\w*)?\(|\)")
 

@@ -357,9 +357,9 @@ SCHEMA: Dict[str, dict] = {
     # this log (FFModel.train_step / train_epoch / train_epochs through
     # profiling.note_program): ``name`` is the key
     # ``profiling.program_phases`` takes to say which phase scope of
-    # ``model.py::_compile_body`` each HLO instruction of that program
-    # belongs to; ``fn`` the wrapper's jitted function.  One event per
-    # program and log, not per dispatch.
+    # ``model.py::_compile_body`` / ``row_cache.py`` each HLO instruction
+    # of that program belongs to; ``fn`` the wrapper's jitted function.
+    # One event per program and log, not per dispatch.
     "program": {
         "required": {"name": str},
         "optional": {"fn": str},

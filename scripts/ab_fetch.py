@@ -1,6 +1,6 @@
 """A/B the region fetch by PIECE SIZE on the real chip (PR 29).
 
-``model.py::_region_fetch`` streams a leaf block's own region with one
+``row_cache.py::_region_fetch`` streams a leaf block's own region with one
 ``dynamic_slice`` and gathers its ``P`` foreign positions over it in
 pieces of ``chunk`` rows.  ``REGION_FETCH_CHUNK = 768`` comes from this
 program, which calls that very function with ``chunk`` as given:
@@ -69,7 +69,7 @@ def one_piece(parent, src, base, count):
 
 def in_pieces(chunk: int):
     """The program's own ``_region_fetch``, with ``chunk`` as given."""
-    from dlrm_flexflow_tpu.model import _region_fetch
+    from dlrm_flexflow_tpu.row_cache import _region_fetch
 
     def fetch(parent, src, base, count):
         return _region_fetch(parent, src, base, count, chunk)

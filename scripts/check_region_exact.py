@@ -2,7 +2,7 @@
 
 The benchmark's ``correct`` compares a 16-batch ``train_epoch`` (shared
 slots): it cannot reach the region plans, ``region_slots`` or
-``_region_fetch``, which engage from 2^18 occurrences an epoch.  This
+``row_cache._region_fetch``, which engage from 2^18 occurrences an epoch.  This
 runs the same fused ``train_epochs`` dispatch twice at a shape where
 they do — by default the benchmark cells' own, 512 batches x 256 x 8
 fused epochs on the run_random.sh model — once with

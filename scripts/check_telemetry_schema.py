@@ -776,21 +776,22 @@ def check_phase_contract(doc_path: str) -> list:
     errs = []
     with open(doc_path) as f:
         doc = f.read()
-    with open(os.path.join(REPO, "dlrm_flexflow_tpu", "model.py")) as f:
-        tree = ast.parse(f.read())
     scopes = set()
-    for node in ast.walk(tree):
-        if (isinstance(node, ast.Call)
-                and isinstance(node.func, ast.Attribute)
-                and node.func.attr == "named_scope" and node.args
-                and isinstance(node.args[0], ast.Constant)):
-            scopes.add(node.args[0].value)
-    if not scopes:
-        errs.append("model.py opens no jax.named_scope literal: the "
-                    "phase scopes of _compile_body are gone")
+    for source in ("model.py", "row_cache.py"):   # the step's, the cache's
+        with open(os.path.join(REPO, "dlrm_flexflow_tpu", source)) as f:
+            tree = ast.parse(f.read())
+        found = {node.args[0].value for node in ast.walk(tree)
+                 if (isinstance(node, ast.Call)
+                     and isinstance(node.func, ast.Attribute)
+                     and node.func.attr == "named_scope" and node.args
+                     and isinstance(node.args[0], ast.Constant))}
+        if not found:
+            errs.append(f"{source} opens no jax.named_scope literal: "
+                        f"its phase scopes are gone")
+        scopes |= found
     for scope in sorted(scopes):
         if phase_of(f"jit(f)/{scope}/add") != scope:
-            errs.append(f"model.py scope {scope!r} is not a phase by "
+            errs.append(f"scope {scope!r} is not a phase by "
                         f"profiling.phase_of's naming rule")
         if f"`{scope}`" not in doc:
             errs.append(f"docs/telemetry.md does not document the phase "

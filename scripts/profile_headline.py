@@ -75,7 +75,7 @@ def parse_trace(logdir, min_frac=0.001):
 
 
 def region_fetch_line(model, state, inputs, labels, epochs):
-    """What the single-level region fetch (``model._region_fetch``)
+    """What the single-level region fetch (``row_cache._region_fetch``)
     meets on these ids: per leaf block, the positions it gathers (rows
     another block holds too: the plan's own count,
     ``ops/slotting.py::region_slots``) and the share it streams with
@@ -145,7 +145,8 @@ def main():
         print(f"{dur/1e3:10.2f} ms  {dur/total*100:5.1f}%  "
               f"{dur/steps:8.1f} us/step  {name[:110]}")
     # the same self time by phase of the compiled program (the scopes of
-    # model.py::_compile_body, read off each slice's name stack)
+    # model.py::_compile_body and row_cache.py, read off each slice's
+    # name stack)
     from dlrm_flexflow_tpu.profiling import parse_device_trace_phases
 
     _path, by_phase, _busy = parse_device_trace_phases(logdir)

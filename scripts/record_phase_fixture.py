@@ -5,7 +5,9 @@ One 16-batch ``train_epoch`` of the run_random.sh DLRM (the program
 with telemetry on, written next to that program's ``program_phases``
 map: the trace route (``args.tf_op``) and the map route must give the
 same ``{phase: us}`` on it.  Re-record when a phase scope of
-``model.py::_compile_body`` is added, renamed or moved.
+``model.py::_compile_body`` or ``row_cache.py`` is added, renamed or
+given another extent (a scope that moves with its code, as in PR 33,
+changes no program).
 
 Usage (on a TPU): python scripts/record_phase_fixture.py <outdir>
 Writes <outdir>/v5e_train_epoch_phases_trace.json.gz (not

@@ -542,9 +542,9 @@ def test_every_scope_of_the_compiled_step_is_attributed():
 
 
 def test_dlrm_keeps_the_sparse_path_and_a_tied_table_leaves_it():
-    """``cached_plan`` passes a model without a row-sparse table
-    untouched (plain SGD would take an untied embedding row-sparse; a
-    table two ops read is updated densely)."""
+    """A model without a row-sparse table trains on the plain scan
+    (plain SGD would take an untied embedding row-sparse; a table two
+    ops read is updated densely)."""
     from dlrm_flexflow_tpu.optim import SGDOptimizer
     cfg = _small()
     model = app.build(cfg, FFConfig(batch_size=2))

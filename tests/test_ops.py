@@ -425,7 +425,7 @@ class TestActivationDtype:
 
     def test_epoch_cache_view_validated_without_sparse_ops(self):
         """epoch_cache_view typos must fail compile even when no sparse
-        embedding op exists to reach cache_prologue (advisor r3)."""
+        embedding op exists to need a row cache (advisor r3)."""
         import dlrm_flexflow_tpu as ff
         fc = ff.FFConfig(batch_size=8)
         fc.epoch_cache_view = "one"  # typo for "on"

@@ -25,7 +25,8 @@ from dlrm_flexflow_tpu.telemetry import event_log, span, start_span
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
-#: every scope model.py::_compile_body opens (PERF.md §3), as phases
+#: every scope model.py::_compile_body and row_cache.py open for the
+#: tiny DLRM (PERF.md §3), as phases
 SCOPES = {"ff.cache.prologue", "ff.cache.plan", "ff.cache.epilogue",
           "ff.ladder", "ff.ladder.fetch", "ff.ladder.writeback",
           "ff.step.gather", "ff.step.model", "ff.step.model.bwd",
@@ -154,9 +155,10 @@ def test_region_fetch_sub_scopes_are_ladder_time_on_both_routes(tmp_path):
     if root not in sys.path:
         sys.path.insert(0, root)
     from benchmarks.lib import phases as bench_phases
+    from benchmarks.models.dlrm import PHASE_GROUPS
 
     for scope in REGION_FETCH:
-        assert bench_phases.group_of(scope) == "ladder"
+        assert bench_phases.group_of(scope, PHASE_GROUPS) == "ladder"
     # the map route
     by_map = hlo_phases(FETCH_HLO)
     assert by_map["dynamic_slice.124"] == "ff.ladder.fetch.own"
@@ -168,7 +170,7 @@ def test_region_fetch_sub_scopes_are_ladder_time_on_both_routes(tmp_path):
     self_us = {"dynamic_slice.124": 40.0, "fusion.144": 30.0,
                "dynamic_update_slice.129": 2.0, "while.228": 1.0,
                "dynamic_update_slice.95": 35.0}
-    parts = bench_phases.split(self_us, by_map, 110.0)
+    parts = bench_phases.split(self_us, by_map, 110.0, PHASE_GROUPS)
     assert parts["ladder"] == 108.0 and parts[UNATTRIBUTED] == 2.0
     # the trace route
     stack = "jit(f)/ff.ladder/while/body/ff.ladder.fetch/"
@@ -187,7 +189,8 @@ def test_region_fetch_sub_scopes_are_ladder_time_on_both_routes(tmp_path):
     _path, by_phase, _busy = parse_device_trace_phases(str(tmp_path))
     assert by_phase == {"ff.ladder.fetch.own": 40.0,
                         "ff.ladder.fetch.foreign": 33.0}
-    assert {bench_phases.group_of(p) for p in by_phase} == {"ladder"}
+    assert {bench_phases.group_of(p, PHASE_GROUPS)
+            for p in by_phase} == {"ladder"}
 
 
 # ------------------------------------------------------- the tiny model

@@ -188,7 +188,7 @@ class TestGroupedRegionPlan:
 
 
 class TestRegionFetch:
-    """``model._region_fetch`` alone, at the chunk sizes
+    """``row_cache._region_fetch`` alone, at the chunk sizes
     ``scripts/ab_fetch.py`` passes it as well as its own default: the
     block it returns is the one-piece gather's, whatever the chunk."""
 
@@ -197,7 +197,7 @@ class TestRegionFetch:
     def test_equals_the_one_piece_gather(self, chunk, foreign):
         import jax
         import jax.numpy as jnp
-        from dlrm_flexflow_tpu.model import _region_fetch
+        from dlrm_flexflow_tpu.row_cache import _region_fetch
 
         nblk, m, d, k = 4, 48, 8, 2
         rng = np.random.default_rng(foreign)

@@ -1,6 +1,6 @@
 """slot_rows invariants — the epoch row-cache's exactness proof needs
 every occurrence of a row to share one slot, and the slot -> row map to
-round-trip (model.py build_cache)."""
+round-trip (row_cache.py build_cache)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -30,7 +30,7 @@ def check(ids, num_rows):
     assert (rowof[live] < num_rows).all()
     # rowof is NON-DECREASING (distinct rows compacted to the front,
     # sentinels at the end) — the writeback scatter's
-    # indices_are_sorted=True hint depends on this (model.py
+    # indices_are_sorted=True hint depends on this (row_cache.py
     # _cache_writeback; 3.8x on the mid-level writeback, PERF.md)
     assert (np.diff(rowof.astype(np.int64)) >= 0).all()
     assert live[:live.sum()].all()  # live slots contiguous at the front

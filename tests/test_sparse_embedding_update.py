@@ -616,9 +616,11 @@ class TestEpochRowCache:
 
     def test_chunk_bounds_round_to_inner(self):
         import dlrm_flexflow_tpu as ffm
+        from dlrm_flexflow_tpu.row_cache import CachePolicy
         m = ffm.FFModel(ff.FFConfig(epoch_cache_chunk=256,
                                     epoch_cache_inner=8))
         m._epoch_cache_active = True
+        m._cache_policy = CachePolicy.resolve(m.config, "cpu", None)
         # inner divides nb -> an in-graph ladder level engages over the
         # whole epoch, so the dispatch is UNCHUNKED (round 4: host-side
         # chunking cost ~5 ms/dispatch and was the real source of the
@@ -879,99 +881,3 @@ class TestRandomizedEquivalence:
                         ref_m.get_weights(ref_st, opn, k),
                         rtol=1e-5, atol=1e-6,
                         err_msg=f"{key} {opn}/{k} seed={seed}")
-
-
-class TestSegmentedEpochSlots:
-    """First-touch-segmented epoch slots (round 4, PERF.md): the top
-    ladder level's fetch/writeback become streaming slices + a B-prefix
-    scatter.  Must be VALUE-identical to the unsegmented path at the
-    table level — same adds, same order, only slot addresses change."""
-
-    def _run(self, segmented, optimizer=None, ids=None, nb=32, batch=8,
-             rows=512):
-        import dlrm_flexflow_tpu as ffm
-        fc = ff.FFConfig(batch_size=batch, packed_tables="on",
-                         epoch_row_cache="on", epoch_cache_levels="16,8",
-                         epoch_cache_segmented=segmented)
-        m = ffm.FFModel(fc)
-        dense = m.create_tensor((batch, 4), name="dense")
-        sparse = m.create_tensor((batch, 4, 2), "int32", name="sparse")
-        t = m.stacked_embedding(sparse, 4, rows, 8, name="emb",
-                                aggr="sum")
-        t = m.concat([m.dense(dense, 8), m.flat(t)], 1)
-        out = m.dense(t, 1)
-        m.compile(optimizer=optimizer or ff.SGDOptimizer(lr=0.1),
-                  loss_type="mean_squared_error", metrics=(), mesh=False)
-        assert all(op.storage_pack > 1 for op in m.layers
-                   if hasattr(op, "storage_pack"))
-        rng = np.random.default_rng(0)
-        inputs = {"dense": rng.standard_normal(
-            (nb, batch, 4)).astype(np.float32),
-            "sparse": ids}
-        labels = rng.standard_normal((nb, batch, 1)).astype(np.float32)
-        st = m.init(seed=0)
-        st, mets = m.train_epoch(st, inputs, labels)
-        st, mets2 = m.train_epoch(st, inputs, labels)
-        return (np.asarray(st.params["emb"]["embedding"]),
-                float(mets["loss"]), float(mets2["loss"]))
-
-    @pytest.mark.parametrize("skew", ["uniform", "reuse", "zipf"])
-    def test_bit_exact_vs_unsegmented(self, skew):
-        """Both the streaming fast path (uniform over a BIG row space:
-        per-block reuse below the B=m/4 budget) and the lax.cond
-        fallback (zipf / small row space: reuse exceeds it) must match
-        the unsegmented path bit-for-bit at the table level.  The
-        fixture VERIFIES which branch each block takes so neither path
-        can silently go untested."""
-        rng = np.random.default_rng(1)
-        if skew == "uniform":
-            rows = 65536  # low view-row reuse -> streaming branch
-            ids = rng.integers(0, rows, size=(32, 8, 4, 2),
-                               dtype=np.int64)
-        elif skew == "reuse":
-            rows = 9216  # heavy view-row reuse -> P > B, cond fallback
-            ids = rng.integers(0, rows, size=(32, 8, 4, 2),
-                               dtype=np.int64)
-        else:
-            from dlrm_flexflow_tpu.data.loader import zipf_ids
-            rows = 65536  # skewed ids
-            ids = zipf_ids(rng, rows, (32, 8, 4, 2))
-        # The branch condition operates on PACKED VIEW rows of the
-        # STACKED table (d=8 -> pack=16; global row = t*rows + id), not
-        # raw per-table ids (review r4) — recompute exactly what the
-        # runtime sees, and require the epoch cache to ENGAGE at all
-        # (occurrences < view rows; at equality build_cache declines).
-        pack = 128 // 8
-        tbl = np.arange(4)[None, None, :, None]
-        gview = ((ids + tbl * rows) // pack).reshape(32, -1)
-        n_occ = gview.size
-        view_rows = 4 * rows // pack
-        assert n_occ < view_rows, "cache would not engage (vacuous)"
-        m_occ = 16 * gview.shape[1]  # top level 16 of levels "16,8"
-        occ = gview.reshape(-1)
-        blocks = [set(occ[k * m_occ:(k + 1) * m_occ]) for k in range(2)]
-        p1 = len(blocks[1] & blocks[0])
-        if skew == "uniform":
-            # 0 < P <= B: the streaming (contig) branch really runs
-            assert 0 < p1 <= m_occ // 4, (p1, m_occ)
-        elif skew == "reuse":
-            assert p1 > m_occ // 4, (p1, m_occ)   # fallback branch
-        t_on, l1_on, l2_on = self._run("on", ids=ids, rows=rows)
-        t_off, l1_off, l2_off = self._run("off", ids=ids, rows=rows)
-        assert l1_on == l1_off and l2_on == l2_off
-        np.testing.assert_array_equal(t_on, t_off)
-
-    def test_bit_exact_lazy_adam(self):
-        rng = np.random.default_rng(2)
-        rows = 65536  # cache must ENGAGE (occurrences < view rows)
-        ids = rng.integers(0, rows, size=(32, 8, 4, 2), dtype=np.int64)
-
-        def opt():
-            return ff.AdamOptimizer(lr=0.01, lazy_embeddings=True)
-
-        t_on, l1_on, l2_on = self._run("on", optimizer=opt(), ids=ids,
-                                       rows=rows)
-        t_off, l1_off, l2_off = self._run("off", optimizer=opt(),
-                                          ids=ids, rows=rows)
-        assert l1_on == l1_off and l2_on == l2_off
-        np.testing.assert_array_equal(t_on, t_off)
