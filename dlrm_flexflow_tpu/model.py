@@ -864,6 +864,14 @@ class FFModel:
         counting_ops = [op for op in self.layers
                         if hasattr(op, "step_metrics")]
         self._counting_ops = [op.name for op in counting_ops]
+        # what the ``program`` events of this model's programs carry
+        # beside their name: how many attention cores took which form
+        # (ops/attention.py::core_form; shapes and backend, so known here)
+        forms = [op.core_form() for op in self.layers
+                 if hasattr(op, "core_form")]
+        self._program_fields = {"attention_core": {
+            form: forms.count(form) for form in ("pallas", "plain")}} \
+            if forms else {}
         counter_ranks: Dict[str, int] = {}  # filled as train_step is traced
 
         # ---- sparse embedding update fast path ---------------------------
@@ -1477,7 +1485,7 @@ class FFModel:
         sp.end()
         step_fn = self._train_step if donate else self._train_step_nodonate
         if log is not None:
-            note_program(log, step_fn, (state, inputs, labels))
+            self._note_program(log, step_fn, (state, inputs, labels))
         sp = start_span("train.launch", parent=parent, annotate=True) \
             if parent else NULL_SPAN
         out = step_fn(state, inputs, labels)
@@ -1539,8 +1547,8 @@ class FFModel:
         bounds = self._epoch_chunk_bounds(labels.shape[0])
         if bounds is None:
             if log is not None:
-                note_program(log, self._train_epoch,
-                             (state, inputs, labels))
+                self._note_program(log, self._train_epoch,
+                                   (state, inputs, labels))
             out = self._train_epoch(state, inputs, labels)
         else:
             out = self._run_epoch_chunks(state, inputs, labels, bounds)
@@ -1576,8 +1584,8 @@ class FFModel:
         bounds = self._epoch_chunk_bounds(labels.shape[0])
         if bounds is None:
             if log is not None:
-                note_program(log, self._train_epochs,
-                             (state, inputs, labels, int(epochs)))
+                self._note_program(log, self._train_epochs,
+                                   (state, inputs, labels, int(epochs)))
             out = self._train_epochs(state, inputs, labels, int(epochs))
         else:
             mets = []
@@ -1599,6 +1607,11 @@ class FFModel:
                      phase="train_epochs")
             sample_memory(phase="train_epochs", log=log)
         return out
+
+    def _note_program(self, log, fn, args: tuple) -> str:
+        """``profiling.note_program`` with what this model's ``program``
+        events carry beside the name (``attention_core``)."""
+        return note_program(log, fn, args, **self._program_fields)
 
     def _emit_op_counters(self, log, mets, fn: str):
         """One ``op_counters`` event per counting op (ops/moe.py) for
@@ -1641,8 +1654,8 @@ class FFModel:
             cin = {k: v[lo:hi] for k, v in inputs.items()}
             fn = (aot or {}).get(hi - lo, self._train_epoch)
             if log is not None:  # an AOT executable ran the same program
-                note_program(log, self._train_epoch,
-                             (state, cin, labels[lo:hi]))
+                self._note_program(log, self._train_epoch,
+                                   (state, cin, labels[lo:hi]))
             state, mets = fn(state, cin, labels[lo:hi])
             w = hi - lo
             for k, v in mets.items():
@@ -1869,7 +1882,7 @@ class FFModel:
             exe = fn.lower(*args).compile()
             log = active_log()
             if log is not None:
-                note_program(log, fn, args)
+                self._note_program(log, fn, args)
                 log.emit("compile", kind="aot", fn=fn_name,
                          duration_s=time.perf_counter() - tc,
                          donated_args=len(getattr(self, "_donate_argnums",

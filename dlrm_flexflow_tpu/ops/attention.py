@@ -27,6 +27,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..initializers import ConstantInitializer, DEFAULT_KERNEL_INIT
 from ..tensor import ParameterSpec
+from . import pallas_attention
 from .base import Op, matmul
 from .transformer import rms_norm, rope_interleaved
 
@@ -162,9 +163,10 @@ def _blockwise_core(q, k, v, scale, block):
 
 
 #: what a recomputed run (``FFModel.scope(recompute=...)``) keeps of the
-#: core: its output and log-sum-exp (134 + 1 MB a layer in f32 at 32 heads
-#: x 8,192 tokens x 128), so that the backward pass rebuilds the
-#: projections but never runs the core's forward a second time
+#: core, whichever form ran: its output and log-sum-exp (134 + 1 MB a
+#: layer in f32 at 32 heads x 8,192 tokens x 128), so that the backward
+#: pass rebuilds the projections but never runs the core's forward (the
+#: forward kernel) a second time
 CORE_SAVED = ("attention_core_out", "attention_core_lse")
 
 
@@ -184,27 +186,91 @@ def _blockwise_core_bwd(scale, block, res, do):
 _blockwise_core.defvjp(_blockwise_core_fwd, _blockwise_core_bwd)
 
 
-#: key (and query) rows of one tile of the blockwise core.  On the v5e at
-#: 32 heads x 8,192 tokens x 192 / 128, forward + backward: 27.7 ms at
-#: 512, 43.0 at 256, 56.3 at 1,024 (``scripts/ab_lm_kernels.py``)
+def _heads_flat(x):
+    return x.reshape(-1, *x.shape[2:])
+
+
+@jax.custom_vjp
+def _fused_core(q, k, v):
+    """The same core through ``ops/pallas_attention.py``: one kernel
+    forward, one backward; the scale already folded into ``q``."""
+    return _fused_core_fwd(q, k, v)[0]
+
+
+def _fused_core_fwd(q, k, v):
+    o, lse = pallas_attention.forward(_heads_flat(q), _heads_flat(k),
+                                      _heads_flat(v))
+    o = checkpoint_name(o.reshape(*q.shape[:3], -1), CORE_SAVED[0])
+    lse = checkpoint_name(lse.reshape(q.shape[:3]), CORE_SAVED[1])
+    return o, (q, k, v, o, lse)
+
+
+def _fused_core_bwd(res, do):
+    q, k, v, o, lse = res
+    grads = pallas_attention.backward(
+        _heads_flat(q), _heads_flat(k), _heads_flat(v), _heads_flat(o),
+        _heads_flat(lse), _heads_flat(do))
+    return tuple(g.reshape(x.shape) for g, x in zip(grads, (q, k, v)))
+
+
+_fused_core.defvjp(_fused_core_fwd, _fused_core_bwd)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def core_form(seq: int, qk_dim: int, v_dim: int, dtype) -> str:
+    """Which form ``blockwise_causal_attention`` runs at these shapes:
+    ``"pallas"`` on a TPU where the kernels take them, else ``"plain"``.
+    Decided from what the trace can see, and by nothing else."""
+    fused = _on_tpu() and pallas_attention.takes(seq, qk_dim, v_dim, dtype)
+    return "pallas" if fused else "plain"
+
+
+#: key (and query) rows of one tile of the PLAIN blockwise core (the
+#: kernels' block is ``pallas_attention.BLOCK``).  On the v5e at 32 heads
+#: x 8,192 tokens x 192 / 128, forward + backward: 27.7 ms at 512, 43.0 at
+#: 256, 56.3 at 1,024; the kernels that run there since PR 34: 20.6
+#: (``scripts/ab_lm_kernels.py attn``)
 ATTENTION_BLOCK = 512
 
 
 def blockwise_causal_attention(q, k, v, scale: Optional[float] = None,
-                               block: Optional[int] = None):
-    """Causal attention that never builds (B, H, S, S): online softmax
-    over key blocks forward, tile-by-tile recomputation backward
-    (``jax.custom_vjp``; plain ``jax.numpy`` under ``lax`` loops).
-    ``q``, ``k``: (B, H, S, Dk); ``v``: (B, H, S, Dv), Dv free of Dk.
-    Matmul operands keep the dtype they come in (bf16 operands give
-    bf16 x bf16 -> f32 on the MXU, the probabilities rounded to it
-    before ``P v``); logits, softmax and accumulators are f32.
-    Returns (B, H, S, Dv) f32.  The same function as ``sdpa(...,
-    causal=True)`` up to rounding.  ``block``: ``ATTENTION_BLOCK``
-    unless given, cut to the largest divisor of S below it."""
+                               block: Optional[int] = None,
+                               compute_dtype=None):
+    """Causal attention that never builds (B, H, S, S).  ``q``, ``k``:
+    (B, H, S, Dk); ``v``: (B, H, S, Dv), Dv free of Dk.  Returns
+    (B, H, S, Dv) f32: the same function as ``sdpa(..., causal=True)``
+    up to rounding.
+
+    The arithmetic, whichever form runs: the matmul operands go to
+    ``compute_dtype`` (the dtype they come in unless given), each
+    rounded once (bf16 operands give bf16 x bf16 -> f32 on the MXU);
+    logits, softmax, log-sum-exp and every accumulator are f32; the
+    probabilities are rounded to the compute dtype before ``P v``.
+    Differentiated, it saves its output and log-sum-exp under
+    ``CORE_SAVED`` and rebuilds each tile's probabilities from them.
+
+    Two forms, one entry, chosen by ``core_form`` from the backend and
+    the shapes alone.  On a TPU, with S a multiple of
+    ``pallas_attention.BLOCK`` and head widths Mosaic takes (192 / 128
+    in the language model): one Pallas kernel forward and one backward
+    (``ops/pallas_attention.py``); the scale is folded into ``q`` here,
+    in f32 and before its one rounding, so hand ``q`` over in f32.
+    Anywhere else (the CPU, a 12-token sequence, 24-wide heads): the
+    plain core, ``jax.numpy`` under ``lax`` loops with a
+    ``jax.custom_vjp``: online softmax over key blocks forward, tile by
+    tile backward, the logits scaled tile by tile.  ``block`` is the
+    plain core's alone: ``ATTENTION_BLOCK`` unless given, cut to the
+    largest divisor of S below it."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _blockwise_core(q, k, v, float(scale),
+    cd = jnp.dtype(compute_dtype or q.dtype)
+    k, v = k.astype(cd), v.astype(cd)
+    if core_form(q.shape[2], q.shape[3], v.shape[3], cd) == "pallas":
+        return _fused_core((q.astype(jnp.float32) * scale).astype(cd), k, v)
+    return _blockwise_core(q.astype(cd), k, v, float(scale),
                            _block_of(q.shape[2], block or ATTENTION_BLOCK))
 
 
@@ -298,7 +364,13 @@ class LatentAttention(Op):
     query/key width (``nope + rope``) need not equal ``v_dim``.
 
     The core never builds (B, H, S, S): ``blockwise_causal_attention``,
-    ``ATTENTION_BLOCK`` keys at a time.  Scopes: ``<phase>.proj`` and
+    which runs as two Pallas kernels on a TPU at shapes they take and
+    as the plain blockwise core elsewhere (``core_form`` says which; the
+    model's ``program`` events count them).  Everything around the core
+    (the projections, norms, RoPE, ``W_o``) is the same either way, and
+    so is what a recomputed run keeps: the core's output and
+    log-sum-exp (``saved_in_recompute``), so the recomputation rebuilds
+    the projections and never the core.  Scopes: ``<phase>.proj`` and
     ``<phase>.core`` (the op's ``phase`` is ``FFModel.scope``'s word,
     ``ff.attn`` without one).
     """
@@ -346,13 +418,15 @@ class LatentAttention(Op):
             ParameterSpec(self.name, "w_o", (h * self.v_dim, d),
                           initializer=init, sharded_dim=0)]
 
-    def _core(self, q, k, v, scale: float):
-        """(B, H, S, .) f32 in, (B, H, S, v_dim) f32 out; operands go to
-        the compute dtype here, each rounded once."""
-        cd = (jnp.bfloat16 if self.compute_dtype in ("bfloat16", jnp.bfloat16)
-              else jnp.float32)
-        return blockwise_causal_attention(q.astype(cd), k.astype(cd),
-                                          v.astype(cd), scale)
+    def _core_dtype(self):
+        return jnp.dtype(jnp.bfloat16 if self.compute_dtype
+                         in ("bfloat16", jnp.bfloat16) else jnp.float32)
+
+    def core_form(self) -> str:
+        """``"pallas"`` or ``"plain"``: what this op's core runs as
+        (``ops/attention.py::core_form`` at its shapes)."""
+        return core_form(self.inputs[0].shape[1], self.nope + self.rope,
+                         self.v_dim, self._core_dtype())
 
     def forward(self, params, xs, *, training=False, rng=None):
         (x,) = xs
@@ -384,7 +458,11 @@ class LatentAttention(Op):
             q_all, k_all, v = heads_first(q_all), heads_first(k_all), \
                 heads_first(kv[..., nope:])
         with jax.named_scope(scope + ".core"):
-            o = self._core(q_all, k_all, v, 1.0 / math.sqrt(nope + rope))
+            # f32 in, f32 out; the operands go to the compute dtype
+            # inside, each rounded once
+            o = blockwise_causal_attention(
+                q_all, k_all, v, 1.0 / math.sqrt(nope + rope),
+                compute_dtype=self._core_dtype())
         with jax.named_scope(scope + ".proj"):
             o = o.transpose(0, 2, 1, 3).reshape(b, s, h * vd)
             out = matmul(o, params["w_o"], cdt)

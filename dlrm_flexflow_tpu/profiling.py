@@ -384,12 +384,14 @@ def _abstract(x):
     return x
 
 
-def note_program(log, fn, args: tuple) -> str:
+def note_program(log, fn, args: tuple, **fields) -> str:
     """Remember that the jitted ``fn`` was dispatched with ``args`` and
     name it in ``log``: one ``program`` event per program and log (so
     the per-step path pays a signature lookup, not an event).  Keeps
     shapes, dtypes, shardings and static arguments; reads no device
-    value.  Returns the program's name, ``<fn's name>#<n>``."""
+    value.  ``fields`` join the event (``attention_core``: what the
+    model knew of the program when it compiled).  Returns the program's
+    name, ``<fn's name>#<n>``."""
     leaves, treedef = jax.tree_util.tree_flatten(args)
     sig = (id(fn), treedef,
            tuple((x.shape, x.dtype, x.sharding if x.committed else None)
@@ -405,7 +407,7 @@ def note_program(log, fn, args: tuple) -> str:
         _by_signature[sig] = _programs[prog.name] = prog
     if prog.log is None or prog.log() is not log:
         prog.log = weakref.ref(log)
-        log.emit("program", name=prog.name, fn=fn.__name__)
+        log.emit("program", name=prog.name, fn=fn.__name__, **fields)
     return prog.name
 
 

@@ -360,9 +360,14 @@ SCHEMA: Dict[str, dict] = {
     # ``model.py::_compile_body`` / ``row_cache.py`` each HLO instruction
     # of that program belongs to; ``fn`` the wrapper's jitted function.
     # One event per program and log, not per dispatch.
+    # ``attention_core``: only for a program with ``LatentAttention``
+    # ops: how many of them run the fused Pallas core and how many the
+    # plain one, ``{"pallas": 6, "plain": 0}`` (ops/attention.py
+    # ``core_form``: the backend and the shapes decide as the program
+    # is built, so the step pays nothing).
     "program": {
         "required": {"name": str},
-        "optional": {"fn": str},
+        "optional": {"fn": str, "attention_core": dict},
     },
     # what one dispatch of FFModel.train_epoch / train_epochs counted
     # inside one op that keeps counters in its state (ops/moe.py

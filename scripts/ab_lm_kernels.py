@@ -1,15 +1,46 @@
 """On the chip: the two kernels of the language-model family at the
 shapes of ``joyai-flash-ep16.pretrain-8k``, each beside its rival.
 
-    chiprun -- python scripts/ab_lm_kernels.py [attn] [gmm] [rows] [slabs]
+    chiprun -- python scripts/ab_lm_kernels.py [attn[:row,row]] [core] [gmm] [rows] [slabs]
 
 attn: the causal attention core (32 heads, 8,192 tokens, query/key
-width 192, value width 128, bf16), forward + backward: the blockwise
-core of ``ops/attention.py`` as the program calls it against JAX's
-Pallas splash attention at the same block, with the largest differences
-between them (PR 31, v5e: blockwise 27.7 ms, splash 32.1; other blocks,
-set through ``ATTENTION_BLOCK``: 256 43.0, 1,024 56.3; splash at 1,024
-29.4: the plain core stayed).
+width 192, value width 128, bf16), forward and forward + backward, one
+row a form tried, its block sizes in the row's name (``attn:own,plain``
+runs the rows whose names hold one of the words): the plain blockwise
+core of ``ops/attention.py``; the repo's own Pallas kernels
+(``ops/pallas_attention.py``, what the program runs on a TPU) at three
+blocks; JAX's Pallas splash attention unfused and with its fused
+backward, square and rectangular blocks, ``SEQ_MINOR`` keys and values.
+Every form takes the operands it multiplies, rounded once (the plain
+core the query, the others the query times the scale, folded in f32);
+``program's entry`` is ``blockwise_causal_attention`` as
+``LatentAttention`` calls it, f32 query in, the fold and the casts
+timed with it.  Each row's largest |o, dq, dk, dv| differences from an
+f32 ``sdpa(..., causal=True)`` on its own rounded operands, over 4 of
+the 32 heads, dq in the unscaled query's units.
+PR 31, v5e, forward + backward: blockwise 27.7 ms, splash (unfused,
+square 512) 32.1; other blocks, set through ``ATTENTION_BLOCK``: 256
+43.0, 1,024 56.3; splash at 1,024 29.4: the plain core stayed.
+PR 34, v5e, forward / forward + backward ms: plain 512 8.78 / 27.68;
+OWN KERNELS 512 **6.26 / 20.55** (they ship), 256 10.71 / 26.39, 1,024
+6.44 / 20.61; the entry with the fold and casts 7.25 / 22.48; splash
+unfused 512 8.14 / 31.21, 1,024 7.60 / 28.54; splash fused 512 8.13 /
+28.52, 1,024 7.58 / 24.93; fused, forward 512/1024/512 backward
+512/1024/512 7.64 / 25.61; 1024/2048/512 and 512/2048/512 7.50 /
+25.02; 1024/1024/512 both 7.33 / 24.86 (splash's best); 512/2048/512
+and 1024/2048/512 7.63 / 24.98; 1024/2048/512 and 512/2048/512 with
+``SEQ_MINOR`` k and v 7.33 / 24.97: no splash form reached the 24.0
+the issue set.  Differences from f32 sdpa, |o, dq, dk, dv|: plain
+0.0027, 0.0090, 0.0169, 0.0133; own 0.0029, 0.0091, 0.0127, 0.0143;
+splash (every form; its output leaves in bf16) 0.0062, 0.0072, 0.0115,
+0.0143.
+core: what the program chose on this device (the cell's model compiled
+and not run; then one dense layer of it trained one step under an event
+log): the ``program`` event's ``attention_core`` and every Pallas
+kernel of the one layer's optimized HLO beside its phase (PR 34, v5e:
+``{"pallas": 6, "plain": 0}``; one ``causal_attention_fwd`` under
+``ff.lm.mla.core``, one ``causal_attention_bwd`` under ``.core.bwd``,
+none recomputed).
 gmm: the grouped matmul of the held experts (16 groups over a buffer of
 65,536 rows of which ~4,096 are assigned; 2048 -> 768), forward +
 backward, the two forms of ``ops/moe.py::grouped_matmul``:
@@ -58,72 +89,212 @@ def timed(fn, *args, n=5):
     return (time.perf_counter() - t0) / n * 1e3, out
 
 
-@functools.lru_cache(maxsize=8)
-def _splash_kernel(heads: int, seq: int, block: int):
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(heads: int, seq: int, fwd: tuple, bwd: tuple,
+                   fused: bool, seq_minor: bool):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as masks)
+    layout = ({"k_layout": kernel.QKVLayout.SEQ_MINOR,
+               "v_layout": kernel.QKVLayout.SEQ_MINOR} if seq_minor else {})
+    dq = {} if fused else {"block_q_dq": bwd[0], "block_kv_dq": bwd[1]}
     sizes = kernel.BlockSizes(
-        block_q=block, block_kv=block, block_kv_compute=block,
-        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
-        block_q_dq=block, block_kv_dq=block)
+        block_q=fwd[0], block_kv=fwd[1], block_kv_compute=fwd[2],
+        block_q_dkv=bwd[0], block_kv_dkv=bwd[1], block_kv_dkv_compute=bwd[2],
+        use_fused_bwd_kernel=fused, **dq, **layout)
     mask = masks.MultiHeadMask([masks.CausalMask((seq, seq))] * heads)
     with jax.ensure_compile_time_eval():  # its mask tables are constants
         return kernel.make_splash_mha_single_device(mask=mask,
                                                     block_sizes=sizes)
 
 
-def splash_causal_attention(q, k, v, scale: float, block: int = 512):
-    """The same function as ``blockwise_causal_attention`` through JAX's
-    Pallas splash-attention kernel (the query pre-scaled, rounded once)."""
-    run = _splash_kernel(q.shape[1], q.shape[2], block)
-    q = (q.astype(jnp.float32) * scale).astype(k.dtype)
-    return jax.vmap(run)(q, k, v).astype(jnp.float32)
+def splash(fwd, bwd, fused=True, seq_minor=False):
+    """JAX's Pallas splash attention at (block_q, block_kv,
+    block_kv_compute) forward and backward; the query comes pre-scaled."""
+    def core(q, k, v):
+        run = _splash_kernel(q.shape[1], q.shape[2], tuple(fwd), tuple(bwd),
+                             fused, seq_minor)
+        return jax.vmap(run)(q, k, v).astype(jnp.float32)
+    return core
 
 
-def attn():
-    from dlrm_flexflow_tpu.ops.attention import (
-        ATTENTION_BLOCK, blockwise_causal_attention)
-    h, s, dk, dv = 32, 8192, 192, 128
+def attn(only=(), h=32, s=8192):
+    """``only``: substrings of the rows to run (all without)."""
+    from dlrm_flexflow_tpu.ops import attention, pallas_attention
+    dk, dv = 192, 128
+    ref_heads = 4     # whose f32 logits (1 GB) the chip can hold
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(keys[0], (1, h, s, dk), jnp.bfloat16)
+    scale = dk ** -0.5
+    q32 = jax.random.normal(keys[0], (1, h, s, dk), jnp.float32)
+    # the operands each form multiplies, rounded once: the plain core
+    # takes q and scales the logits, the others take q * scale
+    q = q32.astype(jnp.bfloat16)
+    qs = (q32 * scale).astype(jnp.bfloat16)
     k = jax.random.normal(keys[1], (1, h, s, dk), jnp.bfloat16)
     v = jax.random.normal(keys[2], (1, h, s, dv), jnp.bfloat16)
     w = jax.random.normal(keys[3], (1, h, s, dv), jnp.float32)
-    scale = dk ** -0.5
     flops_fwd = 2 * s * s * h * (dk + dv) / 2
-    outs = {}
-    forms = {"blockwise": functools.partial(blockwise_causal_attention,
-                                            scale=scale),
-             "splash": functools.partial(splash_causal_attention,
-                                         scale=scale, block=ATTENTION_BLOCK)}
 
-    def measure(name, core):
+    def plain(q, k, v):
+        attention._on_tpu = lambda: False
+        return attention.blockwise_causal_attention(q, k, v, scale)
+
+    def program(q, k, v):   # as LatentAttention calls it: f32 in
+        attention._on_tpu = lambda: True
+        return attention.blockwise_causal_attention(
+            q, k, v, scale, compute_dtype=jnp.bfloat16)
+
+    def pallas(block):      # the repo's kernels at another block
+        def core(q, k, v):
+            pallas_attention.BLOCK = block
+            return attention._fused_core(q, k, v)
+        return core
+
+    # name -> (core, its query, whether the scale is folded into it)
+    forms = {
+        "plain blockwise 512": (plain, q, False),
+        "program's entry (f32 q in)": (program, q32, False),
+        "pallas own 512": (pallas(512), qs, True),
+        "pallas own 256": (pallas(256), qs, True),
+        "pallas own 1024": (pallas(1024), qs, True),
+        "splash unfused 512/512/512": (
+            splash((512,) * 3, (512,) * 3, fused=False), qs, True),
+        "splash unfused 1024/1024/1024": (
+            splash((1024,) * 3, (1024,) * 3, fused=False), qs, True),
+        "splash fused 512/512/512": (
+            splash((512,) * 3, (512,) * 3), qs, True),
+        "splash fused 1024/1024/1024": (
+            splash((1024,) * 3, (1024,) * 3), qs, True),
+        "splash fused fwd 512/1024/512 bwd 512/1024/512": (
+            splash((512, 1024, 512), (512, 1024, 512)), qs, True),
+        "splash fused fwd 1024/2048/512 bwd 512/2048/512": (
+            splash((1024, 2048, 512), (512, 2048, 512)), qs, True),
+        "splash fused fwd 1024/1024/512 bwd 1024/1024/512": (
+            splash((1024, 1024, 512), (1024, 1024, 512)), qs, True),
+        "splash fused fwd 512/2048/512 bwd 1024/2048/512": (
+            splash((512, 2048, 512), (1024, 2048, 512)), qs, True),
+        "splash fused fwd 1024/2048/512 bwd 512/2048/512 k,v SEQ_MINOR": (
+            splash((1024, 2048, 512), (512, 2048, 512), seq_minor=True),
+            qs, True),
+    }
+    if only:
+        forms = {n: f for n, f in forms.items()
+                 if any(part in n for part in only)}
+
+    def reference(folded):
+        """f32 ``sdpa`` over the first heads on the operands the form
+        multiplies: output and the three gradients."""
+        qr = (qs if folded else q)[:, :ref_heads].astype(jnp.float32)
+        args = (qr, k[:, :ref_heads].astype(jnp.float32),
+                v[:, :ref_heads].astype(jnp.float32))
+
+        def full(q, k, v):
+            with jax.default_matmul_precision("highest"):
+                return attention.sdpa(q, k, v, causal=True,
+                                      scale=1.0 if folded else scale)
+        def loss(*operands):
+            o = full(*operands)
+            return jnp.sum(o * w[:, :ref_heads]), o
+        both = jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
+        (_, o), grads = both(*args)
+        return [o, *grads]
+
+    refs = {}
+
+    def measure(name, core, query, folded):
         fwd = jax.jit(lambda q, k, v: core(q, k, v))
         both = jax.jit(jax.value_and_grad(
             lambda q, k, v: jnp.sum(core(q, k, v) * w), (0, 1, 2)))
-        ms_f, o = timed(fwd, q, k, v)
-        ms_b, (_, grads) = timed(both, q, k, v)
-        outs[name] = (o, grads)
+        ms_f, o = timed(fwd, query, k, v)
+        ms_b, (_, grads) = timed(both, query, k, v)
         print(f"attn {name}: fwd {ms_f:.2f} ms ({flops_fwd / ms_f / 1e9:.1f}"
               f" TFLOP/s causal), fwd+bwd {ms_b:.2f} ms "
               f"({3.5 * flops_fwd / ms_b / 1e9:.1f} TFLOP/s)", flush=True)
+        if query is q32:    # its gradient is the plain query's, in f32
+            return
+        if folded not in refs:
+            refs[folded] = reference(folded)
+        # dq in the unscaled query's units whichever operand the form took
+        units = [1.0, scale if folded else 1.0, 1.0, 1.0]
+        errs = [float(jnp.max(jnp.abs(got[:, :ref_heads].astype(jnp.float32)
+                                      - want))) * unit
+                for got, want, unit in zip([o, *grads], refs[folded], units)]
+        print(f"attn {name}: max |o, dq, dk, dv| difference from f32 sdpa "
+              f"on its own rounded operands, {ref_heads} heads: "
+              + ", ".join(f"{e:.4g}" for e in errs), flush=True)
 
-    for name, core in forms.items():
+    for name, (core, query, folded) in forms.items():
         try:
-            measure(name, core)
+            measure(name, core, query, folded)
         except Exception as e:  # a form the compiler refuses is a finding
             print(f"attn {name}: FAILED {type(e).__name__}: "
                   f"{str(e)[:600]}", flush=True)
-    if "blockwise" in outs:
-        base_o, base_g = outs["blockwise"]
-        for name, (o, grads) in outs.items():
-            errs = [float(jnp.max(jnp.abs(o.astype(jnp.float32) - base_o)))]
-            errs += [float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                           - b.astype(jnp.float32))))
-                     for a, b in zip(grads, base_g)]
-            print(f"attn {name} vs blockwise: max |do, dq, dk, dv| diff "
-                  f"{errs}", flush=True)
 
+
+def core():
+    """What the program chose on this device, and what one layer
+    compiles to: the cell's model built and compiled (never initialised
+    or run: ``FFModel.compile`` knows the count its ``program`` events
+    carry), then one dense decoder layer of the cell with the embedding
+    and head (one ``LatentAttention``, recomputed as in the cell) trained
+    one step under an event log: its ``program`` event, and every Pallas
+    kernel of the optimized HLO beside its phase."""
+    import dataclasses
+    import json
+
+    from benchmarks.models import mla_moe_lm as family
+    from dlrm_flexflow_tpu import profiling
+    from dlrm_flexflow_tpu.apps import mla_moe_lm as app
+    from dlrm_flexflow_tpu.config import FFConfig
+    from dlrm_flexflow_tpu.telemetry import event_log
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks/configs/joyai-flash-ep16.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmarks/traffic/pretrain-8k.json")) as f:
+        traffic = json.load(f)
+
+    def compiled(cfg):
+        fc = FFConfig(batch_size=traffic["batch"])
+        for key, value in config["ffconfig"].items():
+            setattr(fc, key, value)
+        model = app.build(cfg, fc)
+        model.compile(optimizer=app.optimizer(cfg),
+                      loss_type=app.token_loss, metrics=(), mesh=False)
+        return model
+
+    cfg = family.model_config(config, traffic)
+    print(f"core: the cell's model, compiled and not run: "
+          f"{compiled(cfg)._program_fields}", flush=True)
+    model = compiled(dataclasses.replace(cfg, num_hidden_layers=1,
+                                         num_nextn_predict_layers=0))
+    init = jax.jit(lambda: model.init(seed=0))
+    state = init()
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, size=(1, traffic["batch"], cfg.seq_len + 1))
+    inputs = {"ids": tokens[..., :-1].astype(np.int32)}
+    labels = tokens[..., 1:, None].astype(np.int32)
+    with event_log() as log:
+        state, mets = model.train_epochs(state, inputs, labels, 1)
+        print(f"core: one layer, one step: loss "
+              f"{float(np.asarray(mets['loss'])[0]):.4f}", flush=True)
+        events = log.events("program")
+    print(f"core: one layer's program events: "
+          f"{[{k: e[k] for k in ('name', 'attention_core')} for e in events]}",
+          flush=True)
+    prog = profiling._programs[events[-1]["name"]]
+    text = prog.fn().lower(*prog.args).compile().as_text()
+    phases = profiling.hlo_phases(text)
+    kernels = [m.group(1) for m in map(profiling._INSTRUCTION.match,
+                                       text.splitlines())
+               if m and "tpu_custom_call" in m.string]
+    print(f"core: Pallas kernels of the optimized HLO by phase: "
+          f"{[(name, phases.get(name)) for name in kernels]}", flush=True)
+    per_scope = {}
+    for name, phase in phases.items():
+        if phase.startswith("ff.lm.mla.core"):
+            per_scope[phase] = per_scope.get(phase, 0) + 1
+    print(f"core: instructions under ff.lm.mla.core by phase: {per_scope}",
+          flush=True)
 
 def gmm(m=65536):
     from dlrm_flexflow_tpu.ops import moe as moe_ops
@@ -311,7 +482,10 @@ if __name__ == "__main__":
     print(f"device: {jax.devices()[0].device_kind}", flush=True)
     which = sys.argv[1:] or ["attn", "gmm", "rows", "slabs"]
     for name in which:
-        {"attn": attn, "gmm": gmm, "rows": rows, "slabs": slabs}[name]()
+        name, _, only = name.partition(":")   # attn:pallas,plain
+        {"attn": attn, "core": core, "gmm": gmm, "rows": rows,
+         "slabs": slabs}[name](
+            *([only.split(",")] if only else []))
         if name == "gmm":   # and over one slab of the layer (PR 32)
             from dlrm_flexflow_tpu.ops.moe import slab_rows
             gmm(slab_rows(65536, 16, 256))

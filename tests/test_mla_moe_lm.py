@@ -98,6 +98,159 @@ def test_blockwise_core_takes_a_block_that_does_not_divide():
         sdpa(q, q, q, causal=True), atol=2e-6)
 
 
+@pytest.fixture
+def kernels_interpreted(monkeypatch):
+    """The fused core's choice and kernels as on a TPU, the kernels run
+    by the Pallas interpreter."""
+    from dlrm_flexflow_tpu.ops import pallas_attention
+    real = pallas_attention.pl.pallas_call
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        pallas_attention.pl, "pallas_call",
+        lambda *a, **kw: real(*a, **dict(kw, interpret=True)))
+    return pallas_attention
+
+
+def _published_widths(seq, dtype, heads=2):
+    """q (f32, as the layer hands it over), k, v at 192 / 128 wide."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(keys[0], (1, heads, seq, 192))
+    k = jax.random.normal(keys[1], (1, heads, seq, 192)).astype(dtype)
+    v = jax.random.normal(keys[2], (1, heads, seq, 128)).astype(dtype)
+    return q, k, v, jax.random.normal(keys[3], (1, heads, seq, 128))
+
+
+def _pallas_calls(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
+
+
+@pytest.mark.parametrize("dtype,o_tol,g_tol", [
+    ("float32", 2e-6, 2e-5), ("bfloat16", 8e-3, 4e-2)])
+def test_fused_core_is_the_full_softmax_core_and_the_plain_one(
+        dtype, o_tol, g_tol, kernels_interpreted, monkeypatch):
+    """The Pallas kernels (interpret mode) at widths they take: 2 heads,
+    4 blocks, 192 / 128 wide: output and all three gradients against
+    ``sdpa(..., causal=True)`` in f32 on the operands the kernels
+    multiply (the scale folded into the query before its one rounding),
+    and against the plain core, whose query is rounded before the
+    scale."""
+    dtype = jnp.dtype(dtype)
+    seq = 4 * kernels_interpreted.BLOCK
+    q, k, v, w = _published_widths(seq, dtype)
+    scale = 192 ** -0.5
+    core = lambda q, k, v: blockwise_causal_attention(
+        q, k, v, scale, compute_dtype=dtype)
+    out = lambda f, *a: (f(*a), *jax.grad(
+        lambda *a: jnp.sum(f(*a) * w), (0, 1, 2))(*a))
+    assert _pallas_calls(core, q, k, v) == 1
+    got = out(core, q, k, v)
+    assert got[0].dtype == jnp.float32 and got[2].dtype == dtype
+
+    def full(q, k, v):   # q: what the kernels multiply, in f32
+        with jax.default_matmul_precision("highest"):
+            return sdpa(q, k.astype(F32), v.astype(F32), causal=True,
+                        scale=1.0)
+    folded = (q * scale).astype(dtype).astype(F32)
+    want = out(full, folded, k, v)
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: False)
+    monkeypatch.setattr(attention_ops, "ATTENTION_BLOCK", 512)
+    plain_core = lambda q, k, v: blockwise_causal_attention(   # a new trace
+        q, k, v, scale, compute_dtype=dtype)
+    assert _pallas_calls(plain_core, q, k, v) == 0
+    plain = out(plain_core, q, k, v)
+    for name, g, t, p, tol, unit in zip(
+            ("o", "dq", "dk", "dv"), got, want, plain,
+            (o_tol, g_tol, g_tol, g_tol), (1.0, scale, 1.0, 1.0)):
+        # the reference's dq is the folded query's: scale times the query's
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(t, np.float32) * unit,
+                                   atol=tol, err_msg=name)
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(p, np.float32),
+                                   atol=2 * tol, err_msg=name + " / plain")
+
+
+@pytest.mark.parametrize("on_tpu,seq,qk,vd,form", [
+    (True, 2048, 192, 128, "pallas"),
+    (True, 2048 + 256, 192, 128, "plain"),   # the blocks do not divide S
+    (True, 12, 192, 128, "plain"),
+    (True, 2048, 24, 16, "plain"),           # widths Mosaic does not take
+    (False, 2048, 192, 128, "plain"),        # another backend
+])
+def test_the_core_form_is_chosen_from_backend_and_shapes(
+        on_tpu, seq, qk, vd, form, monkeypatch):
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: on_tpu)
+    assert attention_ops.core_form(seq, qk, vd, jnp.bfloat16) == form
+    q = jax.ShapeDtypeStruct((1, 2, seq, qk), jnp.float32)
+    v = jax.ShapeDtypeStruct((1, 2, seq, vd), jnp.float32)
+    calls = _pallas_calls(
+        lambda q, k, v: blockwise_causal_attention(
+            q, k, v, compute_dtype=jnp.bfloat16), q, q, v)
+    assert calls == (form == "pallas")
+
+
+def test_a_sequence_the_blocks_do_not_divide_runs_the_plain_core(
+        monkeypatch):
+    """On a TPU's choice, 192 / 128 wide, S = 2.5 blocks: the plain core
+    runs (no kernel in the jaxpr) and is still the full softmax core."""
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention_ops, "ATTENTION_BLOCK", 512)
+    q, k, v, _ = _published_widths(1280, F32, heads=1)
+    core = lambda q, k, v: blockwise_causal_attention(q, k, v)
+    assert attention_ops.core_form(1280, 192, 128, F32) == "plain"
+    assert _pallas_calls(core, q, k, v) == 0
+    np.testing.assert_allclose(core(q, k, v), sdpa(q, k, v, causal=True),
+                               atol=2e-6)
+
+
+def _program_events(model, state, inputs, labels):
+    from dlrm_flexflow_tpu.telemetry import event_log
+    with event_log() as log:
+        model.train_epoch(state, inputs, labels)
+    return [e for e in log.events() if e["type"] == "program"]
+
+
+def test_the_program_event_counts_the_attention_cores():
+    """On the CPU backend, no interpreter: the tiny model's three
+    ``LatentAttention`` ops (two layers and the MTP module) all run the
+    plain core and the ``program`` event says so; a model without such
+    an op carries no such field."""
+    cfg = _small()
+    model, state = _compiled(cfg)
+    inputs, labels = family._split(_tokens(cfg, 2))
+    events = _program_events(model, state, inputs, labels)
+    assert [e["attention_core"] for e in events] \
+        == [{"pallas": 0, "plain": 3}]
+    assert [op.core_form() for op in model.layers
+            if isinstance(op, LatentAttention)] == ["plain"] * 3
+    from dlrm_flexflow_tpu.telemetry.schema import validate_event
+    assert validate_event(events[0]) == []
+
+
+def test_a_recomputed_layer_runs_the_forward_kernel_once(monkeypatch):
+    """The training step of a recomputed model at the published head
+    widths, as a TPU would trace it: one forward and one backward kernel
+    for each of the three ``LatentAttention`` ops and no third: the
+    recomputation keeps the core's output and log-sum-exp
+    (``saved_in_recompute``), so the backward holds no second forward
+    kernel; the ``program`` event would read ``pallas: 3``."""
+    monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
+    cfg = _small(num_attention_heads=2, qk_nope_head_dim=128,
+                 qk_rope_head_dim=64, v_head_dim=128, seq_len=2048)
+    assert cfg.recompute
+    model, state = _compiled(cfg, batch=1)
+    assert model._program_fields == {
+        "attention_core": {"pallas": 3, "plain": 0}}
+    inputs, labels = family._split(_tokens(cfg, 1, batch=1))
+    text = str(jax.make_jaxpr(model._train_step)(
+        state, {k: v[0] for k, v in inputs.items()}, labels[0]))
+    assert text.count("causal_attention_fwd") == 3
+    assert text.count("causal_attention_bwd") == 3
+    assert text.count("pallas_call") == 6
+    for name in attention_ops.CORE_SAVED:
+        assert text.count("name=" + name) == 3, name
+
+
 @pytest.mark.parametrize("block", [8, 32])
 def test_latent_attention_is_the_references(block, monkeypatch):
     """The op against ``ref.mla``: low-rank query and key/value paths
