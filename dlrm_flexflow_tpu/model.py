@@ -365,18 +365,21 @@ class FFModel:
     def held_experts_moe(self, input_tensor, num_experts, hidden_dim, top_k,
                          held=None, num_shared=0, scaling=1.0,
                          bias_update_speed=0.0, kernel_initializer=None,
-                         name=None):
+                         name=None, score_func="sigmoid",
+                         shared_gated=False):
         from .ops.moe import HeldExpertsMoE
         op = HeldExpertsMoE(self._name("moe", name), input_tensor,
                             num_experts, hidden_dim, top_k, held, num_shared,
                             scaling, bias_update_speed, kernel_initializer,
-                            self._op_compute_dtype())
+                            self._op_compute_dtype(), score_func,
+                            shared_gated)
         return self._add(op)
 
-    def rms_norm(self, input_tensor, eps=1e-6, name=None):
+    def rms_norm(self, input_tensor, eps=1e-6, name=None,
+                 zero_centred=False):
         from .ops.transformer import RMSNorm
         return self._add(RMSNorm(self._name("rms_norm", name), input_tensor,
-                                 eps))
+                                 eps, zero_centred))
 
     def gated_ffn(self, input_tensor, hidden_dim, kernel_initializer=None,
                   name=None):
@@ -396,6 +399,26 @@ class FFModel:
                              kv_lora_rank, qk_nope_head_dim,
                              qk_rope_head_dim, v_head_dim, rope_theta, eps,
                              kernel_initializer, self._op_compute_dtype())
+        return self._add(op)
+
+    def gated_attention(self, input_tensor, num_heads, num_kv_heads,
+                        head_dim, rotary_dim, rope_theta=10000.0, eps=1e-6,
+                        kernel_initializer=None, name=None):
+        from .ops.attention import GatedAttention
+        op = GatedAttention(self._name("gated_attention", name),
+                            input_tensor, num_heads, num_kv_heads, head_dim,
+                            rotary_dim, rope_theta, eps, kernel_initializer,
+                            self._op_compute_dtype())
+        return self._add(op)
+
+    def gated_delta_net(self, input_tensor, num_k_heads, num_v_heads,
+                        head_k_dim, head_v_dim, conv_kernel=4, eps=1e-6,
+                        kernel_initializer=None, name=None):
+        from .ops.deltanet import GatedDeltaNet
+        op = GatedDeltaNet(self._name("gated_delta_net", name), input_tensor,
+                           num_k_heads, num_v_heads, head_k_dim, head_v_dim,
+                           conv_kernel, eps, kernel_initializer,
+                           self._op_compute_dtype())
         return self._add(op)
 
     def dropout(self, input_tensor, rate=0.5, seed=0, name=None):
@@ -865,13 +888,15 @@ class FFModel:
                         if hasattr(op, "step_metrics")]
         self._counting_ops = [op.name for op in counting_ops]
         # what the ``program`` events of this model's programs carry
-        # beside their name: how many attention cores took which form
-        # (ops/attention.py::core_form; shapes and backend, so known here)
-        forms = [op.core_form() for op in self.layers
-                 if hasattr(op, "core_form")]
-        self._program_fields = {"attention_core": {
-            form: forms.count(form) for form in ("pallas", "plain")}} \
-            if forms else {}
+        # beside their name: how many attention cores (``attention_core``)
+        # and DeltaNet cores (``gdn_core``) took which form (the op's
+        # ``core_form``; shapes and backend, so known here)
+        self._program_fields = {}
+        for op in self.layers:
+            if hasattr(op, "core_form"):
+                counts = self._program_fields.setdefault(
+                    op.core_field, dict.fromkeys(op.core_forms, 0))
+                counts[op.core_form()] += 1
         counter_ranks: Dict[str, int] = {}  # filled as train_step is traced
 
         # ---- sparse embedding update fast path ---------------------------
@@ -1610,7 +1635,7 @@ class FFModel:
 
     def _note_program(self, log, fn, args: tuple) -> str:
         """``profiling.note_program`` with what this model's ``program``
-        events carry beside the name (``attention_core``)."""
+        events carry beside the name (``attention_core``, ``gdn_core``)."""
         return note_program(log, fn, args, **self._program_fields)
 
     def _emit_op_counters(self, log, mets, fn: str):

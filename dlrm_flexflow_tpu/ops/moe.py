@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from ..initializers import DEFAULT_KERNEL_INIT, ZeroInitializer
 from ..tensor import ParameterSpec
-from .base import Op
+from .base import Op, matmul
 from .transformer import swiglu
 
 
@@ -296,6 +296,10 @@ class HeldExpertsMoE(Op):
     ``sum over selected AND held experts of gate * expert(x)``, plus the
     shared experts; what the absent experts would add is left out (one
     chip's share of an expert-parallel layer, without its exchange).
+    ``score_func="softmax"``: ``s = softmax(x W_r)`` over all experts
+    (Qwen3-Next's router; with ``bias_update_speed`` 0 the bias stays 0
+    and there is no bias rule).  ``shared_gated``: the shared experts'
+    output times ``sigmoid(x w_s)``, ``w_s`` (d, 1) a parameter.
 
     No assignment to a held expert is dropped, and none is computed
     twice: a stable sort puts the assignments to held experts first,
@@ -340,8 +344,12 @@ class HeldExpertsMoE(Op):
     def __init__(self, name, input_tensor, num_experts: int, hidden_dim: int,
                  top_k: int, held=None, num_shared: int = 0,
                  scaling: float = 1.0, bias_update_speed: float = 0.0,
-                 kernel_initializer=None, compute_dtype=None):
+                 kernel_initializer=None, compute_dtype=None,
+                 score_func: str = "sigmoid", shared_gated: bool = False):
         super().__init__(name, [input_tensor])
+        assert score_func in ("sigmoid", "softmax"), score_func
+        self.score_func = score_func
+        self.shared_gated = bool(shared_gated)
         self.num_experts = int(num_experts)
         first, count = held if held is not None else (0, self.num_experts)
         self.first_held, self.num_held = int(first), int(count)
@@ -380,6 +388,9 @@ class HeldExpertsMoE(Op):
                               initializer=init, sharded_dim=1),
                 ParameterSpec(self.name, "shared_down", (hs, d),
                               initializer=init, sharded_dim=0)]
+            if self.shared_gated:
+                specs.append(ParameterSpec(self.name, "shared_sigmoid",
+                                           (d, 1), initializer=init))
         return specs
 
     def init_state(self):
@@ -403,7 +414,10 @@ class HeldExpertsMoE(Op):
         would move the selection)."""
         logits = jnp.matmul(x.astype(jnp.float32), router,
                             precision=jax.lax.Precision.HIGHEST)
-        scores = jax.nn.sigmoid(logits)
+        if self.score_func == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            scores = jax.nn.sigmoid(logits)
         _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias),
                                self.top_k)
         picked = jnp.take_along_axis(scores, idx, axis=-1)
@@ -444,9 +458,12 @@ class HeldExpertsMoE(Op):
             group_sizes)
         if self.num_shared:
             with jax.named_scope(scope + ".shared"):
-                out = out + swiglu(x, params["shared_gate"],
-                                   params["shared_up"],
-                                   params["shared_down"], self.compute_dtype)
+                shared = swiglu(x, params["shared_gate"], params["shared_up"],
+                                params["shared_down"], self.compute_dtype)
+                if self.shared_gated:
+                    shared = shared * jax.nn.sigmoid(matmul(
+                        x, params["shared_sigmoid"], self.compute_dtype))
+                out = out + shared
         new_state = state
         if training:
             with jax.named_scope(scope + ".route"):

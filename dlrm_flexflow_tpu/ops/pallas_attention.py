@@ -28,6 +28,14 @@ matmuls a tile, the probabilities rebuilt as ``exp(logits - lse)``;
 ``dk`` / ``dv`` of the block accumulate in scratch, ``dq`` in the head's
 accumulator, each rounded once as it leaves.
 
+Grouped heads (PR 35): with fewer key/value heads than query heads
+both kernels fetch a group's key/value head by ``h // group`` (once for
+the whole group forward, since the block does not change between the
+group's programs), and the backward writes each query head's ``dk`` and
+``dv`` in f32 for a sum over the group behind the kernel.  v5e, 16 on 2
+heads x 16,384 x 256, bf16: forward 14.20 ms, forward + backward 47.45
+(``ab_lm_kernels.py gqa``).
+
 v5e, 32 heads x 8,192 tokens x 192 / 128, bf16 (``scripts/
 ab_lm_kernels.py attn``; PERF.md section 6, PR 34 has every form
 tried): forward 6.26 ms, forward + backward 20.55, where the plain core
@@ -164,13 +172,17 @@ def _params():
 
 
 def forward(q, k, v):
-    """``q``, ``k``: (N, S, Dk), the scale folded into ``q``; ``v``:
-    (N, S, Dv); N = batch x heads.  Returns ``(o (N, S, Dv) f32, lse
-    (N, S) f32)``."""
+    """``q``: (N, S, Dk), the scale folded in; ``k``: (M, S, Dk);
+    ``v``: (M, S, Dv); N = batch x heads, M = batch x key/value heads
+    dividing N: query head ``h`` reads key/value head ``h // (N / M)``,
+    which is fetched once for the whole group (its block index does not
+    change between the group's programs) and never written N / M times.
+    Returns ``(o (N, S, Dv) f32, lse (N, S) f32)``."""
     n, s, dk = q.shape
     dv = v.shape[-1]
+    group = n // k.shape[0]
     whole = lambda width: pl.BlockSpec((None, s, width),
-                                       lambda h, i: (h, 0, 0))
+                                       lambda h, i: (h // group, 0, 0))
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block=BLOCK),
         grid=(n, s // BLOCK),
@@ -191,26 +203,37 @@ def forward(q, k, v):
 
 def backward(q, k, v, o, lse, do):
     """The three gradients from the saved output and log-sum-exp, in the
-    operands' dtype.  ``o``, ``do``: (N, S, Dv) f32; ``lse``: (N, S)."""
+    operands' dtype.  ``o``, ``do``: (N, S, Dv) f32; ``lse``: (N, S).
+    With grouped heads (``k``, ``v``: (M, S, .), M dividing N) a key
+    block is read from its own head, each query head's ``dk`` and ``dv``
+    leave the kernel in f32 and the group's are summed behind it."""
     n, s, dk = q.shape
     dv = v.shape[-1]
+    group = n // k.shape[0]
     delta = jnp.sum(do * o, axis=-1).reshape(n, 1, s)
     whole = lambda width: pl.BlockSpec((None, s, width),
                                        lambda h, j: (h, 0, 0))
     block = lambda width: pl.BlockSpec((None, BLOCK, width),
                                        lambda h, j: (h, j, 0))
+    shared = lambda width: pl.BlockSpec((None, BLOCK, width),
+                                        lambda h, j: (h // group, j, 0))
     row = pl.BlockSpec((None, 1, s), lambda h, j: (h, 0, 0))
-    return pl.pallas_call(
+    per_head = k.dtype if group == 1 else jnp.float32
+    dq, dk_, dv_ = pl.pallas_call(
         functools.partial(_bwd_kernel, block=BLOCK, blocks=s // BLOCK),
         grid=(n, s // BLOCK),
-        in_specs=[whole(dk), block(dk), block(dv), whole(dv), row, row],
+        in_specs=[whole(dk), shared(dk), shared(dv), whole(dv), row, row],
         out_specs=[whole(dk), block(dk), block(dv)],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+                   jax.ShapeDtypeStruct((n, s, dk), per_head),
+                   jax.ShapeDtypeStruct((n, s, dv), per_head)],
         scratch_shapes=[pltpu.VMEM((s, dk), jnp.float32),
                         pltpu.VMEM((BLOCK, dk), jnp.float32),
                         pltpu.VMEM((BLOCK, dv), jnp.float32)],
         compiler_params=_params(),
         name="causal_attention_bwd",
     )(q, k, v, do.astype(q.dtype), lse.reshape(n, 1, s), delta)
+    if group > 1:
+        dk_, dv_ = (jnp.sum(x.reshape((n // group, group) + x.shape[1:]),
+                            axis=1).astype(k.dtype) for x in (dk_, dv_))
+    return dq, dk_, dv_

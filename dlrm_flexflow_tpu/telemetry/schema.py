@@ -361,13 +361,15 @@ SCHEMA: Dict[str, dict] = {
     # of that program belongs to; ``fn`` the wrapper's jitted function.
     # One event per program and log, not per dispatch.
     # ``attention_core``: only for a program with ``LatentAttention``
-    # ops: how many of them run the fused Pallas core and how many the
-    # plain one, ``{"pallas": 6, "plain": 0}`` (ops/attention.py
-    # ``core_form``: the backend and the shapes decide as the program
-    # is built, so the step pays nothing).
+    # or ``GatedAttention`` ops: how many of them run the fused Pallas
+    # core and how many the plain one, ``{"pallas": 6, "plain": 0}``
+    # (ops/attention.py ``core_form``: the backend and the shapes decide
+    # as the program is built, so the step pays nothing).
+    # ``gdn_core``: the like count for ``GatedDeltaNet`` ops
+    # (ops/deltanet.py ``core_form``), ``{"chunked": 3}``.
     "program": {
         "required": {"name": str},
-        "optional": {"fn": str, "attention_core": dict},
+        "optional": {"fn": str, "attention_core": dict, "gdn_core": dict},
     },
     # what one dispatch of FFModel.train_epoch / train_epochs counted
     # inside one op that keeps counters in its state (ops/moe.py

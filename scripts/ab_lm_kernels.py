@@ -1,7 +1,7 @@
 """On the chip: the two kernels of the language-model family at the
 shapes of ``joyai-flash-ep16.pretrain-8k``, each beside its rival.
 
-    chiprun -- python scripts/ab_lm_kernels.py [attn[:row,row]] [core] [gmm] [rows] [slabs]
+    chiprun -- python scripts/ab_lm_kernels.py [attn[:row,row]] [core] [gmm] [rows] [slabs] [gdn[:row,row]] [gqa[:row,row]]
 
 attn: the causal attention core (32 heads, 8,192 tokens, query/key
 width 192, value width 128, bf16), forward and forward + backward, one
@@ -61,8 +61,25 @@ held, no shared expert, bf16), forward + backward, under a bias on the
 held experts that sends them about 4 k, more than one slab, and all
 65,536 assignments: in slabs of ``SHARES`` even shares, of 4, and in
 one slab of all rows (``SHARES`` = 16), with the slabs each took and the
-largest differences between the first and the last.  Prints ms per call; nothing here is read
-by the benchmark.
+largest differences between the first and the last.
+gdn (PR 35): the Gated DeltaNet rule at the shapes of
+``qwen3-next-ep16.pretrain-16k`` (16 key heads on 32 value heads,
+16,384 tokens, 128 wide, bf16), forward and forward + backward, one row
+a form: ``ops/deltanet.py::gated_delta_rule`` as the program runs it
+(chunks of 64, the hand-written backward over the chunks' boundary
+states) and at chunks of 32 and 128; the same chunks differentiated by
+JAX (no ``custom_vjp``: the scan keeps what it likes); ``T`` by
+``jax.scipy.linalg.solve_triangular`` instead of the block-doubling
+inverse.  Each row's largest |o, dq, dk, dv, dg, dbeta| differences from
+the token-by-token recurrence in f32 on the same rounded operands, over
+the first 2,048 tokens of 2 key heads.  PERF.md section 6, PR 35 has the
+numbers.
+gqa (PR 35): grouped-head attention at 16 query heads on 2 key/value
+heads, 16,384 tokens, 256 wide, bf16: the repo's kernels reading each
+group's key/value head in place (what the program runs), the same
+kernels on k and v written out eight times, and the plain core; each
+row's differences from f32 ``sdpa`` over 2 query heads.
+Prints ms per call; nothing here is read by the benchmark.
 """
 
 from __future__ import annotations
@@ -478,13 +495,174 @@ def slabs():
                   flush=True)
 
 
+def _run_rows(tag, forms, only, measure):
+    for name, form in forms.items():
+        if only and not any(part in name for part in only):
+            continue
+        try:
+            measure(name, form)
+        except Exception as e:  # a form the compiler refuses is a finding
+            print(f"{tag} {name}: FAILED {type(e).__name__}: "
+                  f"{str(e)[:600]}", flush=True)
+
+
+def gdn(only=(), hk=16, hv=32, s=16384, d=128):
+    from benchmarks.reference import gdn_moe_lm_ref as ref
+    from dlrm_flexflow_tpu.ops import deltanet
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
+    bf = jnp.bfloat16
+    q = (deltanet.l2_normalised(jax.random.normal(keys[0], (1, s, hk, d)))
+         * d ** -0.5).astype(bf)
+    k = deltanet.l2_normalised(jax.random.normal(keys[1],
+                                                 (1, s, hk, d))).astype(bf)
+    v = (0.5 * jax.random.normal(keys[2], (1, s, hv, d))).astype(bf)
+    # the released initialisation's decays: A = U(0, 16), dt_bias = 1
+    a = jax.random.uniform(keys[3], (hv,), minval=1e-3, maxval=16.0)
+    g = -a * jax.nn.softplus(jax.random.normal(keys[4], (1, s, hv)) + 1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (1, s, hv)))
+    w = jax.random.normal(keys[6], (1, s, hv, d), jnp.float32)
+    args = (q, k, v, g, beta)
+    flops = 3 * s * hv * 6 * d * d
+
+    def program(chunk):
+        def rule(*xs):
+            deltanet.CHUNK = chunk
+            return deltanet.gated_delta_rule(*xs, compute_dtype=bf)
+        return rule
+
+    def autodiff(*xs):      # the same chunks, differentiated by JAX
+        deltanet.CHUNK = 64
+        operands = deltanet._chunk_operands(
+            *deltanet._laid_out(*xs, 64), bf)
+        return deltanet._tokens_first(deltanet._scan_chunks(operands, bf)[0])
+
+    def solve(*xs):         # T by a triangular solve
+        from jax.scipy.linalg import solve_triangular
+        real = deltanet.unit_lower_inverse
+        eye = jnp.eye(64, dtype=jnp.float32)
+        deltanet.unit_lower_inverse = lambda a: solve_triangular(
+            eye + a, jnp.broadcast_to(eye, a.shape), lower=True,
+            unit_diagonal=True)
+        try:
+            return program(64)(*xs)
+        finally:
+            deltanet.unit_lower_inverse = real
+
+    forms = {"program: chunks of 64, own backward": program(64),
+             "chunks of 32, own backward": program(32),
+             "chunks of 128, own backward": program(128),
+             "chunks of 64, differentiated by JAX": autodiff,
+             "chunks of 64, T by solve_triangular": solve}
+    heads, tokens = 2, 2048      # of the key heads, for the recurrence
+    part = (q[:, :tokens, :heads], k[:, :tokens, :heads],
+            v[:, :tokens, :2 * heads], g[:, :tokens, :2 * heads],
+            beta[:, :tokens, :2 * heads])
+    w_part = w[:, :tokens, :2 * heads]
+
+    def recurrence(q, k, v, g, beta):
+        q, k = (jnp.repeat(x.astype(jnp.float32), 2, axis=2) for x in (q, k))
+        with jax.default_matmul_precision("highest"):
+            return jax.vmap(ref.delta_rule)(q, k, v.astype(jnp.float32), g,
+                                            beta)
+    grads_of = lambda f, w: jax.jit(jax.value_and_grad(
+        lambda *xs: (lambda o: (jnp.sum(o * w), o))(f(*xs)),
+        tuple(range(5)), has_aux=True))
+    (_, o_ref), g_ref = grads_of(recurrence, w_part)(*part)
+
+    def measure(name, rule):
+        fwd = jax.jit(rule)
+        both = jax.jit(jax.value_and_grad(
+            lambda *xs: jnp.sum(rule(*xs) * w), tuple(range(5))))
+        ms_f, _ = timed(fwd, *args)
+        ms_b, _ = timed(both, *args)
+        print(f"gdn {name}: fwd {ms_f:.2f} ms, fwd+bwd {ms_b:.2f} ms "
+              f"({flops / ms_b / 1e9:.2f} TFLOP/s of the recurrence's "
+              f"own work)", flush=True)
+        (_, o), grads = grads_of(rule, w_part)(*part)
+        errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                      - b.astype(jnp.float32))))
+                for a, b in zip([o, *grads], [o_ref, *g_ref])]
+        sizes = [float(jnp.max(jnp.abs(b))) for b in [o_ref, *g_ref]]
+        print(f"gdn {name}: max |o, dq, dk, dv, dg, dbeta| difference from "
+              f"the token recurrence, {tokens} tokens x {heads} key heads: "
+              + ", ".join(f"{e:.3g}" for e in errs) + " (of "
+              + ", ".join(f"{x:.3g}" for x in sizes) + ")", flush=True)
+
+    _run_rows("gdn", forms, only, measure)
+    deltanet.CHUNK = 64
+
+
+def gqa(only=(), h=16, kv=2, s=16384, d=256):
+    from dlrm_flexflow_tpu.ops import attention
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    scale = d ** -0.5
+    q32 = jax.random.normal(keys[0], (1, h, s, d), jnp.float32)
+    k = jax.random.normal(keys[1], (1, kv, s, d), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (1, kv, s, d), jnp.bfloat16)
+    w = jax.random.normal(keys[3], (1, h, s, d), jnp.float32)
+    flops_fwd = 2 * s * s * h * 2 * d / 2
+
+    def entry(tpu, repeated):
+        def form(q, k, v):
+            attention._on_tpu = lambda: tpu
+            if repeated:
+                k, v = (jnp.repeat(x, q.shape[1] // x.shape[1], axis=1)
+                        for x in (k, v))
+            return attention.blockwise_causal_attention(
+                q, k, v, scale, compute_dtype=jnp.bfloat16)
+        return form
+
+    forms = {"program: kernels, a group's k and v read in place":
+             entry(True, False),
+             "kernels, k and v written out eight times": entry(True, True),
+             "plain blockwise 512 (repeats k and v)": entry(False, False)}
+    ref_heads = 2            # of group 0: 2 GB of f32 logits
+
+    def full(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            folded = (q * scale).astype(jnp.bfloat16).astype(jnp.float32)
+            rep = lambda x: jnp.repeat(x.astype(jnp.float32), ref_heads,
+                                       axis=1)
+            return attention.sdpa(folded, rep(k), rep(v), causal=True,
+                                  scale=1.0)
+    ref_args = (q32[:, :ref_heads], k[:, :1], v[:, :1])
+
+    def with_grads(fn):     # (loss, o), the three gradients, on one group
+        return jax.jit(jax.value_and_grad(
+            lambda *xs: (lambda o: (jnp.sum(o * w[:, :ref_heads]), o))(
+                fn(*xs)), (0, 1, 2), has_aux=True))
+    reference = with_grads(full)
+    want = reference(*ref_args)
+    want = [want[0][1], *want[1]]
+
+    def measure(name, form):
+        fwd = jax.jit(form)
+        both = jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(form(q, k, v) * w), (0, 1, 2)))
+        ms_f, _ = timed(fwd, q32, k, v)
+        ms_b, _ = timed(both, q32, k, v)
+        print(f"gqa {name}: fwd {ms_f:.2f} ms ({flops_fwd / ms_f / 1e9:.1f} "
+              f"TFLOP/s causal), fwd+bwd {ms_b:.2f} ms "
+              f"({3.5 * flops_fwd / ms_b / 1e9:.1f} TFLOP/s)", flush=True)
+        # one group of two query heads on its one key/value head
+        grouped = with_grads(form)
+        got = grouped(*ref_args)
+        errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b)))
+                for a, b in zip([got[0][1], *got[1]], want)]
+        print(f"gqa {name}: max |o, dq, dk, dv| difference from f32 sdpa on "
+              f"{ref_heads} query heads of one group: "
+              + ", ".join(f"{e:.4g}" for e in errs), flush=True)
+
+    _run_rows("gqa", forms, only, measure)
+
+
 if __name__ == "__main__":
     print(f"device: {jax.devices()[0].device_kind}", flush=True)
     which = sys.argv[1:] or ["attn", "gmm", "rows", "slabs"]
     for name in which:
         name, _, only = name.partition(":")   # attn:pallas,plain
         {"attn": attn, "core": core, "gmm": gmm, "rows": rows,
-         "slabs": slabs}[name](
+         "slabs": slabs, "gdn": gdn, "gqa": gqa}[name](
             *([only.split(",")] if only else []))
         if name == "gmm":   # and over one slab of the layer (PR 32)
             from dlrm_flexflow_tpu.ops.moe import slab_rows

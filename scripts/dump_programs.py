@@ -17,7 +17,8 @@ the tiny DLRM of ``tests/test_region_cache.py`` (packed storage; SGD and
 lazy Adam; regions on and off; the auto ladder and the explicit
 two-level ``"16,8"``), the same model on logical storage with the
 view-row transport on and off, with the row cache off, a dense-only
-toy, and the tiny language model of ``tests/test_mla_moe_lm.py``.
+toy, the tiny language model of ``tests/test_mla_moe_lm.py`` and (PR 35)
+the tiny hybrid one of ``tests/test_gdn_moe_lm.py``.
 """
 
 import json
@@ -100,6 +101,28 @@ def lm():
             jnp.asarray(labels))
 
 
+def gdn_lm():
+    from benchmarks.models import gdn_moe_lm as family
+    from dlrm_flexflow_tpu.apps import gdn_moe_lm as app
+    from dlrm_flexflow_tpu.ops import attention, deltanet
+    attention.ATTENTION_BLOCK = deltanet.CHUNK = 8
+    cfg = app.GdnMoeLmConfig(
+        vocab_size=96, hidden_size=32, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=6, num_experts=16,
+        experts_held=4, num_experts_per_tok=4, moe_intermediate_size=16,
+        shared_expert_intermediate_size=16, seq_len=32)
+    m = app.build(cfg, ff.FFConfig(batch_size=2))
+    m.compile(optimizer=app.optimizer(cfg), loss_type=app.token_loss,
+              metrics=(), mesh=False)
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, size=(2, 2, cfg.seq_len + 1)).astype(np.int32)
+    inputs, labels = family._split(tokens)
+    return (m, {k: jnp.asarray(v) for k, v in inputs.items()},
+            jnp.asarray(labels))
+
+
 CASES = {
     **{f"dlrm.{opt}.regions-{reg}.levels-{lv or 'auto'}":
        (lambda opt=opt, reg=reg, lv=lv: dlrm(
@@ -118,6 +141,7 @@ CASES = {
     "dlrm.sgd.cache-off": lambda: dlrm(epoch_row_cache="off"),
     "toy.dense": toy,
     "lm.tiny": lm,
+    "gdn_lm.tiny": gdn_lm,
 }
 
 
