@@ -39,6 +39,17 @@ matmul operands (``q``, ``k``, ``v``, ``T``, the state where it is
 multiplied) in the compute dtype, f32 accumulation.  Every exponent is
 of a difference ``gamma_i - gamma_j <= 0``, so nothing overflows
 whatever the decays.
+
+Two forms, one entry (``gated_delta_rule``), chosen by ``core_form``
+from the backend and the shapes alone.  On a TPU, with the sequence
+whole blocks of ``pallas_deltanet.BLOCK`` tokens and head widths that
+are whole lane tiles (128 in the language model): one Pallas kernel
+forward and one backward (``ops/pallas_deltanet.py``), the same
+algorithm at the same chunk and with the same arithmetic, a chunk's
+intermediates in VMEM, q, k and v read where they lie; it keeps between
+its passes what this form keeps.  Anywhere else (the CPU, a 29-token
+sequence, 24-wide heads): the chunked form below, ``jax.numpy`` under a
+``lax.scan``, which is also the kernels' reference.
 """
 
 from __future__ import annotations
@@ -52,6 +63,7 @@ import jax.numpy as jnp
 from ..initializers import (ConstantInitializer, DEFAULT_KERNEL_INIT,
                             Initializer, UniformInitializer)
 from ..tensor import ParameterSpec
+from . import pallas_deltanet
 from .base import Op, matmul
 
 #: tokens of one chunk (a power of two): the released kernels' size.
@@ -60,15 +72,23 @@ from .base import Op, matmul
 #: PR 35): 64: 23.64 / 52.78; 32: 19.26 / 55.40; 128: 28.86 / 58.48;
 #: with ``T`` by ``solve_triangular`` 21.50 / 62.68; the same chunks
 #: differentiated by JAX 23.68 / 53.09 (the hand-written backward buys
-#: what is kept between the passes, not time)
+#: what is kept between the passes, not time); the Pallas kernels that run
+#: there since PR 36, at this chunk: 11.04 / 25.35
 CHUNK = 64
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def core_form() -> str:
-    """Which form ``gated_delta_rule`` runs: one today, on every backend
-    and at every shape (the model's ``program`` events count it)."""
-    return "chunked"
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def core_form(seq: int, dk: int, dv: int, dtype) -> str:
+    """Which form ``gated_delta_rule`` runs at these shapes: ``"pallas"``
+    on a TPU where the kernels take them, else ``"chunked"``.  Decided
+    from what the trace can see, and by nothing else (the model's
+    ``program`` events count it)."""
+    fused = _on_tpu() and pallas_deltanet.takes(seq, dk, dv, dtype)
+    return "pallas" if fused else "chunked"
 
 
 # ------------------------------------------- (I + A)^-1, unit lower triangular
@@ -258,23 +278,45 @@ def _chunked_rule_bwd(chunk, cd, res, do):
 _chunked_rule.defvjp(_chunked_rule_fwd, _chunked_rule_bwd)
 
 
+@jax.custom_vjp
+def _pallas_rule(q, k, v, g, beta):
+    return pallas_deltanet.forward(q, k, v, g, beta)[0]
+
+
+def _pallas_rule_fwd(q, k, v, g, beta):
+    o, starts = pallas_deltanet.forward(q, k, v, g, beta)
+    return o, (q, k, v, g, beta, starts)
+
+
+def _pallas_rule_bwd(res, do):
+    return pallas_deltanet.backward(*res, do.astype(jnp.float32))
+
+
+_pallas_rule.defvjp(_pallas_rule_fwd, _pallas_rule_bwd)
+
+
 def gated_delta_rule(q, k, v, g, beta, compute_dtype=None):
     """The gated delta rule over whole sequences, in chunks of
     ``CHUNK``.  ``q``, ``k``: (B, S, Hk, dk), already normalised and
     scaled; ``v``: (B, S, Hv, dv), ``Hk`` dividing ``Hv`` (value head
     ``h`` reads key head ``h // (Hv / Hk)``); ``g`` (log-decay, <= 0)
-    and ``beta``: (B, S, Hv), f32.  Returns (B, S, Hv, dv) f32.  A
+    and ``beta``: (B, S, Hv), f32.  Returns (B, S, Hv, dv) f32.  Where
+    ``core_form`` says ``"pallas"`` the two kernels of
+    ``ops/pallas_deltanet.py`` run; else the chunked form, in which a
     sequence the chunk does not divide is padded behind its end with
     tokens that change nothing (``beta`` 0, ``g`` 0)."""
     cd = jnp.dtype(compute_dtype or jnp.float32)
     s = q.shape[1]
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    if core_form(s, q.shape[3], v.shape[3], cd) == "pallas":
+        return _pallas_rule(q.astype(cd), k.astype(cd), v.astype(cd), g,
+                            beta)
     pad = -s % CHUNK
     if pad:
         q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad))
                                     + ((0, 0),) * (x.ndim - 2))
                             for x in (q, k, v, g, beta))
-    o = _chunked_rule(q.astype(cd), k.astype(cd), v.astype(cd),
-                      g.astype(jnp.float32), beta.astype(jnp.float32),
+    o = _chunked_rule(q.astype(cd), k.astype(cd), v.astype(cd), g, beta,
                       CHUNK, cd)
     return o[:, :s] if pad else o
 
@@ -324,11 +366,12 @@ class GatedDeltaNet(Op):
 
     Scopes (the prefix is the op's ``phase``, ``ff.gdn`` without one):
     ``.proj`` (both input projections, ``W_out``), ``.conv``, ``.core``
-    (normalisation of q and k, decays, the chunked rule), ``.gate``.
+    (normalisation of q and k, decays, the rule in the form
+    ``core_form`` names), ``.gate``.
     """
 
     op_type = "GatedDeltaNet"
-    core_field, core_forms = "gdn_core", ("chunked",)
+    core_field, core_forms = "gdn_core", ("pallas", "chunked")
 
     def __init__(self, name, input_tensor, num_k_heads: int,
                  num_v_heads: int, head_k_dim: int, head_v_dim: int,
@@ -370,16 +413,22 @@ class GatedDeltaNet(Op):
             ParameterSpec(self.name, "w_out", (value, d), initializer=init,
                           sharded_dim=0)]
 
+    @property
+    def _cd(self):
+        return (jnp.bfloat16 if self.compute_dtype in ("bfloat16",
+                                                       jnp.bfloat16)
+                else jnp.float32)
+
     def core_form(self) -> str:
-        return core_form()
+        """The form this op's rule takes on this backend
+        (``ops/deltanet.py::core_form`` at its shapes)."""
+        return core_form(self.inputs[0].shape[1], self.dk, self.dv, self._cd)
 
     def forward(self, params, xs, *, training=False, rng=None):
         (x,) = xs
         b, s, _ = x.shape
         hk, hv, dk, dv = self.hk, self.hv, self.dk, self.dv
-        cdt = self.compute_dtype
-        cd = (jnp.bfloat16 if cdt in ("bfloat16", jnp.bfloat16)
-              else jnp.float32)
+        cdt, cd = self.compute_dtype, self._cd
         scope = self.phase or "ff.gdn"
         conv_dim = self._conv_dim
         with jax.named_scope(scope + ".proj"):

@@ -365,8 +365,12 @@ SCHEMA: Dict[str, dict] = {
     # core and how many the plain one, ``{"pallas": 6, "plain": 0}``
     # (ops/attention.py ``core_form``: the backend and the shapes decide
     # as the program is built, so the step pays nothing).
-    # ``gdn_core``: the like count for ``GatedDeltaNet`` ops
-    # (ops/deltanet.py ``core_form``), ``{"chunked": 3}``.
+    # ``gdn_core``: the like count for ``GatedDeltaNet`` ops: how many
+    # run the gated delta rule as the two Pallas kernels of
+    # ops/pallas_deltanet.py and how many in the chunked ``jax.numpy``
+    # form, ``{"pallas": 3, "chunked": 0}`` on a TPU at 16,384 tokens
+    # and 128-wide heads, ``{"pallas": 0, "chunked": 3}`` on the CPU
+    # (ops/deltanet.py ``core_form``).
     "program": {
         "required": {"name": str},
         "optional": {"fn": str, "attention_core": dict, "gdn_core": dict},

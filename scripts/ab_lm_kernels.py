@@ -1,7 +1,7 @@
 """On the chip: the two kernels of the language-model family at the
 shapes of ``joyai-flash-ep16.pretrain-8k``, each beside its rival.
 
-    chiprun -- python scripts/ab_lm_kernels.py [attn[:row,row]] [core] [gmm] [rows] [slabs] [gdn[:row,row]] [gqa[:row,row]]
+    chiprun -- python scripts/ab_lm_kernels.py [attn[:row,row]] [core] [gmm] [rows] [slabs] [gdn[:row,row]] [gdncore] [gqa[:row,row]]
 
 attn: the causal attention core (32 heads, 8,192 tokens, query/key
 width 192, value width 128, bf16), forward and forward + backward, one
@@ -73,7 +73,21 @@ JAX (no ``custom_vjp``: the scan keeps what it likes); ``T`` by
 inverse.  Each row's largest |o, dq, dk, dv, dg, dbeta| differences from
 the token-by-token recurrence in f32 on the same rounded operands, over
 the first 2,048 tokens of 2 key heads.  PERF.md section 6, PR 35 has the
-numbers.
+numbers.  PR 36: the Pallas kernels of ``ops/pallas_deltanet.py`` (what
+the program runs on a TPU since) at blocks of 256, 512 and 1,024 tokens;
+with ``T``'s f32 products done by hand in three bf16 passes (16 bits of
+each operand) and in six (what full precision does); with ``T = I - A``
+(wrong: what a kernel costs without its inverse); the chunked rows are
+the chunked form's whatever the backend.  The differences from the
+recurrence are taken on the 2 key heads whose value heads forget most
+slowly (PR 35 took the first 2, whose states outlive a token or two).
+v5e, forward / forward + backward ms: chunked 23.70 / 52.82; KERNELS
+512 **11.04 / 25.35** (they ship), 256 11.26 / 26.64, 1,024 10.91 /
+25.15; three passes 8.05 / 19.66, six by hand 10.70 / 25.00, no inverse
+5.27 / 14.22; the first form written, one pair of chunks at a time in a
+``fori_loop``, 13.98 / 33.29 and unrolled 12.76 / 30.45.
+gdncore (PR 36): ``core`` for the hybrid cell: the ``program`` event's
+``gdn_core`` and one DeltaNet layer's kernels by phase.
 gqa (PR 35): grouped-head attention at 16 query heads on 2 key/value
 heads, 16,384 tokens, 256 wide, bf16: the repo's kernels reading each
 group's key/value head in place (what the program runs), the same
@@ -247,27 +261,44 @@ def attn(only=(), h=32, s=8192):
                   f"{str(e)[:600]}", flush=True)
 
 
-def core():
+#: what ``core`` / ``gdncore`` build: the family, its application, the
+#: cell's files, the one-layer model's changes, the events' field and the
+#: scope whose instructions are counted
+_CORES = {
+    "core": ("mla_moe_lm", "joyai-flash-ep16", "pretrain-8k",
+             {"num_hidden_layers": 1, "num_nextn_predict_layers": 0},
+             "attention_core", "ff.lm.mla.core"),
+    "gdncore": ("gdn_moe_lm", "qwen3-next-ep16", "pretrain-16k",
+                {"num_hidden_layers": 1}, "gdn_core", "ff.lm.gdn.core"),
+}
+
+
+def core(which="core"):
     """What the program chose on this device, and what one layer
     compiles to: the cell's model built and compiled (never initialised
     or run: ``FFModel.compile`` knows the count its ``program`` events
-    carry), then one dense decoder layer of the cell with the embedding
-    and head (one ``LatentAttention``, recomputed as in the cell) trained
-    one step under an event log: its ``program`` event, and every Pallas
-    kernel of the optimized HLO beside its phase."""
+    carry), then the first decoder layer of the cell with the embedding
+    and head (one ``LatentAttention`` / one ``GatedDeltaNet``,
+    recomputed as in the cell) trained one step under an event log: its
+    ``program`` event, and every Pallas kernel of the optimized HLO
+    beside its phase."""
     import dataclasses
+    import importlib
     import json
 
-    from benchmarks.models import mla_moe_lm as family
     from dlrm_flexflow_tpu import profiling
-    from dlrm_flexflow_tpu.apps import mla_moe_lm as app
     from dlrm_flexflow_tpu.config import FFConfig
     from dlrm_flexflow_tpu.telemetry import event_log
 
+    name, config_name, traffic_name, one_layer, field, scope = _CORES[which]
+    family = importlib.import_module("benchmarks.models." + name)
+    app = importlib.import_module("dlrm_flexflow_tpu.apps." + name)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks/configs/joyai-flash-ep16.json")) as f:
+    with open(os.path.join(root, "benchmarks/configs",
+                           config_name + ".json")) as f:
         config = json.load(f)
-    with open(os.path.join(root, "benchmarks/traffic/pretrain-8k.json")) as f:
+    with open(os.path.join(root, "benchmarks/traffic",
+                           traffic_name + ".json")) as f:
         traffic = json.load(f)
 
     def compiled(cfg):
@@ -280,10 +311,9 @@ def core():
         return model
 
     cfg = family.model_config(config, traffic)
-    print(f"core: the cell's model, compiled and not run: "
+    print(f"{which}: the cell's model, compiled and not run: "
           f"{compiled(cfg)._program_fields}", flush=True)
-    model = compiled(dataclasses.replace(cfg, num_hidden_layers=1,
-                                         num_nextn_predict_layers=0))
+    model = compiled(dataclasses.replace(cfg, **one_layer))
     init = jax.jit(lambda: model.init(seed=0))
     state = init()
     tokens = np.random.default_rng(0).integers(
@@ -292,11 +322,11 @@ def core():
     labels = tokens[..., 1:, None].astype(np.int32)
     with event_log() as log:
         state, mets = model.train_epochs(state, inputs, labels, 1)
-        print(f"core: one layer, one step: loss "
+        print(f"{which}: one layer, one step: loss "
               f"{float(np.asarray(mets['loss'])[0]):.4f}", flush=True)
         events = log.events("program")
-    print(f"core: one layer's program events: "
-          f"{[{k: e[k] for k in ('name', 'attention_core')} for e in events]}",
+    print(f"{which}: one layer's program events: "
+          f"{[{k: e[k] for k in ('name', field)} for e in events]}",
           flush=True)
     prog = profiling._programs[events[-1]["name"]]
     text = prog.fn().lower(*prog.args).compile().as_text()
@@ -304,14 +334,15 @@ def core():
     kernels = [m.group(1) for m in map(profiling._INSTRUCTION.match,
                                        text.splitlines())
                if m and "tpu_custom_call" in m.string]
-    print(f"core: Pallas kernels of the optimized HLO by phase: "
+    print(f"{which}: Pallas kernels of the optimized HLO by phase: "
           f"{[(name, phases.get(name)) for name in kernels]}", flush=True)
     per_scope = {}
     for name, phase in phases.items():
-        if phase.startswith("ff.lm.mla.core"):
+        if phase.startswith(scope):
             per_scope[phase] = per_scope.get(phase, 0) + 1
-    print(f"core: instructions under ff.lm.mla.core by phase: {per_scope}",
+    print(f"{which}: instructions under {scope} by phase: {per_scope}",
           flush=True)
+
 
 def gmm(m=65536):
     from dlrm_flexflow_tpu.ops import moe as moe_ops
@@ -508,7 +539,8 @@ def _run_rows(tag, forms, only, measure):
 
 def gdn(only=(), hk=16, hv=32, s=16384, d=128):
     from benchmarks.reference import gdn_moe_lm_ref as ref
-    from dlrm_flexflow_tpu.ops import deltanet
+    from dlrm_flexflow_tpu.ops import deltanet, pallas_deltanet
+    shipped = pallas_deltanet.BLOCK
     keys = jax.random.split(jax.random.PRNGKey(0), 7)
     bf = jnp.bfloat16
     q = (deltanet.l2_normalised(jax.random.normal(keys[0], (1, s, hk, d)))
@@ -524,11 +556,60 @@ def gdn(only=(), hk=16, hv=32, s=16384, d=128):
     args = (q, k, v, g, beta)
     flops = 3 * s * hv * 6 * d * d
 
-    def program(chunk):
+    def program(chunk):     # the chunked form, whatever the backend
         def rule(*xs):
             deltanet.CHUNK = chunk
+            deltanet._on_tpu = lambda: False
             return deltanet.gated_delta_rule(*xs, compute_dtype=bf)
         return rule
+
+    whole_inverses = pallas_deltanet._inverses
+
+    def kernels(block, inverses=whole_inverses):
+        """``ops/pallas_deltanet.py`` at another block, or with ``T``
+        got another way."""
+        def rule(*xs):      # the backward kernel is traced after it returns
+            pallas_deltanet.BLOCK = block
+            pallas_deltanet._inverses = inverses
+            deltanet._on_tpu = lambda: True
+            return deltanet.gated_delta_rule(*xs, compute_dtype=bf)
+        return rule
+
+    def by_hand(pieces, keep):
+        """The doubling's f32 products by hand: each operand split into
+        ``pieces`` bf16 parts, the products of parts whose indices sum
+        to at most ``keep`` (2, 1: three passes, 16 bits of each
+        operand; 3, 2: six passes, what full precision does)."""
+        def split(x):
+            parts = []
+            for _ in range(pieces):
+                parts.append(x.astype(bf))
+                x = x - parts[-1].astype(jnp.float32)
+            return parts
+
+        def product(x, y):
+            xs, ys = split(x), split(y)
+            terms = sorted(((i + j, i, j) for i in range(pieces)
+                            for j in range(pieces) if i + j <= keep),
+                           reverse=True)          # the small ones first
+            return sum(jnp.dot(xs[i], ys[j],
+                               preferred_element_type=jnp.float32)
+                       for _, i, j in terms)
+
+        def inverses(mats, m):
+            eye = jnp.where(m.eye, 1.0, 0.0)
+            ds = [eye - jnp.where(m.corner(1), a, 0.0) for a in mats]
+            b = 2
+            while b < pallas_deltanet.CHUNK:
+                lows = [jnp.where(m.corner(b), a, 0.0) for a in mats]
+                xs = [product(d, low) for d, low in zip(ds, lows)]
+                ds = [d - product(x, d) for x, d in zip(xs, ds)]
+                b *= 2
+            return ds
+        return inverses
+
+    def no_inverse(mats, m):    # a wrong T: what the rest of a kernel costs
+        return [jnp.where(m.eye, 1.0, 0.0) - a for a in mats]
 
     def autodiff(*xs):      # the same chunks, differentiated by JAX
         deltanet.CHUNK = 64
@@ -548,19 +629,35 @@ def gdn(only=(), hk=16, hv=32, s=16384, d=128):
         finally:
             deltanet.unit_lower_inverse = real
 
-    forms = {"program: chunks of 64, own backward": program(64),
+    forms = {"pallas kernels, blocks of %d (the program on a TPU)"
+             % shipped: kernels(shipped),
+             **{"pallas kernels, blocks of %d" % n: kernels(n)
+                for n in (256, 512, 1024) if n != shipped},
+             "pallas kernels, T's products in three bf16 passes by hand":
+             kernels(shipped, by_hand(2, 1)),
+             "pallas kernels, T's products in six bf16 passes by hand":
+             kernels(shipped, by_hand(3, 2)),
+             "pallas kernels, T = I - A (wrong: the kernels less the "
+             "inverse)": kernels(shipped, no_inverse),
+             "chunked: chunks of 64, own backward": program(64),
              "chunks of 32, own backward": program(32),
              "chunks of 128, own backward": program(128),
              "chunks of 64, differentiated by JAX": autodiff,
              "chunks of 64, T by solve_triangular": solve}
     heads, tokens = 2, 2048      # of the key heads, for the recurrence
-    part = (q[:, :tokens, :heads], k[:, :tokens, :heads],
-            v[:, :tokens, :2 * heads], g[:, :tokens, :2 * heads],
-            beta[:, :tokens, :2 * heads])
-    w_part = w[:, :tokens, :2 * heads]
+    # the key heads whose value heads forget most slowly: there the state
+    # and ``T`` matter (PR 36; PR 35 took the first two, whose states
+    # outlive a token or two, so that a wrong ``T`` read the same)
+    group = hv // hk
+    slow = np.argsort(np.asarray(a).reshape(hk, group).min(axis=1))[:heads]
+    own = (slow[:, None] * group + np.arange(group)).reshape(-1)
+    part = (q[:, :tokens, slow], k[:, :tokens, slow], v[:, :tokens, own],
+            g[:, :tokens, own], beta[:, :tokens, own])
+    w_part = w[:, :tokens, own]
 
     def recurrence(q, k, v, g, beta):
-        q, k = (jnp.repeat(x.astype(jnp.float32), 2, axis=2) for x in (q, k))
+        q, k = (jnp.repeat(x.astype(jnp.float32), group, axis=2)
+                for x in (q, k))
         with jax.default_matmul_precision("highest"):
             return jax.vmap(ref.delta_rule)(q, k, v.astype(jnp.float32), g,
                                             beta)
@@ -590,6 +687,8 @@ def gdn(only=(), hk=16, hv=32, s=16384, d=128):
 
     _run_rows("gdn", forms, only, measure)
     deltanet.CHUNK = 64
+    pallas_deltanet.BLOCK = shipped
+    pallas_deltanet._inverses = whole_inverses
 
 
 def gqa(only=(), h=16, kv=2, s=16384, d=256):
@@ -661,7 +760,10 @@ if __name__ == "__main__":
     which = sys.argv[1:] or ["attn", "gmm", "rows", "slabs"]
     for name in which:
         name, _, only = name.partition(":")   # attn:pallas,plain
-        {"attn": attn, "core": core, "gmm": gmm, "rows": rows,
+        if name in _CORES:
+            core(name)
+            continue
+        {"attn": attn, "gmm": gmm, "rows": rows,
          "slabs": slabs, "gdn": gdn, "gqa": gqa}[name](
             *([only.split(",")] if only else []))
         if name == "gmm":   # and over one slab of the layer (PR 32)

@@ -92,7 +92,7 @@ def _rule_inputs(seq, hk=2, hv=4, dk=8, dv=6, batch=2):
     k = deltanet.l2_normalised(jax.random.normal(keys[1],
                                                  (batch, seq, hk, dk)))
     v = jax.random.normal(keys[2], (batch, seq, hv, dv))
-    g = -jnp.exp(jnp.array([-12.0, 3.0, 0.0, 1.0])) * jax.nn.softplus(
+    g = -jnp.exp(jnp.array([-12.0, 3.0, 0.0, 1.0])[:hv]) * jax.nn.softplus(
         jax.random.normal(keys[3], (batch, seq, hv)) + 1.0)
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, seq, hv)))
     return (q, k, v, g, beta), jax.random.normal(keys[5],
@@ -213,6 +213,180 @@ def test_the_deltanet_mixer_is_the_references():
     for a, b in zip(jax.tree_util.tree_leaves(got_g),
                     jax.tree_util.tree_leaves(want_g)):
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=2e-4)
+
+
+# ------------------------------------- the rule's Pallas kernels (interpreted)
+@pytest.fixture
+def rule_kernels_interpreted(monkeypatch):
+    """The rule's choice and kernels as on a TPU, the kernels run by the
+    Pallas interpreter, at the chunk the kernels are written for."""
+    from dlrm_flexflow_tpu.ops import pallas_deltanet
+    real = pallas_deltanet.pl.pallas_call
+    monkeypatch.setattr(deltanet, "_on_tpu", lambda: True)
+    monkeypatch.setattr(deltanet, "CHUNK", pallas_deltanet.CHUNK)
+    monkeypatch.setattr(
+        pallas_deltanet.pl, "pallas_call",
+        lambda *a, **kw: real(*a, **dict(kw, interpret=True)))
+    return pallas_deltanet
+
+
+def _with_grads(rule, args, w):
+    """(o, dq, dk, dv, dg, dbeta) under the loss ``sum(o * w)``."""
+    both = jax.jit(jax.value_and_grad(
+        lambda *a: (lambda o: (jnp.sum(o * w), o))(rule(*a)),
+        argnums=range(5), has_aux=True))
+    (_, o), grads = both(*args)
+    return (o, *grads)
+
+
+def _chunked_form(*args, **kw):
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(deltanet, "_on_tpu", lambda: False)
+        return deltanet.gated_delta_rule(*args, **kw)
+
+
+RULE_NAMES = ("o", "q", "k", "v", "g", "beta")
+
+
+def test_the_rule_kernels_are_the_token_recurrence(rule_kernels_interpreted):
+    """Both kernels in f32 at the published head width, 2 key heads on
+    4 value heads, 8 chunks: output and all five gradients against the
+    token recurrence at the chunked form's tolerances (decays from
+    exp(-30) to 1 - 1e-6 a token), and against the chunked form; two
+    ``pallas_call``s."""
+    seq = rule_kernels_interpreted.BLOCK
+    args, w = _rule_inputs(seq, dk=128, dv=128, batch=1)
+    decay = np.exp(np.asarray(args[3]))
+    assert decay.min() < 1e-8 and decay.max() > 1 - 1e-5
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(deltanet.gated_delta_rule(*a) * w),
+        argnums=range(5)))(*args))
+    assert jaxpr.count("pallas_call") == 2
+    assert jaxpr.count("name=gated_delta_fwd\n") \
+        == jaxpr.count("name=gated_delta_bwd\n") == 1
+    with jax.default_matmul_precision("highest"):
+        got = _with_grads(deltanet.gated_delta_rule, args, w)
+        want = _with_grads(_token_rule, args, w)
+        chunked = _with_grads(_chunked_form, args, w)
+    for name, a, b, c in zip(RULE_NAMES, got, want, chunked):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert np.all(np.isfinite(a)), name
+        # the chunked form's bounds; 128-wide heads over 512 tokens sum
+        # to gradients of 30, so a millionth of the largest beside them
+        atol = 2e-6 if name == "o" else max(
+            2e-5, 1e-6 * float(jnp.max(jnp.abs(b))))
+        np.testing.assert_allclose(a, b, atol=atol, err_msg=name)
+        np.testing.assert_allclose(a, c, atol=atol, err_msg=name)
+
+
+def test_the_rule_kernels_in_bfloat16_are_near_the_recurrence(
+        rule_kernels_interpreted):
+    """bf16 operands, f32 decays, ``T``, state and accumulators: every
+    number within bf16's rounding of the f32 recurrence on the same
+    rounded inputs, the output no further from it than the chunked
+    form's."""
+    bf = jnp.bfloat16
+    args, w = _rule_inputs(rule_kernels_interpreted.BLOCK, dk=128, dv=128,
+                           batch=1)
+    args = tuple(x.astype(bf).astype(F32) for x in args[:3]) + args[3:]
+    rule = lambda f: lambda *a: f(*a, compute_dtype=bf)
+    got = _with_grads(rule(deltanet.gated_delta_rule), args, w)
+    chunked = jax.jit(rule(_chunked_form))(*args)
+    with jax.default_matmul_precision("highest"):
+        want = _with_grads(_token_rule, args, w)
+    assert got[0].dtype == F32
+    for name, a, b in zip(RULE_NAMES, got, want):
+        scale = float(jnp.max(jnp.abs(b)))
+        assert float(jnp.max(jnp.abs(a - b))) < 0.02 * scale, name
+    err, chunked_err = (float(jnp.max(jnp.abs(x - want[0])))
+                        for x in (got[0], chunked))
+    assert 1e-5 < err < 2 * chunked_err      # it did round, and no more
+
+
+def test_a_state_dropped_between_the_kernels_blocks_is_caught(
+        rule_kernels_interpreted, monkeypatch):
+    """What carries the state from a grid step to the next is the
+    kernel's scratch: the kept states equal the chunked form's, and a
+    forward that loses the state at one block's boundary (the two halves
+    run apart) reads gradients thousands of times over the limit."""
+    kernels = rule_kernels_interpreted
+    seq = 2 * kernels.BLOCK
+    args, w = _rule_inputs(seq, hk=1, hv=2, dk=128, dv=128, batch=1)
+    with jax.default_matmul_precision("highest"):
+        _, starts = jax.jit(kernels.forward)(*args)
+        want_starts = jax.jit(lambda *a: deltanet._scan_chunks(
+            deltanet._chunk_operands(
+                *deltanet._laid_out(*a, kernels.CHUNK), F32), F32)[1])(*args)
+        assert starts.shape == (2, seq // kernels.CHUNK, 128, 128)
+        np.testing.assert_allclose(
+            starts, jnp.moveaxis(want_starts[:, 0], 0, 1), atol=2e-6)
+        assert float(jnp.max(jnp.abs(starts[0, -1]))) > 0.1
+        want = _with_grads(_token_rule, args, w)
+        whole = kernels.forward
+
+        def forgetting(*xs):
+            halves = [whole(*(x[:, part] for x in xs))
+                      for part in (slice(0, seq // 2), slice(seq // 2, seq))]
+            return tuple(jnp.concatenate(pair, axis=1)
+                         for pair in zip(*halves))
+
+        monkeypatch.setattr(kernels, "forward", forgetting)
+        got = _with_grads(deltanet.gated_delta_rule, args, w)
+    errs = {name: float(jnp.max(jnp.abs(a - b)))
+            for name, a, b in zip(RULE_NAMES, got, want)}
+    assert errs["o"] > 1e-2 and max(errs.values()) > 1e-1, errs
+
+
+@pytest.mark.parametrize("on_tpu,seq,dk,dv,dtype,form", [
+    (True, 16384, 128, 128, "bfloat16", "pallas"),
+    (True, 512, 128, 128, "float32", "pallas"),
+    (True, 1024, 256, 128, "bfloat16", "pallas"),
+    (False, 16384, 128, 128, "bfloat16", "chunked"),    # the CPU
+    (True, 29, 128, 128, "bfloat16", "chunked"),        # no whole block
+    (True, 576, 128, 128, "bfloat16", "chunked"),       # whole chunks alone
+    (True, 512, 24, 128, "bfloat16", "chunked"),        # no lane tile
+    (True, 512, 128, 64, "float32", "chunked"),
+    (True, 512, 128, 128, "float16", "chunked")])
+def test_the_rule_form_follows_the_backend_and_the_shapes(
+        on_tpu, seq, dk, dv, dtype, form, monkeypatch):
+    """``core_form`` from the backend and the shapes alone, and the
+    differentiated rule's jaxpr holds the two kernels or none."""
+    monkeypatch.setattr(deltanet, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(deltanet, "CHUNK", 64)
+    assert deltanet.core_form(seq, dk, dv, dtype) == form
+    if dtype == "float16":
+        return      # the op's compute dtype is bfloat16 or float32
+    tokens = min(seq, 1024)
+    assert deltanet.core_form(tokens, dk, dv, dtype) == form
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, F32)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(deltanet.gated_delta_rule(
+            *a, compute_dtype=jnp.dtype(dtype))), argnums=range(5)))(
+        shape(1, tokens, 1, dk), shape(1, tokens, 1, dk),
+        shape(1, tokens, 2, dv), shape(1, tokens, 2), shape(1, tokens, 2)))
+    assert jaxpr.count("pallas_call") == (2 if form == "pallas" else 0)
+
+
+def test_a_recomputed_layer_runs_the_rule_forward_twice(monkeypatch):
+    """The training step of a recomputed model at the published head
+    widths, as a TPU would trace it: for each of the three DeltaNet
+    layers one forward kernel, one more in the layer's recomputation
+    (which keeps neither the output nor the boundary states) and one
+    backward kernel, and no more; the ``program`` event would read
+    ``pallas: 3``."""
+    from dlrm_flexflow_tpu.ops import pallas_deltanet
+    monkeypatch.setattr(deltanet, "_on_tpu", lambda: True)
+    cfg = _small(linear_key_head_dim=128, linear_value_head_dim=128,
+                 seq_len=pallas_deltanet.BLOCK)
+    assert cfg.recompute
+    model, state = _compiled(cfg, batch=1)
+    assert model._program_fields["gdn_core"] == {"pallas": 3, "chunked": 0}
+    inputs, labels = family._split(_tokens(cfg, 1, batch=1))
+    text = str(jax.make_jaxpr(model._train_step)(
+        state, {k: v[0] for k, v in inputs.items()}, labels[0]))
+    assert text.count("name=gated_delta_fwd\n") == 6
+    assert text.count("name=gated_delta_bwd\n") == 3
+    assert text.count("pallas_call") == 9
 
 
 # ------------------------------------------------------------ attention
@@ -528,7 +702,7 @@ def test_the_program_event_counts_both_cores():
         model.train_epoch(state, inputs, labels)
     events = [e for e in log.events() if e["type"] == "program"]
     assert [(e["gdn_core"], e["attention_core"]) for e in events] \
-        == [({"chunked": 3}, {"pallas": 0, "plain": 1})]
+        == [({"pallas": 0, "chunked": 3}, {"pallas": 0, "plain": 1})]
     assert validate_event(events[0]) == []
     counted = [e for e in log.events() if e["type"] == "op_counters"]
     assert len(counted) == 4
