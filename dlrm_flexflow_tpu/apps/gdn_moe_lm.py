@@ -28,8 +28,7 @@ from typing import Optional
 from ..config import FFConfig
 from ..initializers import NormInitializer
 from ..model import FFModel
-from ..optim import AdamOptimizer
-from .mla_moe_lm import EMBEDDING_STDDEV, token_loss  # noqa: F401
+from .lm_common import EMBEDDING_STDDEV, optimizer, token_loss  # noqa: F401
 
 
 @dataclass
@@ -81,12 +80,6 @@ class GdnMoeLmConfig:
 
     def is_full_attention(self, index: int) -> bool:
         return (index + 1) % self.full_attention_interval == 0
-
-
-def optimizer(cfg: GdnMoeLmConfig) -> AdamOptimizer:
-    """Dense Adam on every tensor, the embedding included."""
-    return AdamOptimizer(lr=cfg.learning_rate, beta1=cfg.adam_beta1,
-                         beta2=cfg.adam_beta2, epsilon=cfg.adam_epsilon)
 
 
 def _mixer(model: FFModel, cfg: GdnMoeLmConfig, x, index: int, name: str,

@@ -27,22 +27,10 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Optional
 
-import jax
-
 from ..config import FFConfig
 from ..initializers import NormInitializer
-from ..losses import sparse_categorical_crossentropy_from_logits
 from ..model import FFModel
-from ..optim import AdamOptimizer
-
-
-#: the embedding is drawn at torch.nn.Embedding's default scale, not at
-#: ``initializer_range``: at the matrices' 0.02 the attention branches'
-#: mean over positions, one vector at every position of a freshly
-#: initialised model, outweighs the token's own vector (8-29x after two
-#: layers) and every token selects the same experts; at 1.0 the token's
-#: own vector leads the residual stream, as it does in a trained model
-EMBEDDING_STDDEV = 1.0
+from .lm_common import EMBEDDING_STDDEV, optimizer, token_loss  # noqa: F401
 
 
 @dataclass
@@ -90,22 +78,6 @@ class MlaMoeLmConfig:
         """From a config.json's dict: the keys this dataclass knows."""
         known = {f.name for f in dataclasses.fields(cls)}
         return cls(**{k: v for k, v in d.items() if k in known})
-
-
-def token_loss(logits, labels):
-    """Mean over positions of the cross-entropy of (B, S, V) logits with
-    (B, S, 1) token labels, in f32; timed with the head it follows."""
-    with jax.named_scope("ff.lm.head"):
-        return sparse_categorical_crossentropy_from_logits(logits, labels)
-
-
-token_loss.__name__ = "sparse_token_crossentropy"  # compile: sparse labels
-
-
-def optimizer(cfg: MlaMoeLmConfig) -> AdamOptimizer:
-    """Dense Adam on every tensor, the embedding included."""
-    return AdamOptimizer(lr=cfg.learning_rate, beta1=cfg.adam_beta1,
-                         beta2=cfg.adam_beta2, epsilon=cfg.adam_epsilon)
 
 
 def _decoder_layer(model: FFModel, cfg: MlaMoeLmConfig, x, index: int,

@@ -366,13 +366,14 @@ class FFModel:
                          held=None, num_shared=0, scaling=1.0,
                          bias_update_speed=0.0, kernel_initializer=None,
                          name=None, score_func="sigmoid",
-                         shared_gated=False):
+                         shared_gated=False, n_group=1, topk_group=1):
         from .ops.moe import HeldExpertsMoE
         op = HeldExpertsMoE(self._name("moe", name), input_tensor,
                             num_experts, hidden_dim, top_k, held, num_shared,
                             scaling, bias_update_speed, kernel_initializer,
                             self._op_compute_dtype(), score_func,
-                            shared_gated)
+                            shared_gated, n_group=n_group,
+                            topk_group=topk_group)
         return self._add(op)
 
     def rms_norm(self, input_tensor, eps=1e-6, name=None,
@@ -392,13 +393,15 @@ class FFModel:
     def latent_attention(self, input_tensor, num_heads, q_lora_rank,
                          kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
                          v_head_dim, rope_theta=10000.0, eps=1e-6,
-                         kernel_initializer=None, name=None):
+                         kernel_initializer=None, name=None, qk_norm=False,
+                         gate=None, heads_held=None):
         from .ops.attention import LatentAttention
         op = LatentAttention(self._name("latent_attention", name),
                              input_tensor, num_heads, q_lora_rank,
                              kv_lora_rank, qk_nope_head_dim,
                              qk_rope_head_dim, v_head_dim, rope_theta, eps,
-                             kernel_initializer, self._op_compute_dtype())
+                             kernel_initializer, self._op_compute_dtype(),
+                             qk_norm, gate, heads_held)
         return self._add(op)
 
     def gated_attention(self, input_tensor, num_heads, num_kv_heads,
@@ -419,6 +422,18 @@ class FFModel:
                            num_k_heads, num_v_heads, head_k_dim, head_v_dim,
                            conv_kernel, eps, kernel_initializer,
                            self._op_compute_dtype())
+        return self._add(op)
+
+    def kimi_delta_attention(self, input_tensor, num_heads, head_k_dim,
+                             head_v_dim, conv_kernel=4, lower_bound=-5.0,
+                             eps=1e-6, heads_held=None,
+                             kernel_initializer=None, name=None):
+        from .ops.deltanet import KimiDeltaAttention
+        op = KimiDeltaAttention(self._name("kimi_delta_attention", name),
+                                input_tensor, num_heads, head_k_dim,
+                                head_v_dim, conv_kernel, lower_bound, eps,
+                                heads_held, kernel_initializer,
+                                self._op_compute_dtype())
         return self._add(op)
 
     def dropout(self, input_tensor, rate=0.5, seed=0, name=None):
@@ -888,9 +903,10 @@ class FFModel:
                         if hasattr(op, "step_metrics")]
         self._counting_ops = [op.name for op in counting_ops]
         # what the ``program`` events of this model's programs carry
-        # beside their name: how many attention cores (``attention_core``)
-        # and DeltaNet cores (``gdn_core``) took which form (the op's
-        # ``core_form``; shapes and backend, so known here)
+        # beside their name: how many attention cores (``attention_core``),
+        # DeltaNet cores (``gdn_core``) and KDA cores (``kda_core``) took
+        # which form (the op's ``core_form``; shapes and backend, so
+        # known here)
         self._program_fields = {}
         for op in self.layers:
             if hasattr(op, "core_form"):

@@ -28,7 +28,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..initializers import ConstantInitializer, DEFAULT_KERNEL_INIT
 from ..tensor import ParameterSpec
 from . import pallas_attention
-from .base import Op, matmul
+from .base import Op, held_heads, matmul
 from .transformer import rms_norm, rope_half_split, rope_interleaved
 
 
@@ -375,6 +375,20 @@ class LatentAttention(Op):
     k_rope) / sqrt(nope + rope)``; ``out = concat_h(P v) W_o``.  The
     query/key width (``nope + rope``) need not equal ``v_dim``.
 
+    Three variations, each off by default (with them off the parameters
+    and the output are what they were before the arguments existed):
+    ``q_lora_rank=None``, no query latent, ``q = x W_q`` (DeepSeek-V2-
+    Lite's); ``qk_norm``, each head's whole query and whole key ``[k_nope
+    ; k_r]`` RMS-normalised over their ``nope + rope`` elements with one
+    learned weight each, before the rotary embedding (so the rotary key
+    is a head's own); ``gate="head_wise"``, each head's output times
+    ``sigmoid(x w_g)``, ``W_g`` (d, heads).  ``heads_held``: this op
+    holds that many of the heads (tensor parallelism without its
+    all-reduce): ``W_q`` / ``W_qb``, ``W_kvb`` and ``W_g`` have the held
+    heads' columns, ``W_o`` their rows, the latent projections and their
+    norms are whole, and the output is the held heads' part of the sum
+    over all heads.
+
     The core never builds (B, H, S, S): ``blockwise_causal_attention``,
     which runs as two Pallas kernels on a TPU at shapes they take and
     as the plain blockwise core elsewhere (``core_form`` says which; the
@@ -391,16 +405,21 @@ class LatentAttention(Op):
     saved_in_recompute = CORE_SAVED
     core_field, core_forms = "attention_core", ("pallas", "plain")
 
-    def __init__(self, name, input_tensor, num_heads: int, q_lora_rank: int,
+    def __init__(self, name, input_tensor, num_heads: int, q_lora_rank,
                  kv_lora_rank: int, qk_nope_head_dim: int,
                  qk_rope_head_dim: int, v_head_dim: int,
                  rope_theta: float = 10000.0, eps: float = 1e-6,
-                 kernel_initializer=None, compute_dtype=None):
+                 kernel_initializer=None, compute_dtype=None,
+                 qk_norm: bool = False, gate: Optional[str] = None,
+                 heads_held=None):
         super().__init__(name, [input_tensor])
+        assert gate in (None, "head_wise"), gate
         self.model_dim = input_tensor.shape[-1]
-        self.num_heads = int(num_heads)
-        self.q_lora_rank, self.kv_lora_rank = int(q_lora_rank), \
-            int(kv_lora_rank)
+        # the heads computed here: all of them unless a share is held
+        self.num_heads = held_heads(heads_held, int(num_heads))
+        self.qk_norm, self.gate = bool(qk_norm), gate
+        self.q_lora_rank = None if q_lora_rank is None else int(q_lora_rank)
+        self.kv_lora_rank = int(kv_lora_rank)
         self.nope, self.rope, self.v_dim = int(qk_nope_head_dim), \
             int(qk_rope_head_dim), int(v_head_dim)
         self.rope_theta, self.eps = float(rope_theta), float(eps)
@@ -412,14 +431,26 @@ class LatentAttention(Op):
     def param_specs(self):
         d, h = self.model_dim, self.num_heads
         init, one = self.kernel_initializer, ConstantInitializer(1.0)
-        return [
-            ParameterSpec(self.name, "w_qa", (d, self.q_lora_rank),
-                          initializer=init),
-            ParameterSpec(self.name, "q_norm", (self.q_lora_rank,),
-                          initializer=one),
-            ParameterSpec(self.name, "w_qb",
-                          (self.q_lora_rank, h * (self.nope + self.rope)),
-                          initializer=init, sharded_dim=1),
+        qk = self.nope + self.rope
+        if self.q_lora_rank is None:
+            query = [ParameterSpec(self.name, "w_q", (d, h * qk),
+                                   initializer=init, sharded_dim=1)]
+        else:
+            query = [
+                ParameterSpec(self.name, "w_qa", (d, self.q_lora_rank),
+                              initializer=init),
+                ParameterSpec(self.name, "q_norm", (self.q_lora_rank,),
+                              initializer=one),
+                ParameterSpec(self.name, "w_qb", (self.q_lora_rank, h * qk),
+                              initializer=init, sharded_dim=1)]
+        extra = []
+        if self.qk_norm:
+            extra += [ParameterSpec(self.name, name, (qk,), initializer=one)
+                      for name in ("q_head_norm", "k_head_norm")]
+        if self.gate:
+            extra.append(ParameterSpec(self.name, "w_gate", (d, h),
+                                       initializer=init, sharded_dim=1))
+        return query + [
             ParameterSpec(self.name, "w_kva",
                           (d, self.kv_lora_rank + self.rope),
                           initializer=init),
@@ -427,7 +458,7 @@ class LatentAttention(Op):
                           initializer=one),
             ParameterSpec(self.name, "w_kvb",
                           (self.kv_lora_rank, h * (self.nope + self.v_dim)),
-                          initializer=init, sharded_dim=1),
+                          initializer=init, sharded_dim=1)] + extra + [
             ParameterSpec(self.name, "w_o", (h * self.v_dim, d),
                           initializer=init, sharded_dim=0)]
 
@@ -449,24 +480,39 @@ class LatentAttention(Op):
         positions = jnp.arange(s)
         scope = self.phase or "ff.attn"
         with jax.named_scope(scope + ".proj"):
-            c_q = rms_norm(matmul(x, params["w_qa"], cdt), params["q_norm"],
-                           self.eps)
-            q = matmul(c_q, params["w_qb"], cdt).reshape(b, s, h,
-                                                         nope + rope)
+            if self.q_lora_rank is None:
+                q = matmul(x, params["w_q"], cdt)
+            else:
+                c_q = rms_norm(matmul(x, params["w_qa"], cdt),
+                               params["q_norm"], self.eps)
+                q = matmul(c_q, params["w_qb"], cdt)
+            q = q.reshape(b, s, h, nope + rope)
             kva = matmul(x, params["w_kva"], cdt)
             c_kv, k_r = kva[..., :self.kv_lora_rank], \
                 kva[..., self.kv_lora_rank:]
             kv = matmul(rms_norm(c_kv, params["kv_norm"], self.eps),
                         params["w_kvb"], cdt).reshape(b, s, h, nope + vd)
+            if self.qk_norm:
+                # the norm runs over a head's whole key, so the rotary
+                # part is a head's own from here
+                q = rms_norm(q, params["q_head_norm"], self.eps)
+                k = rms_norm(jnp.concatenate(
+                    [kv[..., :nope], jnp.broadcast_to(
+                        k_r[:, :, None, :], (b, s, h, rope))], axis=-1),
+                    params["k_head_norm"], self.eps)
+                k_all = jnp.concatenate(
+                    [k[..., :nope], rope_interleaved(
+                        k[..., nope:], positions, self.rope_theta,
+                        seq_axis=1)], axis=-1)
+            else:
+                k_rope = rope_interleaved(k_r, positions, self.rope_theta,
+                                          seq_axis=1)
+                k_all = jnp.concatenate(
+                    [kv[..., :nope], jnp.broadcast_to(
+                        k_rope[:, :, None, :], (b, s, h, rope))], axis=-1)
             q_rope = rope_interleaved(q[..., nope:], positions,
                                       self.rope_theta, seq_axis=1)
-            k_rope = rope_interleaved(k_r, positions, self.rope_theta,
-                                      seq_axis=1)
             q_all = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
-            k_all = jnp.concatenate(
-                [kv[..., :nope],
-                 jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, rope))],
-                axis=-1)
             heads_first = lambda t: t.transpose(0, 2, 1, 3)
             q_all, k_all, v = heads_first(q_all), heads_first(k_all), \
                 heads_first(kv[..., nope:])
@@ -477,14 +523,19 @@ class LatentAttention(Op):
                 q_all, k_all, v, 1.0 / math.sqrt(nope + rope),
                 compute_dtype=self._core_dtype())
         with jax.named_scope(scope + ".proj"):
-            o = o.transpose(0, 2, 1, 3).reshape(b, s, h * vd)
-            out = matmul(o, params["w_o"], cdt)
+            o = o.transpose(0, 2, 1, 3)
+            if self.gate:
+                o = o * jax.nn.sigmoid(
+                    matmul(x, params["w_gate"], cdt))[..., None]
+            out = matmul(o.reshape(b, s, h * vd), params["w_o"], cdt)
         return [out.astype(self.outputs[0].dtype)]
 
     def flops(self, batch):
         s, d, h = self.inputs[0].shape[1], self.model_dim, self.num_heads
-        proj = (d * self.q_lora_rank
-                + self.q_lora_rank * h * (self.nope + self.rope)
+        qk = self.nope + self.rope
+        query = d * h * qk if self.q_lora_rank is None \
+            else d * self.q_lora_rank + self.q_lora_rank * h * qk
+        proj = (query + (d * h if self.gate else 0)
                 + d * (self.kv_lora_rank + self.rope)
                 + self.kv_lora_rank * h * (self.nope + self.v_dim)
                 + h * self.v_dim * d)

@@ -244,3 +244,13 @@ def matmul(x, w, compute_dtype=None):
         dimension_numbers=(((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+
+
+def held_heads(heads_held, num_heads: int) -> int:
+    """How many of a mixer's ``num_heads`` heads an op holds
+    (``heads_held`` of ``KimiDeltaAttention`` and ``LatentAttention``):
+    all of them for ``None``.  Which ones is the deployment's to say: no
+    parameter, and nothing computed, depends on it."""
+    count = num_heads if heads_held is None else int(heads_held)
+    assert 1 <= count <= num_heads, (heads_held, num_heads)
+    return count

@@ -64,7 +64,7 @@ from ..initializers import (ConstantInitializer, DEFAULT_KERNEL_INIT,
                             Initializer, UniformInitializer)
 from ..tensor import ParameterSpec
 from . import pallas_deltanet
-from .base import Op, matmul
+from .base import Op, held_heads, matmul
 
 #: tokens of one chunk (a power of two): the released kernels' size.
 #: v5e, 16 key heads on 32 value heads x 16,384 tokens x 128, bf16,
@@ -161,9 +161,9 @@ def _chunk_operands(q, k, v, g, beta, cd):
     chunk at once.  ``q``, ``k`` (N, B, H, C, dk), ``v`` (N, B, H, C,
     dv), ``g``, ``beta`` (N, B, H, C); returns ``(qd, p, kd, carry,
     w_k, w_v)``: ``q exp(gamma)``, the masked ``q k^T`` with its decays,
-    ``k exp(gamma_C - gamma)``, ``exp(gamma_C)``, ``T (beta exp(gamma)
-    k)`` and ``T (beta v)``; the matmul operands in ``cd``, ``carry``
-    and ``w_v`` in f32."""
+    ``k exp(gamma_C - gamma)``, ``exp(gamma_C)`` (N, B, H, 1), ``T (beta
+    exp(gamma) k)`` and ``T (beta v)``; the matmul operands in ``cd``,
+    ``carry`` and ``w_v`` in f32."""
     c, dv = q.shape[-2], v.shape[-1]
     gamma = jnp.cumsum(g, axis=-1)
     at = jnp.arange(c)
@@ -182,13 +182,16 @@ def _chunk_operands(q, k, v, g, beta, cd):
     total = gamma[..., -1]
     qd = q * jnp.exp(gamma)[..., None]
     kd = k * jnp.exp(total[..., None] - gamma)[..., None]
-    return (qd.astype(cd), p.astype(cd), kd.astype(cd), jnp.exp(total),
-            w[..., dv:].astype(cd), w[..., :dv])
+    return (qd.astype(cd), p.astype(cd), kd.astype(cd),
+            jnp.exp(total)[..., None], w[..., dv:].astype(cd), w[..., :dv])
 
 
 def _scan_chunks(operands, cd):
     """The part that depends on the state: ``(o (N, B, H, C, dv) f32,
-    the state at every chunk's start (N, B, H, dk, dv) f32)``."""
+    the state at every chunk's start (N, B, H, dk, dv) f32)``.
+    ``carry``, what the chunk's decays leave of the state it found, is
+    (N, B, H, 1) for one decay a head and (N, B, H, dk) for one a key
+    channel (a row of the state)."""
     qd, _p, _kd, _carry, _w_k, w_v = operands
     state = jnp.zeros(qd.shape[1:3] + (qd.shape[-1], w_v.shape[-1]),
                       jnp.float32)
@@ -199,7 +202,7 @@ def _scan_chunks(operands, cd):
         u = (w_v - _mm("...cd,...de->...ce", w_k, sc)).astype(cd)
         o = _mm("...cd,...de->...ce", qd, sc) \
             + _mm("...ij,...je->...ie", p, u)
-        nxt = carry[..., None, None] * s + _mm("...cd,...ce->...de", kd, u)
+        nxt = carry[..., None] * s + _mm("...cd,...ce->...de", kd, u)
         return nxt, (o, s)
 
     _, (o, starts) = jax.lax.scan(chunk, state, operands)
@@ -220,11 +223,11 @@ def _scan_chunks_bwd(operands, starts, do, cd):
         grads = (_mm("...ce,...de->...cd", doc, sc).astype(qd.dtype),
                  _mm("...ie,...je->...ij", doc, u).astype(p.dtype),
                  _mm("...ce,...de->...cd", u, dsc).astype(kd.dtype),
-                 jnp.sum(ds * s, axis=(-1, -2)),
+                 jnp.sum((ds * s).reshape(carry.shape + (-1,)), axis=-1),
                  (-_mm("...ce,...de->...cd", duc, sc)).astype(w_k.dtype),
                  du)
         before = _mm("...cd,...ce->...de", qd, doc) \
-            + carry[..., None, None] * ds \
+            + carry[..., None] * ds \
             - _mm("...cd,...ce->...de", w_k, duc)
         return before, grads
 
@@ -254,28 +257,47 @@ def _tokens_first(o):
     return jnp.moveaxis(o, (0, 3), (1, 2)).reshape(b, n * c, h, dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _chunked_rule(q, k, v, g, beta, chunk, cd):
-    operands = _chunk_operands(*_laid_out(q, k, v, g, beta, chunk), cd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _chunked_rule(q, k, v, g, beta, chunk, cd, operands_of):
+    """The chunked form of either rule: ``operands_of`` is
+    ``_chunk_operands`` (``g`` (B, S, H), one decay a head) or
+    ``_channel_chunk_operands`` (``g`` (B, S, H, dk), one a key
+    channel)."""
+    operands = operands_of(*_laid_out(q, k, v, g, beta, chunk), cd)
     return _tokens_first(_scan_chunks(operands, cd)[0])
 
 
-def _chunked_rule_fwd(q, k, v, g, beta, chunk, cd):
-    operands = _chunk_operands(*_laid_out(q, k, v, g, beta, chunk), cd)
+def _chunked_rule_fwd(q, k, v, g, beta, chunk, cd, operands_of):
+    operands = operands_of(*_laid_out(q, k, v, g, beta, chunk), cd)
     o, starts = _scan_chunks(operands, cd)
     return _tokens_first(o), (q, k, v, g, beta, starts)
 
 
-def _chunked_rule_bwd(chunk, cd, res, do):
+def _chunked_rule_bwd(chunk, cd, operands_of, res, do):
     q, k, v, g, beta, starts = res
     operands, pull = jax.vjp(
-        lambda *xs: _chunk_operands(*_laid_out(*xs, chunk), cd),
+        lambda *xs: operands_of(*_laid_out(*xs, chunk), cd),
         q, k, v, g, beta)
     do = _chunks_first(do.astype(jnp.float32), chunk)
     return pull(_scan_chunks_bwd(operands, starts, do, cd))
 
 
 _chunked_rule.defvjp(_chunked_rule_fwd, _chunked_rule_bwd)
+
+
+def _in_whole_chunks(q, k, v, g, beta, cd, operands_of):
+    """``_chunked_rule`` over sequences of any length: one the chunk
+    does not divide is padded behind its end with tokens that change
+    nothing (``beta`` 0, ``g`` 0)."""
+    s = q.shape[1]
+    pad = -s % CHUNK
+    if pad:
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad))
+                                    + ((0, 0),) * (x.ndim - 2))
+                            for x in (q, k, v, g, beta))
+    o = _chunked_rule(q.astype(cd), k.astype(cd), v.astype(cd), g, beta,
+                      CHUNK, cd, operands_of)
+    return o[:, :s] if pad else o
 
 
 @jax.custom_vjp
@@ -311,14 +333,107 @@ def gated_delta_rule(q, k, v, g, beta, compute_dtype=None):
     if core_form(s, q.shape[3], v.shape[3], cd) == "pallas":
         return _pallas_rule(q.astype(cd), k.astype(cd), v.astype(cd), g,
                             beta)
-    pad = -s % CHUNK
-    if pad:
-        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad))
-                                    + ((0, 0),) * (x.ndim - 2))
-                            for x in (q, k, v, g, beta))
-    o = _chunked_rule(q.astype(cd), k.astype(cd), v.astype(cd), g, beta,
-                      CHUNK, cd)
-    return o[:, :s] if pad else o
+    return _in_whole_chunks(q, k, v, g, beta, cd, _chunk_operands)
+
+
+# ------------------------------------------- one decay a key channel (KDA)
+#: tokens of one sub-block of a chunk in ``kimi_delta_rule``.  With a
+#: decay per channel the pair's decay ``exp(Gamma_i - Gamma_j)`` lies
+#: inside the dot product over the channels, so it has to be split into
+#: a factor on row ``i`` and a factor on column ``j`` around a point of
+#: reference ``R``: ``exp(Gamma_i - R) exp(R - Gamma_j)``.  One point
+#: for a 64-token chunk would need ``exp(5 x 64)``; each sub-block of
+#: rows takes its own, the decays summed through its middle token.  A
+#: row's factor then lies in ``e^+-40`` (8 tokens of at most 5 nats), a
+#: column's inside the sub-block too, and an earlier column's is below 1;
+#: a pair above the diagonal, masked afterwards, reaches ``e^80`` a
+#: channel and ``128 e^80 = e^85`` a sum, inside f32's and bf16's range
+#: (``e^88.7``).  Every pair that counts keeps both factors far from the
+#: denormals, where the sub-block's START as the point would flush a
+#: row's ``e^-80 q`` at the bound (relative error 1e-4 there, 1e-6 so).
+#: 32 tokens would need a bound of -2.5 a token
+SUB = 16
+#: the lowest log-decay a token and channel ``kimi_delta_rule`` takes
+DECAY_FLOOR = -80.0 / SUB
+
+
+def _channel_chunk_operands(q, k, v, g, beta, cd):
+    """``_chunk_operands`` for one decay a key channel: ``g`` (N, B, H,
+    C, dk) in ``[DECAY_FLOOR, 0]``.  The same six operands, ``carry``
+    (N, B, H, dk); ``A`` and the masked ``q k^T`` hold their decays
+    inside the sum over the channels, sub-block by sub-block (``SUB``
+    has why): one product of the rows ``[q ; k] exp(Gamma - R_a)``, a
+    sub-block a batch entry, with the columns ``k exp(R_a - Gamma_j)``
+    up to the sub-block's end, ``R_a`` the decays summed through the
+    sub-block's middle token."""
+    c, dk, dv = q.shape[-2], q.shape[-1], v.shape[-1]
+    lead = q.shape[:-2]
+    sub = min(SUB, c)
+    m = c // sub
+    gamma = jnp.cumsum(g, axis=-2)                      # (..., C, dk)
+    blocks = lambda x: x.reshape(lead + (m, sub) + x.shape[-1:])
+    ref = blocks(gamma)[..., sub // 2 - 1, :]           # (..., m, dk)
+    own = jnp.exp(blocks(gamma) - ref[..., None, :])    # rows: e^+-40
+    at = jnp.arange(c)
+    upto = at[None, :] < ((jnp.arange(m) + 1) * sub)[:, None]     # (m, C)
+    cols = k[..., None, :, :] * jnp.exp(jnp.where(
+        upto[..., None], ref[..., :, None, :] - gamma[..., None, :, :],
+        -jnp.inf))                                      # (..., m, C, dk)
+    rows = jnp.concatenate([blocks(q) * own, blocks(k) * own], axis=-2)
+    # f32 operands at full precision, whatever the compute dtype: a
+    # token's decay is a row's factor of every later pair and a column's
+    # of every earlier one, and in d(loss)/dg the two cancel for every
+    # pair that does not straddle the token.  They cancel only if both
+    # paths multiply the same numbers; with the factors rounded to bf16
+    # between the exponential and the product they do not, and what is
+    # left, 2^-9 of every pair behind the token, drowns a gradient that
+    # decays of e^-4 a token make small (a_log read 8.7 times its own
+    # norm off the recurrence's, w_f 1.0).  4.3 GFLOP a layer forward at
+    # 16 heads x 8,192 tokens: nothing beside the projections
+    pairs = jnp.einsum("...aic,...ajc->...aij", rows, cols,
+                       precision=_HIGHEST,
+                       preferred_element_type=jnp.float32)
+    p = jnp.where(at[:, None] >= at[None, :],
+                  pairs[..., :sub, :].reshape(lead + (c, c)), 0.0)
+    a = jnp.where(at[:, None] > at[None, :],
+                  beta[..., :, None]
+                  * pairs[..., sub:, :].reshape(lead + (c, c)), 0.0)
+    t = unit_lower_inverse(a)
+    rhs = jnp.concatenate([v * beta[..., None],
+                           k * beta[..., None] * jnp.exp(gamma)], axis=-1)
+    w = _mm("...ij,...jd->...id", t.astype(cd), rhs.astype(cd))
+    total = gamma[..., -1:, :]                          # (..., 1, dk)
+    qd = q * jnp.exp(gamma)
+    kd = k * jnp.exp(total - gamma)
+    return (qd.astype(cd), p.astype(cd), kd.astype(cd),
+            jnp.exp(total[..., 0, :]), w[..., dv:].astype(cd), w[..., :dv])
+
+
+def kimi_delta_rule(q, k, v, g, beta, compute_dtype=None):
+    """The delta rule with a decay for every key channel (Kimi Delta
+    Attention: Kimi Linear, arXiv:2510.26692 section 3), over whole
+    sequences, in chunks of ``CHUNK``.  Per head, the state ``S`` (dk,
+    dv) f32, ``S_0 = 0``::
+
+        S <- Diag(exp(g_t)) S               g_t (dk,) in [DECAY_FLOOR, 0]
+        u  = beta_t (v_t - S^T k_t)
+        S <- S + k_t u^T
+        o_t = S^T q_t
+
+    ``q``, ``k``: (B, S, H, dk), already normalised and scaled; ``v``:
+    (B, S, H, dv); ``g``: (B, S, H, dk) f32; ``beta``: (B, S, H) f32.
+    Returns (B, S, H, dv) f32.  ``gated_delta_rule``'s chunked form (its
+    layout, its walk over the chunks, its hand-written backward that
+    keeps the inputs and the state at each chunk's start) with the
+    operands of ``_channel_chunk_operands``; with ``g`` constant over
+    the channels it is that function.  A sequence the chunk does not
+    divide is padded behind its end with tokens that change nothing
+    (``beta`` 0, ``g`` 0).  There is one form, ``"chunked"``, on every
+    backend (``KimiDeltaAttention.core_form``)."""
+    cd = jnp.dtype(compute_dtype or jnp.float32)
+    return _in_whole_chunks(q, k, v, g.astype(jnp.float32),
+                            beta.astype(jnp.float32), cd,
+                            _channel_chunk_operands)
 
 
 # --------------------------------------------------------------- the mixer
@@ -467,4 +582,125 @@ class GatedDeltaNet(Op):
         value = self.hv * self.dv
         proj = d * (self._conv_dim + value + 2 * self.hv) + value * d
         core = 3 * self.hv * self.dk * self.dv     # 6 x dk x dv a token, / 2
+        return batch * s * 2 * (proj + core)
+
+
+class KimiDeltaAttention(Op):
+    """The Kimi Delta Attention mixer (Kimi Linear, arXiv:2510.26692
+    section 3; the layout of the ``fla`` library's
+    ``KimiDeltaAttention`` with full-rank gates): (B, S, d) -> (B, S,
+    d), causal, no biases.
+
+    Per head ``h`` of ``num_heads``, ``head_k_dim`` wide for q and k and
+    ``head_v_dim`` for v: ``q = l2norm(silu(conv(x W_q))) /
+    sqrt(head_k_dim)``, ``k = l2norm(silu(conv(x W_k)))``, ``v =
+    silu(conv(x W_v))``, each convolution depthwise and causal over
+    ``conv_kernel`` tokens; the log-decay of every key channel ``g =
+    lower_bound sigmoid(exp(A_log_h) (x W_f + dt_bias))``, in
+    ``(lower_bound, 0)``; ``beta = sigmoid(x W_beta)``; the delta rule
+    with a decay per channel (``kimi_delta_rule``); ``o <- o /
+    sqrt(mean(o^2) + eps) w_n sigmoid(x W_g)``; ``y = o W_out``.
+
+    ``heads_held``: this op holds that many of the heads (tensor
+    parallelism over the heads, without its all-reduce):
+    every parameter with a head axis has the held heads' part alone,
+    ``W_out`` their rows, and the output is their part of the sum over
+    all heads.  What the absent heads would add is left out.
+
+    Scopes as ``GatedDeltaNet``'s (``ff.kda`` without a phase):
+    ``.proj``, ``.conv``, ``.core`` (normalisation of q and k, both
+    gates, the rule), ``.gate``.
+    """
+
+    op_type = "KimiDeltaAttention"
+    core_field, core_forms = "kda_core", ("chunked",)
+
+    def __init__(self, name, input_tensor, num_heads: int, head_k_dim: int,
+                 head_v_dim: int, conv_kernel: int = 4,
+                 lower_bound: float = -5.0, eps: float = 1e-6,
+                 heads_held=None, kernel_initializer=None,
+                 compute_dtype=None):
+        super().__init__(name, [input_tensor])
+        self.model_dim = input_tensor.shape[-1]
+        self.heads = held_heads(heads_held, int(num_heads))
+        self.dk, self.dv = int(head_k_dim), int(head_v_dim)
+        self.conv_kernel, self.eps = int(conv_kernel), float(eps)
+        self.lower_bound = float(lower_bound)
+        assert DECAY_FLOOR <= self.lower_bound < 0, \
+            f"kimi_delta_rule takes log-decays down to {DECAY_FLOOR}"
+        self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT
+        self.compute_dtype = compute_dtype
+        self.outputs = [self._make_output(input_tensor.shape,
+                                          input_tensor.dtype)]
+
+    def param_specs(self):
+        d, init = self.model_dim, self.kernel_initializer
+        key, value = self.heads * self.dk, self.heads * self.dv
+        bound = 1.0 / math.sqrt(self.conv_kernel)   # torch's Conv1d default
+        conv = UniformInitializer(-bound, bound)
+        wide = lambda name, n: ParameterSpec(self.name, name, (d, n),
+                                             initializer=init, sharded_dim=1)
+        taps = lambda name, n: ParameterSpec(
+            self.name, name, (self.conv_kernel, n), initializer=conv)
+        return [
+            wide("w_q", key), wide("w_k", key), wide("w_v", value),
+            wide("w_f", key), wide("w_g", value), wide("w_beta", self.heads),
+            taps("conv_q", key), taps("conv_k", key), taps("conv_v", value),
+            ParameterSpec(self.name, "a_log", (self.heads,),
+                          initializer=_LogUniform(16.0)),
+            ParameterSpec(self.name, "dt_bias", (key,),
+                          initializer=ConstantInitializer(1.0)),
+            ParameterSpec(self.name, "norm", (self.dv,),
+                          initializer=ConstantInitializer(1.0)),
+            ParameterSpec(self.name, "w_out", (value, d), initializer=init,
+                          sharded_dim=0)]
+
+    _cd = GatedDeltaNet._cd          # reads compute_dtype alone
+
+    def core_form(self) -> str:
+        return "chunked"
+
+    def forward(self, params, xs, *, training=False, rng=None):
+        (x,) = xs
+        b, s, _ = x.shape
+        h, dk, dv = self.heads, self.dk, self.dv
+        cdt, cd = self.compute_dtype, self._cd
+        scope = self.phase or "ff.kda"
+        with jax.named_scope(scope + ".proj"):
+            q, k, v, f, z, beta = (matmul(x, params[name], cdt) for name in
+                                   ("w_q", "w_k", "w_v", "w_f", "w_g",
+                                    "w_beta"))
+
+        @jax.checkpoint   # keeps the three projections alone
+        def heads(q, k, v, conv_q, conv_k, conv_v):
+            with jax.named_scope(scope + ".conv"):
+                q, k, v = (jax.nn.silu(causal_conv(t, w)) for t, w in
+                           ((q, conv_q), (k, conv_k), (v, conv_v)))
+            with jax.named_scope(scope + ".core"):
+                q = l2_normalised(q.reshape(b, s, h, dk)) * dk ** -0.5
+                k = l2_normalised(k.reshape(b, s, h, dk))
+                return q.astype(cd), k.astype(cd), \
+                    v.reshape(b, s, h, dv).astype(cd)
+
+        q, k, v = heads(q, k, v, params["conv_q"], params["conv_k"],
+                        params["conv_v"])
+        with jax.named_scope(scope + ".core"):
+            rate = jnp.exp(params["a_log"])[:, None]
+            g = self.lower_bound * jax.nn.sigmoid(
+                rate * (f.reshape(b, s, h, dk)
+                        + params["dt_bias"].reshape(h, dk)))
+            o = kimi_delta_rule(q, k, v, g, jax.nn.sigmoid(beta), cd)
+        with jax.named_scope(scope + ".gate"):
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
+                                           keepdims=True) + self.eps)
+            o = o * params["norm"] * jax.nn.sigmoid(z.reshape(b, s, h, dv))
+        with jax.named_scope(scope + ".proj"):
+            out = matmul(o.reshape(b, s, h * dv), params["w_out"], cdt)
+        return [out.astype(self.outputs[0].dtype)]
+
+    def flops(self, batch):
+        s, d = self.inputs[0].shape[1], self.model_dim
+        key, value = self.heads * self.dk, self.heads * self.dv
+        proj = d * (3 * key + 2 * value + self.heads) + value * d
+        core = 3 * self.heads * self.dk * self.dv  # 6 x dk x dv a token, / 2
         return batch * s * 2 * (proj + core)

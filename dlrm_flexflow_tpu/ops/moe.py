@@ -292,7 +292,15 @@ class HeldExpertsMoE(Op):
     section 2.1.2 and the ``noaux_tc`` method of its released code):
     ``s = sigmoid(x W_r)`` in f32; the ``top_k`` largest of ``s + b``
     are selected (``b`` selects and never weighs); gates are the
-    selected ``s`` normalised to sum 1, times ``scaling``.  The output is
+    selected ``s`` normalised to sum 1, times ``scaling``.  With
+    ``n_group`` > 1 the selection is group-limited (the ``n_group`` /
+    ``topk_group`` of the released code): the experts lie in ``n_group``
+    consecutive groups, a group's score is the sum of its two largest
+    ``s + b``, only the ``topk_group`` best groups stay, and the
+    ``top_k`` are the largest ``s + b`` inside them (an expert outside
+    them counts as -inf; the released code fills 0.0, which differs only
+    where fewer than ``top_k`` of the ``s + b`` that stay are positive).
+    The output is
     ``sum over selected AND held experts of gate * expert(x)``, plus the
     shared experts; what the absent experts would add is left out (one
     chip's share of an expert-parallel layer, without its exchange).
@@ -345,12 +353,21 @@ class HeldExpertsMoE(Op):
                  top_k: int, held=None, num_shared: int = 0,
                  scaling: float = 1.0, bias_update_speed: float = 0.0,
                  kernel_initializer=None, compute_dtype=None,
-                 score_func: str = "sigmoid", shared_gated: bool = False):
+                 score_func: str = "sigmoid", shared_gated: bool = False,
+                 n_group: int = 1, topk_group: int = 1):
         super().__init__(name, [input_tensor])
         assert score_func in ("sigmoid", "softmax"), score_func
         self.score_func = score_func
         self.shared_gated = bool(shared_gated)
         self.num_experts = int(num_experts)
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        assert self.num_experts % self.n_group == 0 \
+            and 1 <= self.topk_group <= self.n_group
+        # the selection has to fit inside the groups that stay, and a
+        # group's score is the sum of its two largest
+        assert self.n_group == 1 or (
+            self.num_experts // self.n_group >= 2 and int(top_k)
+            <= self.topk_group * (self.num_experts // self.n_group))
         first, count = held if held is not None else (0, self.num_experts)
         self.first_held, self.num_held = int(first), int(count)
         assert 0 <= self.first_held \
@@ -418,8 +435,19 @@ class HeldExpertsMoE(Op):
             scores = jax.nn.softmax(logits, axis=-1)
         else:
             scores = jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias),
-                               self.top_k)
+        choice = scores + jax.lax.stop_gradient(bias)
+        if self.n_group > 1:
+            # group-limited: the experts lie in n_group consecutive
+            # groups, a group scores the sum of its two largest, the
+            # topk_group best groups stay and the others cannot be chosen
+            grouped = choice.reshape(choice.shape[:-1] + (self.n_group, -1))
+            group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+            _, kept = jax.lax.top_k(group_score, self.topk_group)
+            stays = jnp.any(kept[..., None] == jnp.arange(self.n_group),
+                            axis=-2)
+            choice = jnp.where(stays[..., None], grouped,
+                               -jnp.inf).reshape(choice.shape)
+        _, idx = jax.lax.top_k(choice, self.top_k)
         picked = jnp.take_along_axis(scores, idx, axis=-1)
         gates = self.scaling * picked / jnp.sum(picked, axis=-1,
                                                 keepdims=True)

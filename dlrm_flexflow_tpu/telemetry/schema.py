@@ -370,10 +370,13 @@ SCHEMA: Dict[str, dict] = {
     # ops/pallas_deltanet.py and how many in the chunked ``jax.numpy``
     # form, ``{"pallas": 3, "chunked": 0}`` on a TPU at 16,384 tokens
     # and 128-wide heads, ``{"pallas": 0, "chunked": 3}`` on the CPU
-    # (ops/deltanet.py ``core_form``).
+    # (ops/deltanet.py ``core_form``).  ``kda_core``: the like count for
+    # ``KimiDeltaAttention`` ops, whose rule (a decay per key channel)
+    # has one form on every backend: ``{"chunked": 6}``.
     "program": {
         "required": {"name": str},
-        "optional": {"fn": str, "attention_core": dict, "gdn_core": dict},
+        "optional": {"fn": str, "attention_core": dict, "gdn_core": dict,
+                     "kda_core": dict},
     },
     # what one dispatch of FFModel.train_epoch / train_epochs counted
     # inside one op that keeps counters in its state (ops/moe.py

@@ -332,6 +332,60 @@ class TestAttention:
         np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
 
 
+class TestHeldShares:
+    """A mixer's share of the heads and an expert layer's groups (PR 37):
+    the arguments' bounds and the parameter shapes they give;
+    ``tests/test_kda_moe_lm.py`` has the numbers."""
+
+    @pytest.mark.parametrize("held,want", [(None, 8), (4, 4), (1, 1)])
+    def test_held_heads(self, held, want):
+        from dlrm_flexflow_tpu.ops.base import held_heads
+        assert held_heads(held, 8) == want
+
+    @pytest.mark.parametrize("held", [9, -1, 0])
+    def test_held_heads_outside_the_mixer_are_refused(self, held):
+        from dlrm_flexflow_tpu.ops.base import held_heads
+        with pytest.raises(AssertionError):
+            held_heads(held, 8)
+
+    @pytest.mark.parametrize("held,heads", [(None, 8), (2, 2)])
+    def test_both_mixers_size_their_parameters_by_the_heads_held(self, held,
+                                                                 heads):
+        m = ff.FFModel(ff.FFConfig(batch_size=1))
+        x = m.create_tensor((1, 16, 32), name="x")
+        m.kimi_delta_attention(x, 8, 4, 6, heads_held=held, name="kda")
+        m.latent_attention(x, 8, None, 16, 4, 2, 6, qk_norm=True,
+                           gate="head_wise", heads_held=held, name="mla")
+        kda, mla = m.layers
+        shapes = {s.param_name: s.shape for s in kda.param_specs()}
+        assert shapes["w_q"] == shapes["w_f"] == (32, heads * 4)
+        assert shapes["w_v"] == shapes["w_g"] == (32, heads * 6)
+        assert shapes["w_beta"] == (32, heads)
+        assert shapes["conv_k"] == (4, heads * 4)
+        assert shapes["a_log"] == (heads,)
+        assert shapes["dt_bias"] == (heads * 4,)
+        assert shapes["w_out"] == (heads * 6, 32)
+        shapes = {s.param_name: s.shape for s in mla.param_specs()}
+        assert shapes["w_q"] == (32, heads * 6)
+        assert shapes["w_kva"] == (32, 18) and shapes["kv_norm"] == (16,)
+        assert shapes["w_kvb"] == (16, heads * 10)
+        assert shapes["q_head_norm"] == shapes["k_head_norm"] == (6,)
+        assert shapes["w_gate"] == (32, heads)
+        assert shapes["w_o"] == (heads * 6, 32)
+        assert kda.outputs[0].shape == mla.outputs[0].shape == (1, 16, 32)
+        assert kda.core_form() == "chunked"
+
+    @pytest.mark.parametrize("groups,kept,top_k", [(3, 1, 2), (4, 5, 2),
+                                                   (8, 1, 4), (16, 4, 2)])
+    def test_groups_the_selection_cannot_fit_are_refused(self, groups, kept,
+                                                         top_k):
+        m = ff.FFModel(ff.FFConfig(batch_size=1))
+        x = m.create_tensor((1, 16, 32), name="x")
+        with pytest.raises(AssertionError):
+            m.held_experts_moe(x, 16, 8, top_k, n_group=groups,
+                               topk_group=kept)
+
+
 class TestActivationDtype:
     """FFConfig.activation_dtype="bfloat16" (bf16 activation STORAGE
     between ops — the conv-net bandwidth lever, PERF.md round 3): the
