@@ -31,6 +31,7 @@ task-per-op translation.
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -76,6 +77,12 @@ def _fold_steps(name: str, per_step, counter_rank=None):
     steps = tuple(range(per_step.ndim - counter_rank))
     return (jnp.max if name.endswith("_max") else jnp.sum)(per_step,
                                                            axis=steps)
+
+
+#: one checkpoint policy object a set of names, for the runs that share
+#: it (``Op.shares_recompute_policy``)
+_shared_policy = functools.lru_cache(maxsize=None)(
+    jax.checkpoint_policies.save_only_these_names)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -605,8 +612,10 @@ class FFModel:
             return [local[u] for u in out_uids], nb
 
         keep = sorted({n for _, op in run for n in op.saved_in_recompute})
-        policy = (jax.checkpoint_policies.save_only_these_names(*keep)
-                  if keep else None)
+        policy_of = (_shared_policy
+                     if any(op.shares_recompute_policy for _, op in run)
+                     else jax.checkpoint_policies.save_only_these_names)
+        policy = policy_of(*keep) if keep else None
         outs, nb = jax.checkpoint(body, policy=policy)(
             {n: params[n] for n in names}, [values[u] for u in in_uids],
             {n: bn_state[n] for n in stateful} if bn_state else {}, rng)

@@ -106,6 +106,12 @@ class Op:
     #: ``jax.ad_checkpoint.checkpoint_name`` names inside ``forward`` that
     #: a recomputed run keeps instead of computing again
     saved_in_recompute: tuple = ()
+    #: runs that hold such an op share ONE checkpoint policy object for
+    #: their names: JAX caches a run's partial evaluation of a
+    #: ``jax.jit`` callee by the policy's identity, so a callee every
+    #: instance calls (``ops/deltanet.py``'s KDA kernels) is partially
+    #: evaluated and lowered once a program, not once a run
+    shares_recompute_policy: bool = False
 
     def __init_subclass__(cls, **kwargs):
         # every subclass's forward runs under jax.named_scope(op.name)

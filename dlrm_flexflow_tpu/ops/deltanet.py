@@ -59,11 +59,12 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..initializers import (ConstantInitializer, DEFAULT_KERNEL_INIT,
                             Initializer, UniformInitializer)
 from ..tensor import ParameterSpec
-from . import pallas_deltanet
+from . import pallas_deltanet, pallas_kda
 from .base import Op, held_heads, matmul
 
 #: tokens of one chunk (a power of two): the released kernels' size.
@@ -409,6 +410,45 @@ def _channel_chunk_operands(q, k, v, g, beta, cd):
             jnp.exp(total[..., 0, :]), w[..., dv:].astype(cd), w[..., :dv])
 
 
+def kimi_core_form(seq: int, dk: int, dv: int, dtype) -> str:
+    """Which form ``kimi_delta_rule`` runs at these shapes: ``"pallas"``
+    on a TPU where ``pallas_kda.takes`` them, else ``"chunked"``.
+    Decided from what the trace can see, and by nothing else (the
+    model's ``program`` events count it)."""
+    fused = _on_tpu() and pallas_kda.takes(seq, dk, dv, dtype)
+    return "pallas" if fused else "chunked"
+
+
+#: what a recomputed run (``FFModel.scope(recompute=...)``) keeps of the
+#: rule's Pallas form: its output and the (transposed) state at every
+#: chunk's start (67 + 134 MB a layer in f32 at 16 heads x 8,192 tokens
+#: x 128), so that the backward pass rebuilds the projections and gates
+#: but never runs the forward kernel a second time
+KDA_SAVED = ("kda_core_out", "kda_core_starts")
+
+
+@jax.custom_vjp
+def _kimi_pallas(q, k, v, g, beta):
+    return pallas_kda.forward(q, k, v, g, beta)[0]
+
+
+def _kimi_pallas_fwd(q, k, v, g, beta):
+    o, starts = pallas_kda.forward(q, k, v, g, beta)
+    o = checkpoint_name(o, KDA_SAVED[0])
+    starts = checkpoint_name(starts, KDA_SAVED[1])
+    return o, (q, k, v, g, beta, starts)
+
+
+def _kimi_pallas_bwd(res, do):
+    return pallas_kda.backward(*res, do.astype(jnp.float32))
+
+
+_kimi_pallas.defvjp(_kimi_pallas_fwd, _kimi_pallas_bwd)
+#: one callee for every layer: the kernels are traced and lowered once a
+#: program (and each of its derivatives once), not once a call site
+_kimi_pallas_rule = jax.jit(_kimi_pallas)
+
+
 def kimi_delta_rule(q, k, v, g, beta, compute_dtype=None):
     """The delta rule with a decay for every key channel (Kimi Delta
     Attention: Kimi Linear, arXiv:2510.26692 section 3), over whole
@@ -422,18 +462,24 @@ def kimi_delta_rule(q, k, v, g, beta, compute_dtype=None):
 
     ``q``, ``k``: (B, S, H, dk), already normalised and scaled; ``v``:
     (B, S, H, dv); ``g``: (B, S, H, dk) f32; ``beta``: (B, S, H) f32.
-    Returns (B, S, H, dv) f32.  ``gated_delta_rule``'s chunked form (its
-    layout, its walk over the chunks, its hand-written backward that
-    keeps the inputs and the state at each chunk's start) with the
-    operands of ``_channel_chunk_operands``; with ``g`` constant over
-    the channels it is that function.  A sequence the chunk does not
-    divide is padded behind its end with tokens that change nothing
-    (``beta`` 0, ``g`` 0).  There is one form, ``"chunked"``, on every
-    backend (``KimiDeltaAttention.core_form``)."""
+    Returns (B, S, H, dv) f32.  Two forms, chosen by ``kimi_core_form``
+    from the backend and the shapes alone.  On a TPU at shapes
+    ``pallas_kda.takes``: one Pallas kernel forward and one backward
+    (``ops/pallas_kda.py``), through one ``jax.jit`` callee that every
+    layer shares, keeping its output and boundary states through a
+    recomputation (``KDA_SAVED``).  Anywhere else: the chunked form,
+    ``gated_delta_rule``'s (its layout, its walk over the chunks, its
+    hand-written backward that keeps the inputs and the state at each
+    chunk's start) with the operands of ``_channel_chunk_operands``; with
+    ``g`` constant over the channels it is that function, and a sequence
+    the chunk does not divide is padded behind its end with tokens that
+    change nothing (``beta`` 0, ``g`` 0)."""
     cd = jnp.dtype(compute_dtype or jnp.float32)
-    return _in_whole_chunks(q, k, v, g.astype(jnp.float32),
-                            beta.astype(jnp.float32), cd,
-                            _channel_chunk_operands)
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    if kimi_core_form(q.shape[1], q.shape[3], v.shape[3], cd) == "pallas":
+        return _kimi_pallas_rule(q.astype(cd), k.astype(cd), v.astype(cd),
+                                 g, beta)
+    return _in_whole_chunks(q, k, v, g, beta, cd, _channel_chunk_operands)
 
 
 # --------------------------------------------------------------- the mixer
@@ -609,11 +655,15 @@ class KimiDeltaAttention(Op):
 
     Scopes as ``GatedDeltaNet``'s (``ff.kda`` without a phase):
     ``.proj``, ``.conv``, ``.core`` (normalisation of q and k, both
-    gates, the rule), ``.gate``.
+    gates, the rule in the form ``core_form`` names), ``.gate``.  A
+    recomputed run keeps the Pallas form's output and boundary states
+    (``KDA_SAVED``), and the runs share one policy object so that the
+    rule's kernels are lowered once a program.
     """
 
     op_type = "KimiDeltaAttention"
-    core_field, core_forms = "kda_core", ("chunked",)
+    saved_in_recompute, shares_recompute_policy = KDA_SAVED, True
+    core_field, core_forms = "kda_core", ("pallas", "chunked")
 
     def __init__(self, name, input_tensor, num_heads: int, head_k_dim: int,
                  head_v_dim: int, conv_kernel: int = 4,
@@ -658,7 +708,10 @@ class KimiDeltaAttention(Op):
     _cd = GatedDeltaNet._cd          # reads compute_dtype alone
 
     def core_form(self) -> str:
-        return "chunked"
+        """The form this op's rule takes on this backend
+        (``kimi_core_form`` at its shapes)."""
+        return kimi_core_form(self.inputs[0].shape[1], self.dk, self.dv,
+                              self._cd)
 
     def forward(self, params, xs, *, training=False, rng=None):
         (x,) = xs

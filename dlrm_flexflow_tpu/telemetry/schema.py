@@ -371,8 +371,11 @@ SCHEMA: Dict[str, dict] = {
     # form, ``{"pallas": 3, "chunked": 0}`` on a TPU at 16,384 tokens
     # and 128-wide heads, ``{"pallas": 0, "chunked": 3}`` on the CPU
     # (ops/deltanet.py ``core_form``).  ``kda_core``: the like count for
-    # ``KimiDeltaAttention`` ops, whose rule (a decay per key channel)
-    # has one form on every backend: ``{"chunked": 6}``.
+    # ``KimiDeltaAttention`` ops (a decay per key channel): how many run
+    # the rule as the two Pallas kernels of ops/pallas_kda.py and how
+    # many in the chunked form, ``{"pallas": 6, "chunked": 0}`` on a TPU
+    # in the Ling-style cell, ``{"pallas": 0, "chunked": 6}`` on the CPU
+    # (ops/deltanet.py ``kimi_core_form``).
     "program": {
         "required": {"name": str},
         "optional": {"fn": str, "attention_core": dict, "gdn_core": dict,

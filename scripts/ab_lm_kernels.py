@@ -1,7 +1,7 @@
 """On the chip: the two kernels of the language-model family at the
 shapes of ``joyai-flash-ep16.pretrain-8k``, each beside its rival.
 
-    chiprun -- python scripts/ab_lm_kernels.py [attn[:row,row]] [core] [gmm] [rows] [slabs] [gdn[:row,row]] [gdncore] [gqa[:row,row]]
+    chiprun -- python scripts/ab_lm_kernels.py [attn[:row,row]] [core] [gmm] [rows] [slabs] [gdn[:row,row]] [gdncore] [gqa[:row,row]] [kda[:row,row]] [kdacore]
 
 attn: the causal attention core (32 heads, 8,192 tokens, query/key
 width 192, value width 128, bf16), forward and forward + backward, one
@@ -88,6 +88,18 @@ v5e, forward / forward + backward ms: chunked 23.70 / 52.82; KERNELS
 ``fori_loop``, 13.98 / 33.29 and unrolled 12.76 / 30.45.
 gdncore (PR 36): ``core`` for the hybrid cell: the ``program`` event's
 ``gdn_core`` and one DeltaNet layer's kernels by phase.
+kda (PR 39): the delta rule with a decay per key channel at the shapes
+of ``ling3-flash-ep64.pretrain-8k`` (16 heads, 8,192 tokens, 128 wide,
+bf16; the gate's decays at the cell's initialisation), forward and
+forward + backward: the Pallas kernels of ``ops/pallas_kda.py`` (what
+the program runs on a TPU) at blocks of 128, 256 and 512 tokens, and
+the chunked form (``kimi_delta_rule`` off the TPU, the parent's), each
+row's largest |o, dq, dk, dv, dg, dbeta| differences from the token
+recurrence in f32 on the same rounded operands over the first 2,048
+tokens of the 2 heads of largest ``A`` (whose slowest channels keep
+their state longest).  PERF.md section 6, PR 39 has the numbers.
+kdacore (PR 39): ``core`` for the Ling-style cell: the ``program``
+event's ``kda_core`` and two KDA layers' kernels by phase.
 gqa (PR 35): grouped-head attention at 16 query heads on 2 key/value
 heads, 16,384 tokens, 256 wide, bf16: the repo's kernels reading each
 group's key/value head in place (what the program runs), the same
@@ -270,6 +282,8 @@ _CORES = {
              "attention_core", "ff.lm.mla.core"),
     "gdncore": ("gdn_moe_lm", "qwen3-next-ep16", "pretrain-16k",
                 {"num_hidden_layers": 1}, "gdn_core", "ff.lm.gdn.core"),
+    "kdacore": ("kda_moe_lm", "ling3-flash-ep64", "pretrain-8k-kda",
+                {"num_hidden_layers": 2}, "kda_core", "ff.lm.kda.core"),
 }
 
 
@@ -691,6 +705,88 @@ def gdn(only=(), hk=16, hv=32, s=16384, d=128):
     pallas_deltanet._inverses = whole_inverses
 
 
+def kda(only=(), h=16, s=8192, d=128):
+    from benchmarks.reference import kda_moe_lm_ref as ref
+    from dlrm_flexflow_tpu.ops import deltanet, pallas_kda
+    shipped, on_tpu = pallas_kda.BLOCK, deltanet._on_tpu
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
+    bf = jnp.bfloat16
+    q = (deltanet.l2_normalised(jax.random.normal(keys[0], (1, s, h, d)))
+         * d ** -0.5).astype(bf)
+    k = deltanet.l2_normalised(jax.random.normal(keys[1],
+                                                 (1, s, h, d))).astype(bf)
+    v = (0.5 * jax.random.normal(keys[2], (1, s, h, d))).astype(bf)
+    # the cell's gate at its initialisation: lower_bound sigmoid(exp(A_log)
+    # (x W_f + dt_bias)), A = U(0, 16), dt_bias = 1, lower_bound = -5
+    a = jax.random.uniform(keys[3], (h,), minval=1e-3, maxval=16.0)
+    g = -5.0 * jax.nn.sigmoid(
+        a[:, None] * (jax.random.normal(keys[4], (1, s, h, d)) + 1.0))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (1, s, h)))
+    w = jax.random.normal(keys[6], (1, s, h, d), jnp.float32)
+    args = (q, k, v, g, beta)
+    flops = 3 * s * h * 6 * d * d
+
+    def chunked(*xs):       # the parent's form, whatever the backend
+        deltanet._on_tpu = lambda: False
+        return deltanet.kimi_delta_rule(*xs, compute_dtype=bf)
+
+    def kernels(block):
+        """``ops/pallas_kda.py`` at another block (the jit callee keeps
+        its traced kernels: every row starts from cleared caches)."""
+        def rule(*xs):
+            pallas_kda.BLOCK = block
+            deltanet._on_tpu = lambda: True
+            return deltanet.kimi_delta_rule(*xs, compute_dtype=bf)
+        return rule
+
+    forms = {"pallas kernels, blocks of %d (the program on a TPU)"
+             % shipped: kernels(shipped),
+             **{"pallas kernels, blocks of %d" % n: kernels(n)
+                for n in (128, 256, 512) if n != shipped},
+             "chunked: chunks of 64, sub-blocks of 16, own backward":
+             chunked}
+    # the heads whose states live longest: at a large A a channel whose
+    # x W_f + dt_bias is below 0 has g near 0 and keeps its state
+    heads, tokens = 2, min(2048, s)
+    slow = np.argsort(np.asarray(a))[-heads:]
+    part = (q[:, :tokens, slow], k[:, :tokens, slow], v[:, :tokens, slow],
+            g[:, :tokens, slow], beta[:, :tokens, slow])
+    w_part = w[:, :tokens, slow]
+
+    def recurrence(q, k, v, g, beta):
+        with jax.default_matmul_precision("highest"):
+            return jax.vmap(ref.delta_rule)(
+                q.astype(jnp.float32), k.astype(jnp.float32),
+                v.astype(jnp.float32), g, beta)
+    grads_of = lambda f, w: jax.jit(jax.value_and_grad(
+        lambda *xs: (lambda o: (jnp.sum(o * w), o))(f(*xs)),
+        tuple(range(5)), has_aux=True))
+    (_, o_ref), g_ref = grads_of(recurrence, w_part)(*part)
+
+    def measure(name, rule):
+        jax.clear_caches()
+        fwd = jax.jit(rule)
+        both = jax.jit(jax.value_and_grad(
+            lambda *xs: jnp.sum(rule(*xs) * w), tuple(range(5))))
+        ms_f, _ = timed(fwd, *args)
+        ms_b, _ = timed(both, *args)
+        print(f"kda {name}: fwd {ms_f:.2f} ms, fwd+bwd {ms_b:.2f} ms "
+              f"({flops / ms_b / 1e9:.2f} TFLOP/s of the recurrence's "
+              f"own work)", flush=True)
+        (_, o), grads = grads_of(rule, w_part)(*part)
+        errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                      - b.astype(jnp.float32))))
+                for a, b in zip([o, *grads], [o_ref, *g_ref])]
+        sizes = [float(jnp.max(jnp.abs(b))) for b in [o_ref, *g_ref]]
+        print(f"kda {name}: max |o, dq, dk, dv, dg, dbeta| difference from "
+              f"the token recurrence, {tokens} tokens x {heads} heads: "
+              + ", ".join(f"{e:.3g}" for e in errs) + " (of "
+              + ", ".join(f"{x:.3g}" for x in sizes) + ")", flush=True)
+
+    _run_rows("kda", forms, only, measure)
+    pallas_kda.BLOCK, deltanet._on_tpu = shipped, on_tpu
+    jax.clear_caches()
+
 def gqa(only=(), h=16, kv=2, s=16384, d=256):
     from dlrm_flexflow_tpu.ops import attention
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -764,7 +860,7 @@ if __name__ == "__main__":
             core(name)
             continue
         {"attn": attn, "gmm": gmm, "rows": rows,
-         "slabs": slabs, "gdn": gdn, "gqa": gqa}[name](
+         "slabs": slabs, "gdn": gdn, "gqa": gqa, "kda": kda}[name](
             *([only.split(",")] if only else []))
         if name == "gmm":   # and over one slab of the layer (PR 32)
             from dlrm_flexflow_tpu.ops.moe import slab_rows

@@ -684,7 +684,7 @@ def test_the_program_event_names_the_kda_cores_form():
         model.train_epoch(state, inputs, labels)
     events = [e for e in log.events() if e["type"] == "program"]
     assert [(e["kda_core"], e["attention_core"]) for e in events] \
-        == [({"chunked": 2}, {"pallas": 0, "plain": 1})]
+        == [({"pallas": 0, "chunked": 2}, {"pallas": 0, "plain": 1})]
     assert validate_event(events[0]) == []
     counted = [e for e in log.events() if e["type"] == "op_counters"]
     assert len(counted) == 2
@@ -863,7 +863,7 @@ def test_without_a_share_it_is_the_whole_published_language_model():
     assert 123e9 < total < 127e9          # "about 125B parameters"
     active = total - 40 * 504 * 3 * 2560 * 768
     assert 5.3e9 < active < 5.7e9          # "5.5B active"
-    assert model._program_fields["kda_core"] == {"chunked": 35}
+    assert model._program_fields["kda_core"] == {"pallas": 0, "chunked": 35}
 
 
 @pytest.mark.parametrize("key,value", [
