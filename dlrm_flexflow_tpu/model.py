@@ -55,7 +55,7 @@ from .parallel.mesh import (DATA_AXIS, MODEL_AXIS, constrain, make_mesh,
 from .parallel.parallel_config import Strategy
 from .profiling import note_program
 from .row_cache import CacheOp, CachePolicy, RowCache
-from .telemetry import active_log, sample_memory
+from .telemetry import active_log, sample_memory, setup_mark, setup_span
 from .telemetry import fleet as _fleet
 from .telemetry import metrics as _tmetrics
 from .telemetry import rowfreq as _rowfreq
@@ -110,6 +110,7 @@ class FFModel:
     """Graph-builder with the reference's factory API (model.h:294-436)."""
 
     def __init__(self, config: Optional[FFConfig] = None):
+        setup_mark("setup.model")  # the program's set-up starts here
         self.config = config or FFConfig()
         self.layers: List[Op] = []
         self.strategy = Strategy()
@@ -644,6 +645,7 @@ class FFModel:
         return values, new_bn
 
     # ---------------------------------------------------------------- compile
+    @setup_span("setup.graph")
     def compile(self, optimizer: Optional[Optimizer] = None,
                 loss_type: str = "mean_squared_error",
                 metrics: Sequence[str] = ("accuracy",),

@@ -6,8 +6,9 @@ typed, schema-checked events from every layer of the framework:
   * ``step``    — epoch/window wall time, samples/s, loss, metric means
                   (FFModel.fit / train_epoch / bench.py windows)
   * ``compile`` — XLA compiles (jit cache misses) observed through
-                  jax.monitoring hooks, plus fit's AOT compiles with
-                  their donated-argument counts
+                  jax.monitoring hooks, each with its program and
+                  whether the persistent cache served it, plus fit's
+                  AOT compiles with their donated-argument counts
   * ``memory``  — per-device live-bytes watermarks sampled around steps
   * ``search``  — MCMC strategy-search trajectory and simulator
                   calibration fits (sim/search.py, sim/simulator.py)
@@ -33,6 +34,12 @@ typed, schema-checked events from every layer of the framework:
   * ``slo``     — serving SLO evaluations, multi-window burn-rate
                   breaches, and recoveries (telemetry/slo.py,
                   docs/slo.md)
+
+The set-up has a timeline of its own, kept whether or not a log is
+active (``jax_hooks``): every trace, lowering, compile and cache load
+JAX reports, by program, on ``time.perf_counter``, beside the
+program's ``setup.model`` mark and ``setup.graph`` span;
+``setup_breakdown(until)`` reads one set-up from it.
 
 Multi-host runs write one ``telemetry_pNNN.jsonl`` sink per process,
 stamped with ``pidx``/``slice`` (``fleet_event_log``); ``report`` on
@@ -60,7 +67,8 @@ from .fleet import (dump_flight_record, find_flight_records,
                     fleet_data, fleet_event_log, fleet_stamp,
                     load_fleet_events, load_flight_record,
                     process_sink_path)
-from .jax_hooks import compile_stats, install_compile_hooks
+from .jax_hooks import (compile_stats, install_compile_hooks,
+                        setup_breakdown, setup_mark, setup_span, timeline)
 from .rowfreq import RowFreqCounter, hot_rows
 from .schema import SCHEMA, SCHEMA_VERSION, validate_event
 from .slo import SLO, SLOMonitor, parse_slos
@@ -70,7 +78,8 @@ from .trace import (NULL_SPAN, Span, current_span, open_span_records,
 __all__ = [
     "EventLog", "active_log", "emit", "event_log",
     "sample_memory", "set_event_log", "suppressed", "compile_stats",
-    "install_compile_hooks", "SCHEMA", "SCHEMA_VERSION", "validate_event",
+    "install_compile_hooks", "setup_breakdown", "setup_mark",
+    "setup_span", "timeline", "SCHEMA", "SCHEMA_VERSION", "validate_event",
     "NULL_SPAN", "Span", "current_span", "open_span_records",
     "record_span", "span", "start_span",
     "dump_flight_record", "find_flight_records", "fleet_data",

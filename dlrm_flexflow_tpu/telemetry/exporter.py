@@ -242,7 +242,7 @@ def chrome_trace(events: List[dict]) -> dict:
             name = f"compile:{e.get('fn', e.get('kind', '?'))}"
             out.append(_x(name, (ts - dur - t0) * 1e6, dur * 1e6,
                           _TRACK_COMPILES, "compile",
-                          {"kind": e.get("kind")}))
+                          {k: e[k] for k in ("kind", "stage") if k in e}))
         elif t == "op_time":
             fwd = float(e["forward_s"])
             args = {k: e[k] for k in ("backward_s", "sim_forward_s")

@@ -217,9 +217,12 @@ def compile_timeline(events: List[dict]) -> List[str]:
     # would double-count every AOT build's compile wall
     misses = [e for e in comps if e["kind"] == "backend_compile"]
     aots = [e for e in comps if e["kind"] == "aot"]
+    loads = sum(e.get("stage") == "cache_load" for e in misses)
     head = (f"{len(misses)} backend compiles (jit cache misses), "
             f"{sum(e['duration_s'] for e in misses):.2f}s total compile "
             f"wall")
+    if loads:
+        head += f", {loads} of them loaded from the persistent cache"
     if aots:
         head += (f"; {len(aots)} AOT builds "
                  f"({sum(e['duration_s'] for e in aots):.2f}s "
@@ -229,6 +232,8 @@ def compile_timeline(events: List[dict]) -> List[str]:
         extra = ""
         if "fn" in e:
             extra += f" fn={e['fn']}"
+        if "stage" in e:
+            extra += f" stage={e['stage']}"
         if "donated_args" in e:
             extra += f" donated_args={e['donated_args']}"
         lines.append(f"  t+{e['ts'] - t0:8.2f}s  {e['kind']:16s} "
@@ -941,6 +946,8 @@ def report_data(events: List[dict],
         headline["compile"] = {
             "backend_compiles": len(misses),
             "backend_compile_s": sum(e["duration_s"] for e in misses),
+            "cache_loads": sum(e.get("stage") == "cache_load"
+                               for e in misses),
             "aot_builds": len(aots),
             "aot_s": sum(e["duration_s"] for e in aots)}
     fits = [e for e in by.get("calibration", [])

@@ -64,10 +64,13 @@ SCHEMA: Dict[str, dict] = {
     # one XLA compilation (jit cache miss).  ``kind`` is
     # "backend_compile" for hook-observed compiles and "aot" for
     # FFModel.fit's explicit lower().compile() calls (which also know
-    # the donated-argument count).
+    # the donated-argument count).  A backend compile names its program
+    # (``fn``) and its ``stage``: "compile", or "cache_load" where the
+    # persistent cache served the executable.
     "compile": {
         "required": {"kind": str, "duration_s": float},
-        "optional": {"fn": str, "donated_args": int, "backend": str},
+        "optional": {"fn": str, "stage": str, "donated_args": int,
+                     "backend": str},
     },
     # per-device live-bytes watermark sampled around a step.  ``source``
     # is "memory_stats" on backends that expose allocator stats (TPU) or

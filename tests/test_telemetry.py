@@ -285,6 +285,161 @@ class TestCompileEvents:
         assert stats.get("backend_compile", 0) >= 1
         assert stats.get("backend_compile_s", 0.0) > 0
 
+    def test_backend_compile_s_is_every_compile_wall_and_names_it(self):
+        """What ``compile_s`` reads keeps its meaning: the sum of the
+        backend compiles' walls, one per ``compile`` event, which now
+        names its program and stage."""
+        from dlrm_flexflow_tpu.telemetry import compile_stats
+
+        def h(v):
+            return v * 3 - 1
+
+        x = jnp.ones((11,))
+        before = compile_stats()
+        with event_log() as log:
+            jax.jit(h)(x)
+        after = compile_stats()
+        comps = log.events("compile")
+        assert [(e["fn"], e["stage"]) for e in comps] == [("h", "compile")]
+        assert after["backend_compile"] - before["backend_compile"] == 1
+        assert after["backend_compile_s"] - before["backend_compile_s"] \
+            == pytest.approx(comps[0]["duration_s"])
+        assert "fn=h stage=compile" in format_report(log.events())
+
+
+# ------------------------------------------------------ the set-up timeline
+
+class TestSetupTimeline:
+    """``jax_hooks``' set-up timeline and ``setup_breakdown``."""
+
+    @pytest.fixture(autouse=True)
+    def _hooks(self):
+        from dlrm_flexflow_tpu.telemetry import install_compile_hooks
+        install_compile_hooks()
+
+    def test_a_nested_trace_counts_once(self):
+        import time
+
+        from dlrm_flexflow_tpu.telemetry import (compile_stats,
+                                                 setup_breakdown, setup_mark)
+
+        @jax.jit
+        def inner(v):
+            time.sleep(0.2)
+            return v + 1
+
+        @jax.jit
+        def outer(v):
+            return inner(v) * 2
+
+        x = jnp.ones((13,))
+        before = compile_stats()
+        setup_mark("setup.model")
+        outer(x)
+        b = setup_breakdown()
+        traced = compile_stats()["jaxpr_trace_s"] - before["jaxpr_trace_s"]
+        assert 0.2 <= b["trace_s"] < 0.3
+        assert 0.2 <= traced < 0.3          # was 0.4: inner twice
+        # the per-program table keeps both names, each with its own wall
+        assert b["programs"]["inner"]["trace_s"] >= 0.2
+        assert b["programs"]["outer"]["trace_s"] >= 0.2
+        assert b["programs"]["outer"]["compiles"] == 1
+        assert b["trace_s"] + b["lower_s"] + b["compile_s"] <= b["own_s"]
+
+    def test_a_persistent_cache_load_is_loaded_not_compiled(self, tmp_path):
+        from jax._src import compilation_cache
+
+        from dlrm_flexflow_tpu.telemetry import setup_breakdown, setup_mark
+
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs")
+        saved = {k: getattr(jax.config, k) for k in keys}
+        jax.config.update(keys[0], str(tmp_path))
+        jax.config.update(keys[1], 0.0)
+        compilation_cache.reset_cache()
+        try:
+            def cached(v):
+                return jnp.sin(v) @ v
+
+            x = jnp.ones((17,))
+            seen = []
+            for _ in range(2):  # one call site: the cache key holds it
+                jax.clear_caches()
+                setup_mark("setup.model")
+                with event_log() as log:
+                    jax.jit(cached)(x)
+                seen.append(setup_breakdown())
+            first, again = seen
+        finally:
+            for k, v in saved.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+        assert first["programs"]["cached"]["compiles"] == 1
+        assert first["cache_load_s"] == 0
+        row = again["programs"]["cached"]
+        assert (row["compiles"], row["loaded"]) == (0, 1)
+        assert again["compiled_programs"] == 0
+        assert again["loaded_programs"] == 1
+        assert 0 < again["cache_load_s"] <= again["compile_s"]
+        assert row["cache_load_s"] == pytest.approx(again["cache_load_s"])
+        (event,) = [e for e in log.events("compile") if e["fn"] == "cached"]
+        assert event["stage"] == "cache_load"
+
+    def test_the_breakdown_starts_at_the_newest_model(self):
+        import time
+
+        from dlrm_flexflow_tpu.telemetry import setup_breakdown, timeline
+
+        first = mlp_model()
+        first.compile()
+        first.init(seed=0)
+        between = time.perf_counter()
+        second = mlp_model(widths=(16, 24, 8))
+        second.compile()
+        second.init(seed=0)
+        now = setup_breakdown()
+        marks = [r["start"] for r in timeline()
+                 if r["stage"] == "setup.model"]
+        assert now["start"] == marks[-1] > between
+        assert setup_breakdown(between)["start"] == marks[-2] < between
+        assert now["graph_s"] > 0 and now["compiled_programs"] >= 1
+        assert now["own_s"] < setup_breakdown(between)["own_s"] \
+            + now["own_s"]
+
+    def test_setup_graph_is_a_span_only_while_a_log_is_on(self):
+        from dlrm_flexflow_tpu.telemetry import timeline
+
+        m = mlp_model()
+        m.compile()
+        assert timeline()[-1]["stage"] == "setup.graph"
+        with event_log() as log:
+            m = mlp_model()
+            m.compile()
+        (sp,) = log.events("span")
+        assert sp["name"] == "setup.graph" and sp["status"] == "ok"
+
+    def test_the_bound_folds_what_it_evicts(self, monkeypatch):
+        from dlrm_flexflow_tpu.telemetry import (jax_hooks, setup_breakdown,
+                                                 setup_mark)
+
+        import time
+
+        monkeypatch.setattr(jax_hooks, "_records", jax_hooks.deque())
+        monkeypatch.setattr(jax_hooks, "MAX_RECORDS", 4)
+        setup_mark("setup.model")
+        t = time.perf_counter()
+        for i in range(6):   # six compiles of 10 ms, written to no cache
+            jax_hooks._append(jax_hooks._Record(
+                "compile", f"p{i}", t + 0.01 * i, t + 0.01 * (i + 1)))
+        b = setup_breakdown(t + 1.0)
+        assert len(jax_hooks.timeline()) == 4
+        assert b["overflow"] == 3    # the mark and two compiles
+        assert b["compiled_programs"] == 6
+        assert b["compile_s"] == pytest.approx(0.06)
+        time.sleep(0.07)
+        setup_mark("setup.model")    # a new set-up: nothing of it lost
+        assert setup_breakdown()["overflow"] == 0
+
 
 # --------------------------------------------------------- search recording
 
