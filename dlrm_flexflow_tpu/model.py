@@ -917,13 +917,24 @@ class FFModel:
         # beside their name: how many attention cores (``attention_core``),
         # DeltaNet cores (``gdn_core``) and KDA cores (``kda_core``) took
         # which form (the op's ``core_form``; shapes and backend, so
-        # known here)
+        # known here); and ``core_kept``: per core field, how many of
+        # them run their kernel form (the first of ``core_forms``) in a
+        # recomputed run that keeps what the kernels name
+        # (``saved_in_recompute``), so the recomputation never runs
+        # their forward kernel again
         self._program_fields = {}
+        kept = {}
         for op in self.layers:
             if hasattr(op, "core_form"):
+                form = op.core_form()
                 counts = self._program_fields.setdefault(
                     op.core_field, dict.fromkeys(op.core_forms, 0))
-                counts[op.core_form()] += 1
+                counts[form] += 1
+                kept[op.core_field] = kept.get(op.core_field, 0) + int(
+                    form == op.core_forms[0] and op.recompute is not None
+                    and bool(op.saved_in_recompute))
+        if kept:
+            self._program_fields["core_kept"] = kept
         counter_ranks: Dict[str, int] = {}  # filled as train_step is traced
 
         # ---- sparse embedding update fast path ---------------------------
@@ -1662,7 +1673,8 @@ class FFModel:
 
     def _note_program(self, log, fn, args: tuple) -> str:
         """``profiling.note_program`` with what this model's ``program``
-        events carry beside the name (``attention_core``, ``gdn_core``)."""
+        events carry beside the name (``attention_core``, ``gdn_core``,
+        ``kda_core``, ``core_kept``)."""
         return note_program(log, fn, args, **self._program_fields)
 
     def _emit_op_counters(self, log, mets, fn: str):

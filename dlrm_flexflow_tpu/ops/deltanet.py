@@ -47,9 +47,10 @@ are whole lane tiles (128 in the language model): one Pallas kernel
 forward and one backward (``ops/pallas_deltanet.py``), the same
 algorithm at the same chunk and with the same arithmetic, a chunk's
 intermediates in VMEM, q, k and v read where they lie; it keeps between
-its passes what this form keeps.  Anywhere else (the CPU, a 29-token
-sequence, 24-wide heads): the chunked form below, ``jax.numpy`` under a
-``lax.scan``, which is also the kernels' reference.
+its passes what this form keeps, and through a layer's recomputation its
+output and those states too (``GDN_SAVED``).  Anywhere else (the CPU, a
+29-token sequence, 24-wide heads): the chunked form below, ``jax.numpy``
+under a ``lax.scan``, which is also the kernels' reference.
 """
 
 from __future__ import annotations
@@ -301,21 +302,35 @@ def _in_whole_chunks(q, k, v, g, beta, cd, operands_of):
     return o[:, :s] if pad else o
 
 
+#: what a recomputed run (``FFModel.scope(recompute=...)``) keeps of the
+#: rule's Pallas form: its output and the state at every chunk's start,
+#: both f32 (268 + 537 MB a layer at 32 value heads x 16,384 tokens x
+#: 128: 2.4 GB for the hybrid cell's three layers), so that the backward
+#: pass rebuilds the projections, convolution and gates but never runs
+#: the forward kernel a second time
+GDN_SAVED = ("gdn_core_out", "gdn_core_starts")
+
+
 @jax.custom_vjp
-def _pallas_rule(q, k, v, g, beta):
+def _pallas(q, k, v, g, beta):
     return pallas_deltanet.forward(q, k, v, g, beta)[0]
 
 
-def _pallas_rule_fwd(q, k, v, g, beta):
+def _pallas_fwd(q, k, v, g, beta):
     o, starts = pallas_deltanet.forward(q, k, v, g, beta)
+    o = checkpoint_name(o, GDN_SAVED[0])
+    starts = checkpoint_name(starts, GDN_SAVED[1])
     return o, (q, k, v, g, beta, starts)
 
 
-def _pallas_rule_bwd(res, do):
+def _pallas_bwd(res, do):
     return pallas_deltanet.backward(*res, do.astype(jnp.float32))
 
 
-_pallas_rule.defvjp(_pallas_rule_fwd, _pallas_rule_bwd)
+_pallas.defvjp(_pallas_fwd, _pallas_bwd)
+#: one callee for every layer: the kernels are traced and lowered once a
+#: program (and each of its derivatives once), not once a call site
+_pallas_rule = jax.jit(_pallas)
 
 
 def gated_delta_rule(q, k, v, g, beta, compute_dtype=None):
@@ -325,7 +340,9 @@ def gated_delta_rule(q, k, v, g, beta, compute_dtype=None):
     ``h`` reads key head ``h // (Hv / Hk)``); ``g`` (log-decay, <= 0)
     and ``beta``: (B, S, Hv), f32.  Returns (B, S, Hv, dv) f32.  Where
     ``core_form`` says ``"pallas"`` the two kernels of
-    ``ops/pallas_deltanet.py`` run; else the chunked form, in which a
+    ``ops/pallas_deltanet.py`` run, through one ``jax.jit`` callee that
+    every layer shares, keeping their output and boundary states through
+    a recomputation (``GDN_SAVED``); else the chunked form, in which a
     sequence the chunk does not divide is padded behind its end with
     tokens that change nothing (``beta`` 0, ``g`` 0)."""
     cd = jnp.dtype(compute_dtype or jnp.float32)
@@ -528,10 +545,15 @@ class GatedDeltaNet(Op):
     Scopes (the prefix is the op's ``phase``, ``ff.gdn`` without one):
     ``.proj`` (both input projections, ``W_out``), ``.conv``, ``.core``
     (normalisation of q and k, decays, the rule in the form
-    ``core_form`` names), ``.gate``.
+    ``core_form`` names), ``.gate``.  A recomputed run keeps the Pallas
+    form's output and boundary states (``GDN_SAVED``: the gate's
+    backward reads the one, the backward kernel the other), and the runs
+    share one policy object so that the rule's kernels are lowered once
+    a program.
     """
 
     op_type = "GatedDeltaNet"
+    saved_in_recompute, shares_recompute_policy = GDN_SAVED, True
     core_field, core_forms = "gdn_core", ("pallas", "chunked")
 
     def __init__(self, name, input_tensor, num_k_heads: int,

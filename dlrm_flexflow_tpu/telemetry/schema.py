@@ -378,11 +378,18 @@ SCHEMA: Dict[str, dict] = {
     # the rule as the two Pallas kernels of ops/pallas_kda.py and how
     # many in the chunked form, ``{"pallas": 6, "chunked": 0}`` on a TPU
     # in the Ling-style cell, ``{"pallas": 0, "chunked": 6}`` on the CPU
-    # (ops/deltanet.py ``kimi_core_form``).
+    # (ops/deltanet.py ``kimi_core_form``).  ``core_kept``: for each of
+    # those fields, how many of its ops run their kernel form inside a
+    # recomputed run that keeps what the kernels name
+    # (``Op.saved_in_recompute``: the output and the boundary states or
+    # log-sum-exp), so that the recomputation never runs their forward
+    # kernel again: ``{"attention_core": 1, "gdn_core": 3}`` on a TPU in
+    # the hybrid cell, ``{"attention_core": 1, "kda_core": 6}`` in the
+    # Ling-style one, every count 0 on the CPU or with recomputation off.
     "program": {
         "required": {"name": str},
         "optional": {"fn": str, "attention_core": dict, "gdn_core": dict,
-                     "kda_core": dict},
+                     "kda_core": dict, "core_kept": dict},
     },
     # what one dispatch of FFModel.train_epoch / train_epochs counted
     # inside one op that keeps counters in its state (ops/moe.py

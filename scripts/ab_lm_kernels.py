@@ -87,7 +87,7 @@ v5e, forward / forward + backward ms: chunked 23.70 / 52.82; KERNELS
 5.27 / 14.22; the first form written, one pair of chunks at a time in a
 ``fori_loop``, 13.98 / 33.29 and unrolled 12.76 / 30.45.
 gdncore (PR 36): ``core`` for the hybrid cell: the ``program`` event's
-``gdn_core`` and one DeltaNet layer's kernels by phase.
+``gdn_core`` and ``core_kept`` and one DeltaNet layer's kernels by phase.
 kda (PR 39): the delta rule with a decay per key channel at the shapes
 of ``ling3-flash-ep64.pretrain-8k`` (16 heads, 8,192 tokens, 128 wide,
 bf16; the gate's decays at the cell's initialisation), forward and
@@ -99,7 +99,8 @@ recurrence in f32 on the same rounded operands over the first 2,048
 tokens of the 2 heads of largest ``A`` (whose slowest channels keep
 their state longest).  PERF.md section 6, PR 39 has the numbers.
 kdacore (PR 39): ``core`` for the Ling-style cell: the ``program``
-event's ``kda_core`` and two KDA layers' kernels by phase.
+event's ``kda_core`` and ``core_kept`` and two KDA layers' kernels by
+phase.
 gqa (PR 35): grouped-head attention at 16 query heads on 2 key/value
 heads, 16,384 tokens, 256 wide, bf16: the repo's kernels reading each
 group's key/value head in place (what the program runs), the same
@@ -294,8 +295,9 @@ def core(which="core"):
     carry), then the first decoder layer of the cell with the embedding
     and head (one ``LatentAttention`` / one ``GatedDeltaNet``,
     recomputed as in the cell) trained one step under an event log: its
-    ``program`` event, and every Pallas kernel of the optimized HLO
-    beside its phase."""
+    ``program`` event (the core's forms and ``core_kept``, the cores
+    whose kernels a recomputed run keeps), and every Pallas kernel of the
+    optimized HLO beside its phase."""
     import dataclasses
     import importlib
     import json
@@ -340,7 +342,8 @@ def core(which="core"):
               f"{float(np.asarray(mets['loss'])[0]):.4f}", flush=True)
         events = log.events("program")
     print(f"{which}: one layer's program events: "
-          f"{[{k: e[k] for k in ('name', field)} for e in events]}",
+          f"{[{k: e[k] for k in ('name', field, 'core_kept')}
+              for e in events]}",
           flush=True)
     prog = profiling._programs[events[-1]["name"]]
     text = prog.fn().lower(*prog.args).compile().as_text()
@@ -581,7 +584,8 @@ def gdn(only=(), hk=16, hv=32, s=16384, d=128):
 
     def kernels(block, inverses=whole_inverses):
         """``ops/pallas_deltanet.py`` at another block, or with ``T``
-        got another way."""
+        got another way (the jit callee keeps its traced kernels: every
+        row starts from cleared caches)."""
         def rule(*xs):      # the backward kernel is traced after it returns
             pallas_deltanet.BLOCK = block
             pallas_deltanet._inverses = inverses
@@ -681,6 +685,7 @@ def gdn(only=(), hk=16, hv=32, s=16384, d=128):
     (_, o_ref), g_ref = grads_of(recurrence, w_part)(*part)
 
     def measure(name, rule):
+        jax.clear_caches()
         fwd = jax.jit(rule)
         both = jax.jit(jax.value_and_grad(
             lambda *xs: jnp.sum(rule(*xs) * w), tuple(range(5))))
@@ -703,6 +708,7 @@ def gdn(only=(), hk=16, hv=32, s=16384, d=128):
     deltanet.CHUNK = 64
     pallas_deltanet.BLOCK = shipped
     pallas_deltanet._inverses = whole_inverses
+    jax.clear_caches()
 
 
 def kda(only=(), h=16, s=8192, d=128):

@@ -68,10 +68,15 @@ def _hp(cfg, batch=2):
                                           "seq_len": cfg.seq_len}))
 
 
-def _compiled(cfg, batch=2, seed=0):
+def _built(cfg, batch=2):
     model = app.build(cfg, FFConfig(batch_size=batch))
     model.compile(optimizer=app.optimizer(cfg), loss_type=app.token_loss,
                   metrics=(), mesh=False)
+    return model
+
+
+def _compiled(cfg, batch=2, seed=0):
+    model = _built(cfg, batch)
     return model, model.init(seed=seed)
 
 
@@ -227,7 +232,9 @@ def rule_kernels_interpreted(monkeypatch):
     monkeypatch.setattr(
         pallas_deltanet.pl, "pallas_call",
         lambda *a, **kw: real(*a, **dict(kw, interpret=True)))
-    return pallas_deltanet
+    jax.clear_caches()      # the jit callee keeps what it traced
+    yield pallas_deltanet
+    jax.clear_caches()
 
 
 def _with_grads(rule, args, w):
@@ -367,26 +374,97 @@ def test_the_rule_form_follows_the_backend_and_the_shapes(
     assert jaxpr.count("pallas_call") == (2 if form == "pallas" else 0)
 
 
-def test_a_recomputed_layer_runs_the_rule_forward_twice(monkeypatch):
+@pytest.mark.parametrize("layers", [1, 3])
+def test_a_recomputed_layer_runs_the_rule_forward_once(layers, monkeypatch):
     """The training step of a recomputed model at the published head
-    widths, as a TPU would trace it: for each of the three DeltaNet
-    layers one forward kernel, one more in the layer's recomputation
-    (which keeps neither the output nor the boundary states) and one
-    backward kernel, and no more; the ``program`` event would read
-    ``pallas: 3``."""
+    widths, lowered for a TPU here (no chip, no compile): one
+    ``gated_delta_fwd`` and one ``gated_delta_bwd`` custom call in the
+    module whether it holds one DeltaNet layer or three (the layers share
+    the rule's jit callee and one recompute policy), the forward's
+    function called once a layer and never inside a recomputation (the
+    run keeps the rule's output and boundary states, ``GDN_SAVED``), the
+    backward's once a layer under the transpose; the ``program`` event
+    would read ``pallas`` and ``core_kept`` the layer count."""
     from dlrm_flexflow_tpu.ops import pallas_deltanet
+    from tests.test_pallas_kda import _functions_calling
     monkeypatch.setattr(deltanet, "_on_tpu", lambda: True)
-    cfg = _small(linear_key_head_dim=128, linear_value_head_dim=128,
-                 seq_len=pallas_deltanet.BLOCK)
+    jax.clear_caches()
+    cfg = _small(num_hidden_layers=layers, linear_key_head_dim=128,
+                 linear_value_head_dim=128, seq_len=pallas_deltanet.BLOCK)
     assert cfg.recompute
-    model, state = _compiled(cfg, batch=1)
-    assert model._program_fields["gdn_core"] == {"pallas": 3, "chunked": 0}
+    model = _built(cfg, batch=1)
+    assert model._program_fields["gdn_core"] == {"pallas": layers,
+                                                 "chunked": 0}
+    assert model._program_fields["core_kept"] == {"gdn_core": layers}
+    state = jax.eval_shape(lambda: model.init(seed=0))
+    ids = jax.ShapeDtypeStruct((1, cfg.seq_len), jnp.int32)
+    labels = jax.ShapeDtypeStruct((1, cfg.seq_len, 1), jnp.int32)
+    text = model._train_step.trace(state, {"ids": ids}, labels).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert text.count("tpu_custom_call") == 2
+    forward = _functions_calling(text, "gated_delta_fwd")
+    backward = _functions_calling(text, "gated_delta_bwd")
+    assert len(forward) == len(backward) == 1
+    (fwd_sites,), (bwd_sites,) = forward.values(), backward.values()
+    assert len(fwd_sites) == len(bwd_sites) == layers
+    assert all("ff.lm.gdn.core" in s and "rematted_computation" not in s
+               and "transpose" not in s for s in fwd_sites), fwd_sites
+    assert all("ff.lm.gdn.core" in s and "transpose" in s
+               for s in bwd_sites), bwd_sites
+    jax.clear_caches()
+
+
+def test_a_recomputed_model_with_the_kernels_is_the_chunked_ones(
+        rule_kernels_interpreted):
+    """One f32 training step of a one-layer model at 128-wide heads and
+    one block of tokens, the mixer recomputed: with the kernels (their
+    output and states kept through the recomputation) the loss and every
+    gradient (Adam's first moment after one step) are the chunked
+    form's."""
+    cfg = _small(num_hidden_layers=1, linear_key_head_dim=128,
+                 linear_value_head_dim=128,
+                 seq_len=rule_kernels_interpreted.BLOCK)
+    assert cfg.recompute
+    model = _built(cfg, batch=1)
+    assert model._program_fields["core_kept"] == {"gdn_core": 1}
     inputs, labels = family._split(_tokens(cfg, 1, batch=1))
-    text = str(jax.make_jaxpr(model._train_step)(
-        state, {k: v[0] for k, v in inputs.items()}, labels[0]))
-    assert text.count("name=gated_delta_fwd\n") == 6
-    assert text.count("name=gated_delta_bwd\n") == 3
-    assert text.count("pallas_call") == 9
+
+    def one_step():
+        with jax.default_matmul_precision("highest"):
+            state, mets = model.train_epoch(model.init(seed=0), inputs,
+                                            labels)
+        return float(mets["loss"]), state.opt_state
+
+    got_loss, got = one_step()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(deltanet, "_on_tpu", lambda: False)
+        jax.clear_caches()
+        want_loss, want = one_step()
+    assert abs(got_loss - want_loss) < 1e-5 * abs(want_loss)
+    moments = 0
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, atol=1e-5 * scale + 1e-12,
+                                   err_msg=jax.tree_util.keystr(path))
+        moments += scale > 0
+    assert moments > 10
+
+
+@pytest.mark.parametrize("on_tpu,recompute", [(True, False), (False, True)])
+def test_nothing_is_kept_without_a_recomputation_or_the_kernels(
+        on_tpu, recompute, monkeypatch):
+    """``core_kept`` counts a core only where its kernels run inside a
+    recomputed run: 0 for a model built without recomputation (the
+    kernels chosen), and 0 in the CPU's chunked form (recomputed)."""
+    from dlrm_flexflow_tpu.ops import pallas_deltanet
+    monkeypatch.setattr(deltanet, "_on_tpu", lambda: on_tpu)
+    cfg = _small(linear_key_head_dim=128, linear_value_head_dim=128,
+                 seq_len=pallas_deltanet.BLOCK, recompute=recompute)
+    fields = _built(cfg)._program_fields
+    assert fields["gdn_core"] == ({"pallas": 3, "chunked": 0} if on_tpu
+                                  else {"pallas": 0, "chunked": 3})
+    assert fields["core_kept"] == {"attention_core": 0, "gdn_core": 0}
 
 
 # ------------------------------------------------------------ attention

@@ -233,14 +233,16 @@ def test_a_recomputed_layer_runs_the_forward_kernel_once(monkeypatch):
     for each of the three ``LatentAttention`` ops and no third: the
     recomputation keeps the core's output and log-sum-exp
     (``saved_in_recompute``), so the backward holds no second forward
-    kernel; the ``program`` event would read ``pallas: 3``."""
+    kernel; the ``program`` event would read ``pallas: 3``, all three
+    kept through their recomputation."""
     monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
     cfg = _small(num_attention_heads=2, qk_nope_head_dim=128,
                  qk_rope_head_dim=64, v_head_dim=128, seq_len=2048)
     assert cfg.recompute
     model, state = _compiled(cfg, batch=1)
     assert model._program_fields == {
-        "attention_core": {"pallas": 3, "plain": 0}}
+        "attention_core": {"pallas": 3, "plain": 0},
+        "core_kept": {"attention_core": 3}}
     inputs, labels = family._split(_tokens(cfg, 1, batch=1))
     text = str(jax.make_jaxpr(model._train_step)(
         state, {k: v[0] for k, v in inputs.items()}, labels[0]))
