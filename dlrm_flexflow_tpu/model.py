@@ -374,14 +374,21 @@ class FFModel:
                          held=None, num_shared=0, scaling=1.0,
                          bias_update_speed=0.0, kernel_initializer=None,
                          name=None, score_func="sigmoid",
-                         shared_gated=False, n_group=1, topk_group=1):
+                         shared_gated=False, n_group=1, topk_group=1,
+                         router="linear", router_hidden=0,
+                         router_state=None):
+        """The held-experts layer (``ops/moe.py``).  With
+        ``router="mlp"`` it returns ``[output, router state]``, the state
+        to hand the next expert layer as its ``router_state``."""
         from .ops.moe import HeldExpertsMoE
         op = HeldExpertsMoE(self._name("moe", name), input_tensor,
                             num_experts, hidden_dim, top_k, held, num_shared,
                             scaling, bias_update_speed, kernel_initializer,
                             self._op_compute_dtype(), score_func,
                             shared_gated, n_group=n_group,
-                            topk_group=topk_group)
+                            topk_group=topk_group, router=router,
+                            router_hidden=router_hidden,
+                            router_state=router_state)
         return self._add(op)
 
     def rms_norm(self, input_tensor, eps=1e-6, name=None,
@@ -421,6 +428,27 @@ class FFModel:
                             rotary_dim, rope_theta, eps, kernel_initializer,
                             self._op_compute_dtype())
         return self._add(op)
+
+    def compressed_conv_attention(self, input_tensor, num_heads,
+                                  num_kv_heads, head_dim, conv0_taps,
+                                  conv1_taps, rotary_dim, rope_theta=10000.0,
+                                  kernel_initializer=None, name=None):
+        from .ops.attention import CompressedConvAttention
+        op = CompressedConvAttention(
+            self._name("cca", name), input_tensor, num_heads, num_kv_heads,
+            head_dim, conv0_taps, conv1_taps, rotary_dim, rope_theta,
+            kernel_initializer, self._op_compute_dtype())
+        return self._add(op)
+
+    def embedding_head(self, input_tensor, vocab_size,
+                       kernel_initializer=None, name=None):
+        """Logits ``x E^T`` over an embedding-shaped table: with ``tie``
+        to an ``embedding``, a head that shares the input's table."""
+        from .ops.transformer import EmbeddingHead
+        return self._add(EmbeddingHead(self._name("embedding_head", name),
+                                       input_tensor, vocab_size,
+                                       kernel_initializer,
+                                       self._op_compute_dtype()))
 
     def gated_delta_net(self, input_tensor, num_k_heads, num_v_heads,
                         head_k_dim, head_v_dim, conv_kernel=4, eps=1e-6,

@@ -25,10 +25,12 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..initializers import ConstantInitializer, DEFAULT_KERNEL_INIT
+from ..initializers import (ConstantInitializer, DEFAULT_KERNEL_INIT,
+                            UniformInitializer)
 from ..tensor import ParameterSpec
 from . import pallas_attention
 from .base import Op, held_heads, matmul
+from .deltanet import l2_normalised
 from .transformer import rms_norm, rope_half_split, rope_interleaved
 
 
@@ -640,4 +642,169 @@ class GatedAttention(Op):
         s, d, h, hd = self.inputs[0].shape[1], self.model_dim, \
             self.num_heads, self.head_dim
         proj = d * hd * (2 * h + 2 * self.num_kv_heads) + h * hd * d
+        return batch * s * 2 * (proj + s * h * hd)   # the core: causal half
+
+
+def lagged(x, lag: int):
+    """``x`` (B, S, ...) moved ``lag`` tokens later along axis 1: row
+    ``t`` holds row ``t - lag``, zeros before the sequence's start."""
+    if lag == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (lag, 0)
+    return jnp.pad(x[:, :x.shape[1] - lag], pad)
+
+
+def qk_mean(q_latent, k_latent, q_mixed, k_mixed):
+    """CCA's q-k mean: ``(q, k)`` from the latent queries (B, S, H, hd)
+    and keys (B, S, Hkv, hd) before the convolutions and the mixed ones
+    after.  ``m_h = (q~_h + k~_g(h)) / 2`` for query head ``h`` of key
+    head ``g(h) = h // (H / Hkv)``; ``q_h = q''_h + m_h``; ``k_g =
+    k''_g`` + the mean of ``m_h`` over g's query heads.  With one query
+    head a key head both get the paper's ``(q~ + k~) / 2``."""
+    b, s, kv, hd = k_latent.shape
+    group = q_latent.shape[2] // kv
+    mean = (q_latent + jnp.repeat(k_latent, group, axis=2)) / 2
+    return q_mixed + mean, k_mixed + jnp.mean(
+        mean.reshape(b, s, kv, group, hd), axis=3)
+
+
+class CompressedConvAttention(Op):
+    """Compressed Convolutional Attention (CCA: Figliolia et al.,
+    arXiv:2510.04476; ZAYA1's attention): (B, S, d) -> (B, S, d),
+    causal, no biases.  ``docs/CCA_MOE_LM.md`` has the equations.
+
+    ``q~ = x W_q`` (``num_heads`` heads), ``k~ = x W_k`` (``num_kv_heads``
+    heads), all ``head_dim`` wide: the attention's latent.  The value
+    shift: ``v = x W_v`` over ``num_kv_heads`` heads, of which the last
+    half of the channels take the previous token's row (zeros at the
+    first).  Two causal convolutions over tokens mix ``c = [q~ ; k~]``:
+    ``c' = sum_j a_j * c_(t-j)``, depthwise (``conv0``: taps x channels),
+    then ``c''_g = sum_j c'_(t-j),g B_(j,g)``, grouped by head
+    (``conv1``: taps x heads x head_dim x head_dim).  The q-k mean, from
+    the values before the convolutions: ``m_h = (q~_h + k~_g(h)) / 2``,
+    ``q = q''_h + m_h``, ``k = k''_g + mean over g's heads of m_h``.
+    Then ``q^ = q / |q|`` and ``k^ = tau_g k / |k|`` (``temperature``,
+    one a key head, drawn at ``sqrt(head_dim)``), the rotary embedding
+    in the half-split form on the first ``rotary_dim`` elements of each
+    head, causal softmax attention at scale 1 (the temperature carries
+    it) with ``num_heads / num_kv_heads`` query heads to a key/value
+    head, and ``y = o W_o``.
+
+    The core is ``blockwise_causal_attention`` (the Pallas kernels on a
+    TPU at shapes they take, reading each group's key/value head in
+    place; the plain core elsewhere), handed q and k in f32 with the
+    normalisation and the temperature folded in, each rounded once
+    there.  A recomputed run keeps the core's output and log-sum-exp as
+    ``LatentAttention``'s does.  The convolutions' taps and the
+    normalisation are f32; ``conv1`` is a matmul, its operands in the
+    compute dtype.  Scopes: ``<phase>.proj`` (``W_q``, ``W_k``,
+    ``W_v``), ``<phase>.mix`` (both convolutions, the value shift, the
+    q-k mean, the normalisation with the temperature, the rotary
+    embedding), ``<phase>.core``, ``<phase>.out`` (``W_o``); ``ff.attn``
+    without a phase.
+    """
+
+    op_type = "CompressedConvAttention"
+    saved_in_recompute = CORE_SAVED
+    core_field, core_forms = "attention_core", ("pallas", "plain")
+
+    def __init__(self, name, input_tensor, num_heads: int, num_kv_heads: int,
+                 head_dim: int, conv0_taps: int, conv1_taps: int,
+                 rotary_dim: int, rope_theta: float = 10000.0,
+                 kernel_initializer=None, compute_dtype=None):
+        super().__init__(name, [input_tensor])
+        self.model_dim = input_tensor.shape[-1]
+        self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
+        assert self.num_heads % self.num_kv_heads == 0
+        self.head_dim, self.rotary_dim = int(head_dim), int(rotary_dim)
+        assert (self.num_kv_heads * self.head_dim) % 2 == 0
+        self.conv0_taps, self.conv1_taps = int(conv0_taps), int(conv1_taps)
+        self.rope_theta = float(rope_theta)
+        self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT
+        self.compute_dtype = compute_dtype
+        self.outputs = [self._make_output(input_tensor.shape,
+                                          input_tensor.dtype)]
+
+    def param_specs(self):
+        d, h, kv, hd = self.model_dim, self.num_heads, self.num_kv_heads, \
+            self.head_dim
+        init = self.kernel_initializer
+        # the convolutions at torch.nn.Conv1d's default: U(+-fan_in^-1/2)
+        b0 = self.conv0_taps ** -0.5
+        b1 = (self.conv1_taps * hd) ** -0.5
+        return [
+            ParameterSpec(self.name, "w_q", (d, h * hd), initializer=init,
+                          sharded_dim=1),
+            ParameterSpec(self.name, "w_k", (d, kv * hd), initializer=init,
+                          sharded_dim=1),
+            ParameterSpec(self.name, "w_v", (d, kv * hd), initializer=init,
+                          sharded_dim=1),
+            ParameterSpec(self.name, "conv0",
+                          (self.conv0_taps, (h + kv) * hd),
+                          initializer=UniformInitializer(-b0, b0)),
+            ParameterSpec(self.name, "conv1",
+                          (self.conv1_taps, h + kv, hd, hd),
+                          initializer=UniformInitializer(-b1, b1)),
+            ParameterSpec(self.name, "temperature", (kv,),
+                          initializer=ConstantInitializer(math.sqrt(hd))),
+            ParameterSpec(self.name, "w_o", (h * hd, d), initializer=init,
+                          sharded_dim=0)]
+
+    _core_dtype = LatentAttention._core_dtype   # reads compute_dtype alone
+
+    def core_form(self) -> str:
+        return core_form(self.inputs[0].shape[1], self.head_dim,
+                         self.head_dim, self._core_dtype())
+
+    def forward(self, params, xs, *, training=False, rng=None):
+        (x,) = xs
+        b, s, _ = x.shape
+        h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        cdt = self.compute_dtype
+        scope = self.phase or "ff.attn"
+        heads_first = lambda t: t.transpose(0, 2, 1, 3)
+        with jax.named_scope(scope + ".proj"):
+            q0 = matmul(x, params["w_q"], cdt)             # (B, S, H hd)
+            k0 = matmul(x, params["w_k"], cdt)             # (B, S, Hkv hd)
+            v = matmul(x, params["w_v"], cdt)
+        with jax.named_scope(scope + ".mix"):
+            half = kv * hd // 2
+            v = jnp.concatenate([v[..., :half], lagged(v[..., half:], 1)],
+                                axis=-1).reshape(b, s, kv, hd)
+            c = jnp.concatenate([q0, k0], axis=-1)
+            c = sum(lagged(c, j) * params["conv0"][j]
+                    for j in range(self.conv0_taps))
+            # grouped by head: a (B S, hd) x (hd, hd) matmul a head and
+            # tap, with the head axis leading
+            c = c.reshape(b, s, h + kv, hd)
+            cd = self._core_dtype()
+            lead = lambda t: t.transpose(2, 0, 1, 3).reshape(h + kv, -1, hd)
+            c = sum(jnp.matmul(lead(lagged(c, j)).astype(cd),
+                               params["conv1"][j].astype(cd),
+                               preferred_element_type=jnp.float32)
+                    for j in range(self.conv1_taps))
+            c = c.reshape(h + kv, b, s, hd).transpose(1, 2, 0, 3)
+            q, k = qk_mean(q0.reshape(b, s, h, hd), k0.reshape(b, s, kv, hd),
+                           c[:, :, :h], c[:, :, h:])
+            q = l2_normalised(q)
+            k = l2_normalised(k) * params["temperature"][:, None]
+            q = rope_half_split(q, jnp.arange(s), self.rope_theta,
+                                self.rotary_dim, seq_axis=1)
+            k = rope_half_split(k, jnp.arange(s), self.rope_theta,
+                                self.rotary_dim, seq_axis=1)
+        with jax.named_scope(scope + ".core"):
+            o = blockwise_causal_attention(
+                heads_first(q), heads_first(k), heads_first(v), 1.0,
+                compute_dtype=self._core_dtype())
+        with jax.named_scope(scope + ".out"):
+            out = matmul(heads_first(o).reshape(b, s, h * hd), params["w_o"],
+                         cdt)
+        return [out.astype(self.outputs[0].dtype)]
+
+    def flops(self, batch):
+        s, d, h, kv, hd = self.inputs[0].shape[1], self.model_dim, \
+            self.num_heads, self.num_kv_heads, self.head_dim
+        proj = d * hd * (h + 2 * kv) + h * hd * d \
+            + self.conv1_taps * (h + kv) * hd * hd
         return batch * s * 2 * (proj + s * h * hd)   # the core: causal half

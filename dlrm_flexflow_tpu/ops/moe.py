@@ -115,8 +115,15 @@ def _on_tpu() -> bool:
 # what the layer's state counts beside ``tokens_per_expert``
 COUNTERS = ("held_assignments", "padded_rows", "buffer_rows",
             "overflow_steps")
+# and, in a layer with a skip slot, the tokens routed to it
+SKIPPED = "skipped_tokens"
 # the grouped matmul's row tile on the chip; a slab is whole tiles
 ROW_TILE = 512
+# the grouped matmul's (k, n) tile on the chip holds at most this many
+# elements: at 512 rows a 1024 x 1024 tile overflows the v5e's 16 MiB of
+# scoped VMEM in the weights' gradient (tgmm) by 124 KB (experts 2048
+# wide both ways); 1024 x 768 fits
+TILE_ELEMENTS = 1024 * 768
 # A slab holds this many even shares of the held experts' assignments
 # (``slab_rows``).  The language-model cell (16 of 256 experts held, an
 # even share 4,096 rows) sends a layer 2.9-6.8 k rows a step and none
@@ -150,8 +157,10 @@ def grouped_matmul(rows, weights, group_sizes):
     never visits their tiles), and the caller masks them."""
     if _on_tpu():
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-        tiling = (min(ROW_TILE, rows.shape[0]), min(1024, rows.shape[1]),
-                  min(1024, weights.shape[2]))
+        tk, tn = min(1024, rows.shape[1]), min(1024, weights.shape[2])
+        while tk * tn > TILE_ELEMENTS:
+            tn //= 2
+        tiling = (min(ROW_TILE, rows.shape[0]), tk, tn)
         return megablox.gmm(rows, weights, group_sizes, jnp.float32, tiling)
     return jax.lax.ragged_dot(rows, weights, group_sizes,
                               preferred_element_type=jnp.float32)
@@ -309,6 +318,19 @@ class HeldExpertsMoE(Op):
     and there is no bias rule).  ``shared_gated``: the shared experts'
     output times ``sigmoid(x w_s)``, ``w_s`` (d, 1) a parameter.
 
+    ``router="mlp"`` (ZAYA1's router; ``docs/CCA_MOE_LM.md``): ``z = x
+    W_d`` (d -> ``router_hidden``); exponential depth averaging ``r =
+    (1 - g) z + g r_prev``, ``g = sigmoid(c)`` with ``c`` a learned
+    scalar drawn at 0 and ``r_prev`` the previous expert layer's ``r``
+    (the op's second input; zeros without one); ``s = softmax(gelu(r
+    W_a) W_e)`` over the experts and one slot more, the skip slot, that
+    no expert holds (ZAYA1's mixture of depths); the ``top_k`` largest
+    ``s`` are selected and
+    their gates are the selected ``s`` themselves, unnormalised and
+    without a bias.  A token whose selection is the skip slot gets
+    nothing from the layer.  The op's second output is ``r``, in f32,
+    for the next expert layer.
+
     No assignment to a held expert is dropped, and none is computed
     twice: a stable sort puts the assignments to held experts first,
     grouped by expert, and the layer works through them a slab of
@@ -336,7 +358,8 @@ class HeldExpertsMoE(Op):
     ``held_assignments / buffer_rows`` is the fill); ``overflow_steps``,
     the steps that took more than one slab.  A state that lacks a
     counter (the benchmark's control writes the first three) is
-    carried as it is.
+    carried as it is.  The MLP router's layer also counts
+    ``skipped_tokens``; ``tokens_per_expert`` counts the experts alone.
 
     Scopes (the prefix is the op's ``phase``, ``FFModel.scope``'s word,
     ``ff.moe`` without one): ``.route`` (scores, top-k, counts),
@@ -354,9 +377,16 @@ class HeldExpertsMoE(Op):
                  scaling: float = 1.0, bias_update_speed: float = 0.0,
                  kernel_initializer=None, compute_dtype=None,
                  score_func: str = "sigmoid", shared_gated: bool = False,
-                 n_group: int = 1, topk_group: int = 1):
-        super().__init__(name, [input_tensor])
+                 n_group: int = 1, topk_group: int = 1,
+                 router: str = "linear", router_hidden: int = 0,
+                 router_state=None):
+        super().__init__(name, [input_tensor] + (
+            [router_state] if router_state is not None else []))
         assert score_func in ("sigmoid", "softmax"), score_func
+        assert router in ("linear", "mlp"), router
+        assert router == "mlp" or router_state is None, \
+            "a router state belongs to the MLP router"
+        self.router, self.router_hidden = router, int(router_hidden)
         self.score_func = score_func
         self.shared_gated = bool(shared_gated)
         self.num_experts = int(num_experts)
@@ -373,6 +403,7 @@ class HeldExpertsMoE(Op):
         assert 0 <= self.first_held \
             and self.first_held + self.num_held <= self.num_experts
         assert 1 <= top_k <= self.num_experts
+        self.slots = self.num_experts + int(self.router == "mlp")
         self.hidden_dim = int(hidden_dim)
         self.top_k = int(top_k)
         self.num_shared = int(num_shared)
@@ -383,13 +414,31 @@ class HeldExpertsMoE(Op):
         self.compute_dtype = compute_dtype
         self.outputs = [self._make_output(input_tensor.shape,
                                           input_tensor.dtype)]
+        if self.router == "mlp":
+            self.outputs.append(self._make_output(
+                input_tensor.shape[:-1] + (self.router_hidden,),
+                jnp.float32, idx=1))
+
+    def _router_specs(self):
+        d, rh, init = self.model_dim, self.router_hidden, \
+            self.kernel_initializer
+        if self.router == "linear":
+            return [ParameterSpec(self.name, "router", (d, self.num_experts),
+                                  initializer=init)]
+        return [
+            ParameterSpec(self.name, "router_down", (d, rh),
+                          initializer=init),
+            ParameterSpec(self.name, "router_depth", (),
+                          initializer=ZeroInitializer()),
+            ParameterSpec(self.name, "router_hidden", (rh, rh),
+                          initializer=init),
+            ParameterSpec(self.name, "router_out", (rh, self.slots),
+                          initializer=init)]
 
     def param_specs(self):
         d, h, e = self.model_dim, self.hidden_dim, self.num_held
         init = self.kernel_initializer
-        specs = [
-            ParameterSpec(self.name, "router", (d, self.num_experts),
-                          initializer=init),
+        specs = self._router_specs() + [
             ParameterSpec(self.name, "w_gate", (e, d, h), initializer=init,
                           sharded_dim=0),
             ParameterSpec(self.name, "w_up", (e, d, h), initializer=init,
@@ -414,13 +463,14 @@ class HeldExpertsMoE(Op):
         return {"bias": jnp.zeros((self.num_experts,), jnp.float32),
                 "tokens_per_expert": jnp.zeros((self.num_experts,),
                                                jnp.int32),
-                **{name: jnp.zeros((), jnp.int32) for name in COUNTERS}}
+                **{name: jnp.zeros((), jnp.int32) for name in COUNTERS
+                   + ((SKIPPED,) if self.router == "mlp" else ())}}
 
     def step_metrics(self, old, new):
         """This step's counters for the step's metrics (``train_epoch``
         folds them: sums, and the largest for a name ending ``_max``)."""
         out = {k: new[k] - old[k]
-               for k in ("tokens_per_expert",) + COUNTERS if k in old}
+               for k in ("tokens_per_expert", SKIPPED) + COUNTERS if k in old}
         out["bias_abs_max"] = jnp.max(jnp.abs(new["bias"]))
         return out
 
@@ -455,8 +505,29 @@ class HeldExpertsMoE(Op):
                          axis=(0, 1), dtype=jnp.int32)
         return idx.astype(jnp.int32), gates, counts
 
+    def route_mlp(self, x, params, r_prev):
+        """``(idx, gates, counts, r (T, router_hidden) f32)`` for tokens
+        ``x`` (T, d) and the previous layer's ``r_prev`` (or None): the
+        MLP router, in f32 at full matmul precision as ``route``'s
+        scores; ``idx`` may be the skip slot, ``num_experts``, which
+        ``counts`` leaves out."""
+        mm = functools.partial(jnp.matmul,
+                               precision=jax.lax.Precision.HIGHEST)
+        z = mm(x.astype(jnp.float32), params["router_down"])
+        depth = jax.nn.sigmoid(params["router_depth"])
+        r = (1.0 - depth) * z
+        if r_prev is not None:
+            r = r + depth * r_prev
+        scores = jax.nn.softmax(mm(jax.nn.gelu(
+            mm(r, params["router_hidden"]), approximate=False),
+            params["router_out"]), axis=-1)
+        gates, idx = jax.lax.top_k(scores, self.top_k)
+        counts = jnp.sum(idx[..., None] == jnp.arange(self.num_experts),
+                         axis=(0, 1), dtype=jnp.int32)
+        return idx.astype(jnp.int32), gates, counts, r
+
     def forward(self, params, xs, *, training=False, rng=None, state=None):
-        (x_in,) = xs
+        x_in, r_prev = xs[0], (xs[1] if len(xs) > 1 else None)
         d, k = self.model_dim, self.top_k
         x = x_in.reshape(-1, d)
         tokens = x.shape[0]
@@ -466,8 +537,13 @@ class HeldExpertsMoE(Op):
               else jnp.float32)
         scope = self.phase or "ff.moe"
         with jax.named_scope(scope + ".route"):
-            idx, gates, counts = self.route(x, params["router"],
-                                            state["bias"])
+            if self.router == "mlp":
+                idx, gates, counts, r = self.route_mlp(
+                    x, params, None if r_prev is None
+                    else r_prev.reshape(tokens, -1))
+            else:
+                idx, gates, counts = self.route(x, params["router"],
+                                                state["bias"])
         with jax.named_scope(scope + ".dispatch"):
             local = idx.reshape(-1) - self.first_held
             here = (local >= 0) & (local < self.num_held)
@@ -507,12 +583,20 @@ class HeldExpertsMoE(Op):
                         ("buffer_rows", slabs * slab),
                         ("overflow_steps", (slabs > 1).astype(jnp.int32)))
                        if name in state}}
+                if self.router == "mlp":
+                    new_state[SKIPPED] = state[SKIPPED] + (
+                        tokens * k - jnp.sum(counts))
         self._last_state = new_state
-        return [out.reshape(x_in.shape).astype(self.outputs[0].dtype)]
+        out = out.reshape(x_in.shape).astype(self.outputs[0].dtype)
+        if self.router == "mlp":
+            return [out, r.reshape(x_in.shape[:-1] + (-1,))]
+        return [out]
 
     def flops(self, batch):
         rows = self.inputs[0].numel() // self.model_dim
-        d, h = self.model_dim, self.hidden_dim
-        routed = self.top_k * self.num_held / self.num_experts
-        return int(rows * (2 * d * self.num_experts
+        d, h, rh = self.model_dim, self.hidden_dim, self.router_hidden
+        routed = self.top_k * self.num_held / self.slots
+        router = (d * self.num_experts if self.router == "linear"
+                  else d * rh + rh * rh + rh * self.slots)
+        return int(rows * (2 * router
                            + 6 * d * h * (routed + self.num_shared)))

@@ -137,3 +137,37 @@ class GatedFFN(Op):
     def flops(self, batch):
         rows = self.inputs[0].numel() // self.model_dim
         return 6 * rows * self.model_dim * self.hidden_dim
+
+
+class EmbeddingHead(Op):
+    """Logits over a vocabulary from a table laid out as an embedding's:
+    ``x E^T``, ``E`` (V, d) named ``embedding``, so that ``FFModel.tie``
+    makes the op read an ``Embedding``'s table in place of its own (one
+    matrix as input embedding and output head).  No bias; the operands
+    in the compute dtype, f32 out."""
+
+    op_type = "EmbeddingHead"
+
+    def __init__(self, name, input_tensor, vocab_size: int,
+                 kernel_initializer=None, compute_dtype=None):
+        super().__init__(name, [input_tensor])
+        self.model_dim = input_tensor.shape[-1]
+        self.vocab_size = int(vocab_size)
+        self.kernel_initializer = kernel_initializer or DEFAULT_KERNEL_INIT
+        self.compute_dtype = compute_dtype
+        self.outputs = [self._make_output(
+            input_tensor.shape[:-1] + (self.vocab_size,), jnp.float32)]
+
+    def param_specs(self):
+        return [ParameterSpec(self.name, "embedding",
+                              (self.vocab_size, self.model_dim),
+                              initializer=self.kernel_initializer,
+                              sharded_dim=1)]
+
+    def forward(self, params, xs, *, training=False, rng=None):
+        (x,) = xs
+        return [matmul(x, params["embedding"].T, self.compute_dtype)]
+
+    def flops(self, batch):
+        rows = self.inputs[0].numel() // self.model_dim
+        return 2 * rows * self.model_dim * self.vocab_size

@@ -397,7 +397,8 @@ SCHEMA: Dict[str, dict] = {
     # ``held_assignments``, ``padded_rows`` (assignments to no held
     # expert), ``buffer_rows`` (rows the grouped matmuls were given:
     # slabs x the slab's rows), ``overflow_steps`` (steps that took more
-    # than one slab), ``bias_abs_max``), summed over the dispatch's
+    # than one slab), ``bias_abs_max``; with a skip slot
+    # ``skipped_tokens``, the assignments to it), summed over the dispatch's
     # steps (``*_max``: the largest).  Emitting it reads device values:
     # the host waits for the dispatch.
     "op_counters": {

@@ -17,8 +17,9 @@ the tiny DLRM of ``tests/test_region_cache.py`` (packed storage; SGD and
 lazy Adam; regions on and off; the auto ladder and the explicit
 two-level ``"16,8"``), the same model on logical storage with the
 view-row transport on and off, with the row cache off, a dense-only
-toy, the tiny language model of ``tests/test_mla_moe_lm.py`` and (PR 35)
-the tiny hybrid one of ``tests/test_gdn_moe_lm.py``.
+toy, the tiny language model of ``tests/test_mla_moe_lm.py``, the tiny
+hybrid one of ``tests/test_gdn_moe_lm.py`` and the tiny Kimi Delta
+Attention one of ``tests/test_kda_moe_lm.py``.
 """
 
 import json
@@ -123,6 +124,30 @@ def gdn_lm():
             jnp.asarray(labels))
 
 
+def kda_lm():
+    from benchmarks.models import kda_moe_lm as family
+    from dlrm_flexflow_tpu.apps import kda_moe_lm as app
+    from dlrm_flexflow_tpu.ops import attention, deltanet
+    attention.ATTENTION_BLOCK = 8
+    deltanet.CHUNK, deltanet.SUB = 16, 4
+    cfg = app.KdaMoeLmConfig(
+        vocab_size=96, hidden_size=32, num_hidden_layers=3,
+        layer_group_size=3, first_layer_held=1, intermediate_size=48,
+        num_attention_heads=4, heads_held=2, head_dim=8, kv_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=6,
+        num_experts=16, experts_held=4, num_experts_per_tok=4, n_group=4,
+        topk_group=2, moe_intermediate_size=16,
+        moe_shared_expert_intermediate_size=16, seq_len=32)
+    m = app.build(cfg, ff.FFConfig(batch_size=2))
+    m.compile(optimizer=app.optimizer(cfg), loss_type=app.token_loss,
+              metrics=(), mesh=False)
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, size=(2, 2, cfg.seq_len + 1)).astype(np.int32)
+    inputs, labels = family._split(tokens)
+    return (m, {k: jnp.asarray(v) for k, v in inputs.items()},
+            jnp.asarray(labels))
+
+
 CASES = {
     **{f"dlrm.{opt}.regions-{reg}.levels-{lv or 'auto'}":
        (lambda opt=opt, reg=reg, lv=lv: dlrm(
@@ -142,6 +167,7 @@ CASES = {
     "toy.dense": toy,
     "lm.tiny": lm,
     "gdn_lm.tiny": gdn_lm,
+    "kda_lm.tiny": kda_lm,
 }
 
 

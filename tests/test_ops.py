@@ -385,6 +385,54 @@ class TestHeldShares:
             m.held_experts_moe(x, 16, 8, top_k, n_group=groups,
                                topk_group=kept)
 
+    @pytest.mark.parametrize("kw", [
+        {}, {"score_func": "softmax", "num_shared": 1, "shared_gated": True},
+        {"n_group": 4, "topk_group": 2, "num_shared": 1}])
+    def test_the_linear_router_builds_the_layer_it_built(self, kw):
+        """The held-experts layer without the MLP router's arguments:
+        one input, one output, the one router matrix, no skip
+        counter, and an output that is the gated sum, over the selected
+        experts that are held, of ``route``'s choice (plus the shared
+        expert)."""
+        from dlrm_flexflow_tpu.ops import moe
+        from dlrm_flexflow_tpu.ops.transformer import swiglu
+        m = ff.FFModel(ff.FFConfig(batch_size=2))
+        x = m.create_tensor((2, 8, 16), name="x")
+        m.held_experts_moe(x, 16, 8, 4, (4, 8), name="moe", **kw)
+        (op,) = m.layers
+        assert len(op.inputs) == len(op.outputs) == 1
+        assert [s.param_name for s in op.param_specs()][:4] == [
+            "router", "w_gate", "w_up", "w_down"]
+        assert set(op.init_state()) == {"bias", "tokens_per_expert",
+                                        *moe.COUNTERS}
+        params = op.init_params(jax.random.PRNGKey(0))
+        params = {k: 10.0 * v for k, v in params.items()}
+        xv = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16))
+        with jax.default_matmul_precision("highest"):
+            (out,) = op.forward(params, [xv], training=True,
+                                state=op.init_state())
+            flat = xv.reshape(-1, 16)
+            idx, gates, counts = op.route(flat, params["router"],
+                                          jnp.zeros(16))
+            want = jnp.zeros_like(flat)
+            for e in range(8):
+                ffn = [params[k][e] for k in ("w_gate", "w_up", "w_down")]
+                gate = jnp.sum(jnp.where(idx == 4 + e, gates, 0.0), axis=1)
+                want = want + gate[:, None] * swiglu(flat, *ffn)
+            if kw.get("num_shared"):
+                shared = swiglu(flat, params["shared_gate"],
+                                params["shared_up"], params["shared_down"])
+                if kw.get("shared_gated"):
+                    shared = shared * jax.nn.sigmoid(
+                        flat @ params["shared_sigmoid"])
+                want = want + shared
+        np.testing.assert_allclose(out.reshape(flat.shape), want, atol=1e-4,
+                                   rtol=1e-5)
+        np.testing.assert_array_equal(op._last_state["tokens_per_expert"],
+                                      counts)
+        assert int(op._last_state["held_assignments"]) \
+            == int(counts[4:12].sum())
+
 
 class TestActivationDtype:
     """FFConfig.activation_dtype="bfloat16" (bf16 activation STORAGE
