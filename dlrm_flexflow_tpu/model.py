@@ -949,7 +949,9 @@ class FFModel:
         # them run their kernel form (the first of ``core_forms``) in a
         # recomputed run that keeps what the kernels name
         # (``saved_in_recompute``), so the recomputation never runs
-        # their forward kernel again
+        # their forward kernel again; and ``route_kept``: how many
+        # expert layers sit in a recomputed run and keep their routing
+        # there, so the recomputation never routes again
         self._program_fields = {}
         kept = {}
         for op in self.layers:
@@ -963,6 +965,13 @@ class FFModel:
                     and bool(op.saved_in_recompute))
         if kept:
             self._program_fields["core_kept"] = kept
+        from .ops.moe import HeldExpertsMoE
+        routers = [op for op in self.layers
+                   if isinstance(op, HeldExpertsMoE)]
+        if routers:
+            self._program_fields["route_kept"] = sum(
+                op.recompute is not None and bool(op.saved_in_recompute)
+                for op in routers)
         counter_ranks: Dict[str, int] = {}  # filled as train_step is traced
 
         # ---- sparse embedding update fast path ---------------------------
@@ -1702,7 +1711,7 @@ class FFModel:
     def _note_program(self, log, fn, args: tuple) -> str:
         """``profiling.note_program`` with what this model's ``program``
         events carry beside the name (``attention_core``, ``gdn_core``,
-        ``kda_core``, ``core_kept``)."""
+        ``kda_core``, ``core_kept``, ``route_kept``)."""
         return note_program(log, fn, args, **self._program_fields)
 
     def _emit_op_counters(self, log, mets, fn: str):

@@ -26,6 +26,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..initializers import DEFAULT_KERNEL_INIT, ZeroInitializer
 from ..tensor import ParameterSpec
@@ -132,6 +133,20 @@ TILE_ELEMENTS = 1024 * 768
 # 12.2 ms forward + backward at 2 shares, 15.4 at 4, and a further slab
 # in the rare step that needs one 6.7 (v5e; PERF.md section 6, PR 32).
 SHARES = 2
+# what a recomputed run keeps of the layer's routing and dispatch
+# (``HeldExpertsMoE.saved_in_recompute``): the router's scores, the
+# selection and the selected scores, the sorted assignments and the
+# groups' sizes; the MLP router's also its first product and the gelu's
+# input.  Kept, the recomputation holds no router matmul, top-k, gather
+# of the selected scores (1.7 ms a layer at 16,384 x 10 of 512 on the
+# v5e) or sort, for 4 T (E + 3 k) bytes a layer (and 8 T router_hidden
+# more for the MLP router's): <= 35 MB in every cell
+SCORES, SELECTION, PICKED = ("moe_route_scores", "moe_route_idx",
+                             "moe_route_picked")
+ORDER, GROUP_SIZES = "moe_dispatch_order", "moe_dispatch_group_sizes"
+ROUTER_Z, ROUTER_H = "moe_router_z", "moe_router_h"
+ROUTE_SAVED = (SCORES, SELECTION, PICKED, ORDER, GROUP_SIZES, ROUTER_Z,
+               ROUTER_H)
 
 
 def slab_rows(assignments: int, num_held: int, num_experts: int) -> int:
@@ -291,6 +306,34 @@ def _held_experts_bwd(static, res, cts):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+_SCORE_FUNCS = {"softmax": jax.nn.softmax, "sigmoid": jax.nn.sigmoid}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _scores(func, logits):
+    """``softmax`` or ``sigmoid`` of ``logits`` (..., E) over the
+    experts, differentiated from its output alone, which the forward
+    names ``SCORES``: a recomputed run that keeps it never computes the
+    logits again (JAX's own rules read an output of their own, which a
+    name cannot reach).  The same numbers as JAX's rules."""
+    return _SCORE_FUNCS[func](logits)
+
+
+def _scores_fwd(func, logits):
+    s = checkpoint_name(_SCORE_FUNCS[func](logits), SCORES)
+    return s, s
+
+
+def _scores_bwd(func, s, g):
+    if func == "softmax":
+        gs = g * s
+        return (gs - s * jnp.sum(gs, axis=-1, keepdims=True),)
+    return (g * (s * (1.0 - s)),)
+
+
+_scores.defvjp(_scores_fwd, _scores_bwd)
+
+
 class HeldExpertsMoE(Op):
     """A routed-expert layer that holds ``held = (first, count)`` of its
     ``num_experts`` experts (all of them by default), plus
@@ -361,6 +404,14 @@ class HeldExpertsMoE(Op):
     carried as it is.  The MLP router's layer also counts
     ``skipped_tokens``; ``tokens_per_expert`` counts the experts alone.
 
+    In a recomputed run (``FFModel.scope(recompute=...)``) the layer
+    keeps its routing (``saved_in_recompute = ROUTE_SAVED``): the
+    scores, the selection and the selected scores, the sorted
+    assignments and the groups' sizes, and the MLP router's first
+    product and gelu input, so the backward pass uses the forward's own
+    selection and computes no score, top-k, gather or sort again.
+    Elsewhere the names are the identity.
+
     Scopes (the prefix is the op's ``phase``, ``FFModel.scope``'s word,
     ``ff.moe`` without one): ``.route`` (scores, top-k, counts),
     ``.dispatch`` (sort, gather; backward: the scatter-add into the
@@ -371,6 +422,7 @@ class HeldExpertsMoE(Op):
 
     op_type = "HeldExpertsMoE"
     has_state = True
+    saved_in_recompute = ROUTE_SAVED
 
     def __init__(self, name, input_tensor, num_experts: int, hidden_dim: int,
                  top_k: int, held=None, num_shared: int = 0,
@@ -479,12 +531,9 @@ class HeldExpertsMoE(Op):
         for tokens ``x`` (T, d): scores and selection in f32 at full
         matmul precision, whatever the compute dtype (a rounded score
         would move the selection)."""
-        logits = jnp.matmul(x.astype(jnp.float32), router,
-                            precision=jax.lax.Precision.HIGHEST)
-        if self.score_func == "softmax":
-            scores = jax.nn.softmax(logits, axis=-1)
-        else:
-            scores = jax.nn.sigmoid(logits)
+        scores = _scores(self.score_func, jnp.matmul(
+            x.astype(jnp.float32), router,
+            precision=jax.lax.Precision.HIGHEST))
         choice = scores + jax.lax.stop_gradient(bias)
         if self.n_group > 1:
             # group-limited: the experts lie in n_group consecutive
@@ -497,13 +546,15 @@ class HeldExpertsMoE(Op):
                             axis=-2)
             choice = jnp.where(stays[..., None], grouped,
                                -jnp.inf).reshape(choice.shape)
-        _, idx = jax.lax.top_k(choice, self.top_k)
-        picked = jnp.take_along_axis(scores, idx, axis=-1)
+        idx = checkpoint_name(jax.lax.top_k(choice, self.top_k)[1],
+                              SELECTION)
+        picked = checkpoint_name(jnp.take_along_axis(scores, idx, axis=-1),
+                                 PICKED)
         gates = self.scaling * picked / jnp.sum(picked, axis=-1,
                                                 keepdims=True)
         counts = jnp.sum(idx[..., None] == jnp.arange(self.num_experts),
                          axis=(0, 1), dtype=jnp.int32)
-        return idx.astype(jnp.int32), gates, counts
+        return idx, gates, counts
 
     def route_mlp(self, x, params, r_prev):
         """``(idx, gates, counts, r (T, router_hidden) f32)`` for tokens
@@ -513,18 +564,22 @@ class HeldExpertsMoE(Op):
         ``counts`` leaves out."""
         mm = functools.partial(jnp.matmul,
                                precision=jax.lax.Precision.HIGHEST)
-        z = mm(x.astype(jnp.float32), params["router_down"])
+        z = checkpoint_name(
+            mm(x.astype(jnp.float32), params["router_down"]), ROUTER_Z)
         depth = jax.nn.sigmoid(params["router_depth"])
         r = (1.0 - depth) * z
         if r_prev is not None:
             r = r + depth * r_prev
-        scores = jax.nn.softmax(mm(jax.nn.gelu(
-            mm(r, params["router_hidden"]), approximate=False),
-            params["router_out"]), axis=-1)
-        gates, idx = jax.lax.top_k(scores, self.top_k)
+        h = checkpoint_name(mm(r, params["router_hidden"]), ROUTER_H)
+        scores = _scores("softmax", mm(jax.nn.gelu(h, approximate=False),
+                                       params["router_out"]))
+        idx = checkpoint_name(jax.lax.top_k(scores, self.top_k)[1],
+                              SELECTION)
+        gates = checkpoint_name(jnp.take_along_axis(scores, idx, axis=-1),
+                                PICKED)
         counts = jnp.sum(idx[..., None] == jnp.arange(self.num_experts),
                          axis=(0, 1), dtype=jnp.int32)
-        return idx.astype(jnp.int32), gates, counts, r
+        return idx, gates, counts, r
 
     def forward(self, params, xs, *, training=False, rng=None, state=None):
         x_in, r_prev = xs[0], (xs[1] if len(xs) > 1 else None)
@@ -550,10 +605,11 @@ class HeldExpertsMoE(Op):
             key = jnp.where(here, local, self.num_held)
             # stable, so a group keeps its tokens in sequence order, and
             # the assignments to held experts come first
-            order = jnp.argsort(key, stable=True).astype(jnp.int32)
-            group_sizes = jnp.sum(
+            order = checkpoint_name(
+                jnp.argsort(key, stable=True).astype(jnp.int32), ORDER)
+            group_sizes = checkpoint_name(jnp.sum(
                 key[:, None] == jnp.arange(self.num_held), axis=0,
-                dtype=jnp.int32)
+                dtype=jnp.int32), GROUP_SIZES)
             n_here = jnp.sum(group_sizes)
         slab = slab_rows(tokens * k, self.num_held, self.num_experts)
         out, slabs = _held_experts(

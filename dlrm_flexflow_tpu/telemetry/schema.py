@@ -386,10 +386,16 @@ SCHEMA: Dict[str, dict] = {
     # kernel again: ``{"attention_core": 1, "gdn_core": 3}`` on a TPU in
     # the hybrid cell, ``{"attention_core": 1, "kda_core": 6}`` in the
     # Ling-style one, every count 0 on the CPU or with recomputation off.
+    # ``route_kept``: only for a program with ``HeldExpertsMoE`` ops: how
+    # many of them sit in a recomputed run and keep their routing there
+    # (the scores, the selection and its scores, the sorted assignments
+    # and the groups' sizes, ``ops/moe.py::ROUTE_SAVED``), so that the
+    # recomputation never routes again: 4 / 6 / 5 / 4 in the hybrid,
+    # Ling-style, JoyAI and ZAYA1-style cells, 0 with recomputation off.
     "program": {
         "required": {"name": str},
         "optional": {"fn": str, "attention_core": dict, "gdn_core": dict,
-                     "kda_core": dict, "core_kept": dict},
+                     "kda_core": dict, "core_kept": dict, "route_kept": int},
     },
     # what one dispatch of FFModel.train_epoch / train_epochs counted
     # inside one op that keeps counters in its state (ops/moe.py

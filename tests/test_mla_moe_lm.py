@@ -234,7 +234,8 @@ def test_a_recomputed_layer_runs_the_forward_kernel_once(monkeypatch):
     recomputation keeps the core's output and log-sum-exp
     (``saved_in_recompute``), so the backward holds no second forward
     kernel; the ``program`` event would read ``pallas: 3``, all three
-    kept through their recomputation."""
+    kept through their recomputation, and the two expert layers' routing
+    kept through theirs (``route_kept``)."""
     monkeypatch.setattr(attention_ops, "_on_tpu", lambda: True)
     cfg = _small(num_attention_heads=2, qk_nope_head_dim=128,
                  qk_rope_head_dim=64, v_head_dim=128, seq_len=2048)
@@ -242,7 +243,7 @@ def test_a_recomputed_layer_runs_the_forward_kernel_once(monkeypatch):
     model, state = _compiled(cfg, batch=1)
     assert model._program_fields == {
         "attention_core": {"pallas": 3, "plain": 0},
-        "core_kept": {"attention_core": 3}}
+        "core_kept": {"attention_core": 3}, "route_kept": 2}
     inputs, labels = family._split(_tokens(cfg, 1, batch=1))
     text = str(jax.make_jaxpr(model._train_step)(
         state, {k: v[0] for k, v in inputs.items()}, labels[0]))
@@ -682,14 +683,16 @@ def test_every_scope_of_the_compiled_step_is_attributed():
                   "ff.lm.moe.shared", "ff.lm.mtp", "ff.lm.head",
                   "ff.step.dense_update"):
         assert scope in found or scope + ".bwd" in found, scope
-    for scope in ("ff.lm.mla.proj", "ff.lm.moe.dispatch", "ff.lm.ffn"):
+    for scope in ("ff.lm.mla.proj", "ff.lm.ffn"):
         assert scope + ".remat" in found, scope
         assert scope + ".bwd" in found, scope
     # the core's output and log-sum-exp are kept: its forward runs once;
     # the held experts' backward computes its own slabs again from the
-    # tokens, so the recomputation before it has none of them to compute
+    # tokens, so the recomputation before it has none of them to compute;
+    # the dispatch's order and group sizes are kept (``moe.ROUTE_SAVED``):
+    # the recomputation sorts nothing
     for scope in ("ff.lm.mla.core", "ff.lm.moe.experts",
-                  "ff.lm.moe.combine"):
+                  "ff.lm.moe.combine", "ff.lm.moe.dispatch"):
         assert scope + ".bwd" in found, scope
         assert scope + ".remat" not in found, scope
     for phase in found - {profiling.UNATTRIBUTED}:
